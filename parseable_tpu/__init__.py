@@ -20,14 +20,6 @@ Layer map mirrors SURVEY.md (L0 storage .. L8 CLI); see each subpackage.
 
 __version__ = "0.1.0"
 
-# Python 3.10 compatibility: datetime.UTC landed in 3.11; the codebase uses
-# `from datetime import UTC` throughout. Alias it before any submodule loads.
-import datetime as _datetime
-
-if not hasattr(_datetime, "UTC"):  # pragma: no cover - version-dependent
-    _datetime.UTC = _datetime.timezone.utc
-del _datetime
-
 # Internal stream names (reference: src/parseable/mod.rs internal stream consts)
 INTERNAL_STREAM_NAME = "pmeta"
 FIELD_STATS_STREAM_NAME = "pstats"

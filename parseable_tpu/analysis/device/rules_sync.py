@@ -394,7 +394,7 @@ class BenchSyncRule(Rule):
         "overhead, not device execution — the bench number becomes fiction"
     )
 
-    _BENCH_FILES = ("bench.py", "scripts/hw_validate.py")
+    _BENCH_FILES = ("bench.py",)
     _BENCH_PREFIX = "scripts/bench_"
 
     def applies(self, rel: str) -> bool:

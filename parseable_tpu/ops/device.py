@@ -279,23 +279,32 @@ def encode_table(
     )
 
 
+# The local devices the TPU engine runs on, recorded when it first resolves
+# them (executor_tpu.resolve_mesh). Scrapes read THIS list and never ask
+# JAX: a /metrics scrape must not be what initialises a backend — an
+# ingest-mode node that never runs the engine would otherwise reach for the
+# chip its querier holds (one process per chip).
+_ENGINE_DEVICES: list = []
+
+
+def note_engine_devices(devices: list) -> None:
+    if not _ENGINE_DEVICES:
+        _ENGINE_DEVICES.extend(devices)
+
+
 def collect_device_gauges() -> None:
     """Refresh per-device accelerator gauges at scrape time (the /metrics
     handler calls this just before rendering; reference analogue: the
-    metrics layer polling allocator stats). Backends without memory_stats
-    (CPU PJRT) simply leave the gauge family empty."""
-    from parseable_tpu.utils.metrics import DEVICE_MEMORY_IN_USE
+    metrics layer polling allocator stats). Only a process that has run
+    the TPU engine reports them; backends without memory_stats (CPU PJRT)
+    leave the gauge families empty."""
+    from parseable_tpu.utils.metrics import DEVICE_MEMORY_IN_USE, DEVICE_MEMORY_PEAK
 
-    try:
-        import jax
-
-        devices = jax.local_devices()
-    except Exception:  # noqa: BLE001 — no backend at all: nothing to report
-        return
-    for d in devices:
-        try:
-            stats = d.memory_stats()
-        except Exception:  # noqa: BLE001 — per-device probe is best-effort
-            stats = None
-        if stats and "bytes_in_use" in stats:
+    for d in _ENGINE_DEVICES:
+        stats = d.memory_stats()
+        if not stats:
+            continue
+        if "bytes_in_use" in stats:
             DEVICE_MEMORY_IN_USE.labels(str(d.id)).set(stats["bytes_in_use"])
+        if "peak_bytes_in_use" in stats:
+            DEVICE_MEMORY_PEAK.labels(str(d.id)).set(stats["peak_bytes_in_use"])

@@ -4,8 +4,9 @@ The reference keeps a hot tier of parquet on local NVMe so queries skip
 object-store GETs (reference: src/hottier.rs). The TPU-native equivalent
 keeps *encoded device arrays* resident in HBM: once a parquet file's columns
 have been encoded and shipped, subsequent queries over the same data run with
-ZERO host->device transfer — which, on any real deployment (PCIe) and
-especially on tunneled dev setups, is the dominant cost of a scan.
+ZERO host->device transfer and zero parquet decode or dictionary encode
+(how large a share of a cold scan those are is not measured on a directly
+attached chip).
 
 Entries are keyed by a source id (file path + mtime + size, or a staging
 batch fingerprint) plus the column-set signature.

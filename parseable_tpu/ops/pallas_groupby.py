@@ -6,10 +6,12 @@ one-hot on the fly in VMEM and accumulates `rows_tile @ onehot_tile` into a
 VMEM accumulator on the MXU — the one-hot never round-trips to HBM, which
 is the XLA version's main residual traffic at large G.
 
-Correctness is pinned against the XLA kernel on every platform via
-`interpret=True` (Pallas' reference interpreter) in tests; on real TPU the
-kernel compiles natively. Kept opt-in until it's benchmarked faster on
-hardware — the XLA path already sustains ~70 Grows/s on a v5e.
+Correctness is pinned against the XLA kernel in tests through Pallas'
+interpreter (`interpret=True`, passed explicitly); chip_smoke.py compiles
+it with Mosaic on the chip and compares it with the XLA path there (the
+1-D ids block, the `ids[:, None]` relayout and an R that is no multiple
+of 8 all compile on a v5e with jax 0.9.0). Kept opt-in: which of the two
+is faster is not measured on today's code (ROADMAP S5).
 """
 
 from __future__ import annotations
@@ -18,14 +20,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
-try:  # pallas import is safe everywhere; compilation is deferred
-    from jax.experimental import pallas as pl
-
-    PALLAS_AVAILABLE = True
-except Exception:  # pragma: no cover
-    pl = None
-    PALLAS_AVAILABLE = False
+from parseable_tpu.ops.kernels import SUM_DOT_PRECISION
 
 ROW_TILE = 2048  # rows per grid step (sublane-friendly multiple of 8)
 
@@ -44,6 +41,7 @@ def _additive_kernel(ids_ref, rows_ref, out_ref, *, num_groups: int):
     partial_sum = jax.lax.dot_general(
         rows_ref[...], onehot, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
+        precision=SUM_DOT_PRECISION,  # f32 products; see kernels.py
     )
     first = pl.program_id(0) == 0
     out_ref[...] = jnp.where(first, partial_sum, out_ref[...] + partial_sum)

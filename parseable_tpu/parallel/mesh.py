@@ -26,6 +26,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from parseable_tpu.ops import kernels
@@ -65,10 +66,6 @@ def distributed_groupby(
     replicated (psum/pmin/pmax over ICI). jit-compiled once per
     (block, groups) shape bucket.
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5 keeps it in experimental
-        from jax.experimental.shard_map import shard_map
 
     @partial(
         shard_map,
@@ -111,11 +108,6 @@ def distributed_groupby_2d(
     trades an all-to-all for recompute-free masking, and the only collective
     is the psum over `data`.
     """
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5 keeps it in experimental
-        from jax.experimental.shard_map import shard_map
-
     n_group_shards = mesh.shape["groups"]
 
     @partial(
@@ -173,14 +165,6 @@ def full_query_step(mesh: Mesh, num_groups: int):
             0,
         )
         return count, sums
-
-    try:
-
-        from jax import shard_map
-
-    except ImportError:  # jax < 0.5 keeps it in experimental
-
-        from jax.experimental.shard_map import shard_map
 
     sharded = shard_map(
         lambda *a: tuple(

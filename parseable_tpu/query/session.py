@@ -1220,22 +1220,7 @@ class QuerySession:
             self._fanout_stats = dist.stats
             return QueryResult(table, table.column_names, {}), timer
 
-        use_tpu = self.engine == "tpu"
-        fallback = False
-        if use_tpu:
-            from parseable_tpu.utils.devicecheck import device_healthy
-
-            # bound the probe by the query's own deadline so the health
-            # check can never be what times the query out
-            max_wait = None
-            if lp.deadline is not None:
-                max_wait = max(0.0, lp.deadline - _time.monotonic() - 1.0)
-            if not device_healthy(max_wait=max_wait):
-                # wedged/unreachable accelerator: the CPU engine is a
-                # complete fallback — degrade instead of hanging a worker
-                use_tpu = False
-                fallback = True
-        if use_tpu:
+        if self.engine == "tpu":
             from parseable_tpu.query.executor_tpu import TpuQueryExecutor
 
             if (
@@ -1277,7 +1262,7 @@ class QuerySession:
             table = executor.execute(timer)
         finally:
             timer.close()
-        stats = {"engine_fallback": "device unhealthy"} if fallback else {}
+        stats = {}
         routes = getattr(executor, "route_stats", None)
         if routes is not None:
             # adaptive-dispatch observability (EXPLAIN ANALYZE surfaces

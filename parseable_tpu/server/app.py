@@ -113,6 +113,9 @@ class ServerState:
         # BEFORE node registration so discovery metadata is accurate;
         # stopped in stop()
         self.flight = None
+        # executor_tpu.device_summary() of the TPU engine's devices — set
+        # by run_server on nodes that serve queries with it, else None
+        self.query_device: dict | None = None
 
     def hot_tier(self):
         """Lazily-built hot tier manager, restored from persisted budgets."""
@@ -260,12 +263,6 @@ class ServerState:
                 lambda: _C.ingest_cluster_metrics(self.p),
                 "pmeta-scrape",
             )
-            if self.p.options.query_engine == "tpu":
-                # warm the device-health probe off the request path so the
-                # first query never pays the watchdog wait
-                from parseable_tpu.utils.devicecheck import device_healthy
-
-                self.workers.submit(device_healthy)
         if self.p.options.send_analytics:
             from parseable_tpu.analytics import analytics_tick
 
@@ -612,6 +609,17 @@ async def debug_profile(request: web.Request) -> web.Response:
     )
 
 
+def _query_device(state: ServerState) -> dict | None:
+    if state.query_device is None:
+        return None
+    from parseable_tpu.query import executor_tpu
+
+    return {
+        **state.query_device,
+        "mesh_programs_built": executor_tpu.MESH_PROGRAMS_BUILT,
+    }
+
+
 @require(Action.GET_ABOUT)
 async def about(request: web.Request) -> web.Response:
     state: ServerState = request.app["state"]
@@ -625,6 +633,10 @@ async def about(request: web.Request) -> web.Response:
             "staging": str(state.p.options.local_staging_path),
             "store": {"type": state.p.storage.name, "path": state.p.provider.get_endpoint()},
             "queryEngine": state.p.options.query_engine,
+            # what the engine runs on (None on nodes that run no TPU
+            # engine): platform / device_kind / device_count / mesh, and
+            # how many of its programs were built over that mesh so far
+            "queryDevice": _query_device(state),
             "license": "AGPL-3.0",
         }
     )
@@ -2401,6 +2413,20 @@ def run_server(opts: Options | None = None, storage: StorageOptions | None = Non
     if upgraded:
         logger.info("migrated %d stream metadata documents", upgraded)
     state = ServerState(p)
+    if p.options.query_engine == "tpu" and p.options.mode in (Mode.ALL, Mode.QUERY):
+        # the process that serves queries takes its devices now, before it
+        # listens: a backend that cannot start fails the boot, and what the
+        # engine runs on is in the log and /api/v1/about, never a guess
+        from parseable_tpu.query.executor_tpu import device_summary
+
+        state.query_device = device_summary(p.options)
+        logger.info(
+            "query engine tpu on platform=%s device_kind=%s devices=%d mesh=%s",
+            state.query_device["platform"],
+            state.query_device["device_kind"],
+            state.query_device["device_count"],
+            state.query_device["mesh"] or "none (single chip)",
+        )
     host, _, port = p.options.address.rpartition(":")
     # Arrow Flight data plane BEFORE registration: register_node advertises
     # the flight endpoint from options, and a failed start zeroes the port
@@ -2456,7 +2482,11 @@ def run_server(opts: Options | None = None, storage: StorageOptions | None = Non
 
 
 def main(argv: list[str] | None = None) -> None:
+    from parseable_tpu.utils.compile_cache import configure_compile_cache
+
     opts, storage = parse_cli(argv)
+    if opts.query_engine == "tpu":  # a CPU-engine node compiles nothing
+        configure_compile_cache()
     run_server(opts, storage)
 
 

@@ -216,7 +216,7 @@ class FlightDataServer(flight.FlightServerBase):
         errors: the client treats any of them as a decline and retries the
         peer over HTTP, which classifies terminal (400: unsupported plan)
         vs retryable exactly as before — the ladder never invents a new
-        failure taxonomy."""
+        classification of failures."""
         from parseable_tpu.query import fanout as FO
 
         sql = req.get("query")

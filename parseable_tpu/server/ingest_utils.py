@@ -349,7 +349,7 @@ def _ndjson_to_event(
 
     # the NDJSON tier's real parse happens here (pyarrow's C++ reader),
     # above the telemetry ring — timed Python-side under the same
-    # stage/lane taxonomy so the waterfall stays complete on this tier
+    # stage/lane naming so the waterfall stays complete on this tier
     t0 = time.time_ns()
     try:
         # BufferReader wraps the bytes zero-copy (BytesIO copies them)

@@ -196,6 +196,9 @@ DEVICE_BYTES_TO_DEVICE = _counter("tpu_bytes_to_device", "Bytes shipped host->de
 DEVICE_MEMORY_IN_USE = _gauge(
     "tpu_device_memory_in_use", "Accelerator memory in use (bytes)", ["device"]
 )
+DEVICE_MEMORY_PEAK = _gauge(
+    "tpu_device_memory_peak", "Accelerator memory high-water mark (bytes)", ["device"]
+)
 DEVICE_TRANSFER_BYTES = _gauge(
     "tpu_host_transfer_bytes", "Cumulative host->device transfer bytes", []
 )

@@ -1,6 +1,6 @@
 """Adaptive link dispatch: non-resident blocks route to the CPU when the
 measured link makes shipping a losing trade, and warm the device hot set
-in the background (ops/link.py; the degraded-tunnel counterpart of the
+in the background (ops/link.py; the slow-link counterpart of the
 reference's data-local DataFusion execution,
 /root/reference/src/query/mod.rs)."""
 
@@ -128,7 +128,7 @@ def test_link_profile_flush_bypasses_throttle(tmp_path):
     from parseable_tpu.ops.link import LinkProfile
 
     path = tmp_path / "link_profile.json"
-    prof = LinkProfile(path)
+    prof = LinkProfile(path, device="cpu/cpu")
     prof.record_h2d(1 << 20, 1.0)  # throttled: first save stamps _last_save
     prof.record_h2d(1 << 20, 1.0)
     prof.flush()
@@ -147,8 +147,8 @@ def test_link_profile_merge_on_save(tmp_path):
     from parseable_tpu.ops.link import LinkProfile
 
     path = tmp_path / "link_profile.json"
-    a = LinkProfile(path)
-    b = LinkProfile(path)  # loads the same (absent) baseline
+    a = LinkProfile(path, device="cpu/cpu")
+    b = LinkProfile(path, device="cpu/cpu")  # loads the same (absent) baseline
     for _ in range(30):
         a.record_h2d(1 << 22, 4.0)  # ~1 MB/s: a learns a terrible link
     a.flush()
@@ -162,3 +162,34 @@ def test_link_profile_merge_on_save(tmp_path):
     assert stored["h2d_bw"] <= 0.5 * (a_bw + 8e9) + 1e-6
     assert stored["h2d_bw"] < 8e9 * 0.6  # nowhere near the default
     assert stored["d2h_bw"] < 8e9  # b's own measurement persisted
+
+
+def test_link_profile_ignores_a_file_stamped_for_another_device(tmp_path):
+    """A stored profile steers routing only on the device it was measured
+    on: a file stamped for another device (or not stamped at all — a file
+    from before the stamp) neither loads nor merges into our save, and a
+    profile that knows no device persists nothing."""
+    import json as _json
+
+    from parseable_tpu.ops.link import _DEFAULTS, LinkProfile
+
+    path = tmp_path / "link_profile.json"
+    slow = {**_DEFAULTS, "h2d_bw": 1e6, "d2h_bw": 9e6}
+    for stamp in ({"device": "tpu/TPU v5 lite"}, {}):
+        path.write_text(_json.dumps({**slow, **stamp}))
+        prof = LinkProfile(path, device="cpu/cpu")
+        assert prof.snapshot() == _DEFAULTS
+        prof.record_d2h(1 << 22, 2.0)
+        prof.flush()
+        stored = _json.loads(path.read_text())
+        assert stored["device"] == "cpu/cpu"
+        assert stored["h2d_bw"] == _DEFAULTS["h2d_bw"]  # not averaged with 1e6
+    # the same file loads where it was measured
+    assert LinkProfile(path, device="cpu/cpu").snapshot()["d2h_bw"] == stored["d2h_bw"]
+    # no device known (a CPU-engine process): nothing loaded, nothing written
+    before = path.read_text()
+    anon = LinkProfile(path)
+    assert anon.snapshot() == _DEFAULTS
+    anon.record_cpu_agg(1 << 20, 0.5)
+    anon.flush()
+    assert path.read_text() == before

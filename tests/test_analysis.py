@@ -192,16 +192,16 @@ def test_pool_lifecycle_flags_unjoined_local_thread():
 
 
 def test_pool_lifecycle_local_bounded_join_clean():
-    """The devicecheck.py device-probe idiom: spawn, start, join(wait)."""
+    """A local thread with a bounded join: spawn, start, join(wait)."""
     code = """
         import threading
 
         def probe(fn, wait):
-            t = threading.Thread(target=fn, name="device-probe", daemon=True)
+            t = threading.Thread(target=fn, name="probe", daemon=True)
             t.start()
             t.join(wait)
     """
-    assert check(PoolLifecycleRule(), code, "parseable_tpu/utils/devicecheck.py") == []
+    assert check(PoolLifecycleRule(), code, "parseable_tpu/utils/probe.py") == []
 
 
 def test_pool_lifecycle_custody_transfer_clean():

@@ -319,7 +319,7 @@ def test_donation_missed_is_advisory_and_comment_silences(tmp_path):
 
     documented = bare.replace(
         "        f = jax.jit(step)",
-        "        # no donate: input outlives the call on tunneled backends\n"
+        "        # no donate: the input outlives the call\n"
         "        f = jax.jit(step)",
     )
     root2 = _tree(tmp_path / "b", {EXEC_REL: documented})

@@ -87,8 +87,8 @@ def test_stub_eviction_race_rereads_source(loaded):
     orig_get = hs.get
     state = {"cleared": False}
 
-    def evil_get(key):
-        entry = orig_get(key)
+    def evil_get(key, **kw):
+        entry = orig_get(key, **kw)
         if entry is not None and not state["cleared"]:
             # let the provider see it as hot, then evict before execution
             state["cleared"] = True

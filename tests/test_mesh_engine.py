@@ -225,3 +225,27 @@ def test_2d_mesh_distinct_group_sharded(parseable):
     tpu = ET.TpuQueryExecutor(lp2, opts).execute(iter([t])).to_pylist()
     assert ET.GROUP_SHARDED_PROGRAMS_BUILT > before_gs, "did not group-shard"
     assert_parity(cpu, tpu, sql)
+
+
+@pytest.mark.parametrize(
+    "shape, match",
+    [
+        ("16", "needs 16 devices, 8 visible"),  # more than are visible
+        ("4x4", "needs 16 devices, 8 visible"),
+        ("data:3", "not a power of two"),  # could never divide a row block
+        ("4x", "malformed"),
+        ("fast", "malformed"),
+    ],
+)
+def test_resolve_mesh_refuses_what_it_cannot_honour(shape, match):
+    """An explicit P_TPU_MESH is honoured or refused — "single chip" is
+    never the quiet answer to a mesh that was asked for."""
+    from parseable_tpu.config import Options
+
+    opts = Options()
+    opts.mesh_shape = shape
+    with pytest.raises(ValueError, match=match):
+        ET.resolve_mesh(opts)
+    assert shape not in ET._MESH_CACHE  # a refusal is not cached as "no mesh"
+    opts.mesh_shape = "data:4"
+    assert ET.device_summary(opts)["mesh"] == "data:4"

@@ -99,8 +99,9 @@ def test_distributed_groupby_2d_shards_group_space():
 
 
 def test_pallas_groupby_opt_in_parity(monkeypatch):
-    """P_TPU_USE_PALLAS=1 routes the additive reduction through the pallas
-    kernel (interpret mode off-TPU) with results matching the XLA path."""
+    """P_TPU_USE_PALLAS routes the additive reduction through the pallas
+    kernel — through the Pallas interpreter, which the test asks for
+    explicitly — with results matching the XLA path."""
     import numpy as np
     import jax.numpy as jnp
 
@@ -116,7 +117,7 @@ def test_pallas_groupby_opt_in_parity(monkeypatch):
     valid = jnp.ones((2, n), bool)
 
     base = K.fused_groupby_block(ids, mask, sums, mins, empty, valid, g, 1, 1, 0)
-    monkeypatch.setenv("P_TPU_USE_PALLAS", "1")
+    monkeypatch.setenv("P_TPU_USE_PALLAS", "interpret")
     K.fused_groupby_block.clear_cache()
     try:
         pal = K.fused_groupby_block(ids, mask, sums, mins, empty, valid, g, 1, 1, 0)
