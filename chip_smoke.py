@@ -20,10 +20,14 @@ Process layout (a chip belongs to one process):
   kernels  after the server has exited: Mosaic compile + XLA comparison
 
 Exit code 0 and a last stdout line
-  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}, ...}
-only if every phase passed; otherwise a non-zero code and a last line
-{"ok": false, "reason": "..."}. Nothing a failing phase raises is caught and
-carried past. Times it prints are set-up information, never a measurement.
+  {"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}
+(exactly these keys; the device as JAX reports it) only if every phase
+passed. A phase that fails once the device is known gives a non-zero code,
+the same line with "ok": false, and the reason in the {"detail": ...} line
+before it and on stderr. Where JAX finds no TPU, or the script stands alone
+without the repository, it prints no result at all: a non-zero code and the
+reason on stderr. Nothing a failing phase raises is caught and carried past.
+Times it prints are set-up information, never a measurement.
 
 `--cpu-rehearsal` is the explicit, tiny run on the CPU backend used to debug
 this script before chip time is spent: it stamps `"platform": "cpu"` and
@@ -80,14 +84,6 @@ SPARSE_SQL = (
 SELECT_SQL = (
     "SELECT p_timestamp, host, bytes, latency_ms FROM {stream} "
     f"WHERE {RARE} AND status = 503 LIMIT 100000"
-)
-
-
-# the verdict line: the contract's {"ok", "device"} plus a few scalars; the
-# full record is the {"detail": ...} line before it
-LAST_LINE_KEYS = (
-    "ok", "reason", "rehearsal", "platform", "device", "mesh", "mesh_programs_built",
-    "rows_loaded", "first_answer_s", "compile_cache", "total_s",
 )
 
 
@@ -597,9 +593,11 @@ def smoke(args, workdir: Path, result: dict) -> None:
 
     # -- is there a chip at all? (fail fast, before 32M rows are made)
     probe = run_child("probe", [], env, 300)
-    note(f"probe: {probe}")
     check(rehearsal or probe["platform"] == "tpu",
           f"JAX found no TPU: {probe} (JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r})")
+    # from here on there is a device to report, pass or fail
+    result["device"] = probe
+    note(f"probe: {probe}")
 
     # -- built from the files git would commit: the native library is built
     # here, on the machine that runs it (build.sh uses -march=native)
@@ -638,7 +636,8 @@ def smoke(args, workdir: Path, result: dict) -> None:
         check(about.get("queryEngine") == "tpu" and qd is not None, f"server does not run the tpu engine: {about}")
         note(f"server: {qd} (boot {result['boot_s']}s)")
         check(rehearsal or qd["platform"] == "tpu", f"the server's device is not a TPU: {qd}")
-        result["device"] = {"platform": qd["platform"], "kind": qd["device_kind"], "count": qd["device_count"]}
+        served = {"platform": qd["platform"], "kind": qd["device_kind"], "count": qd["device_count"]}
+        check(served == probe, f"the server runs on {served}, JAX reports {probe}")
         result["mesh"] = qd["mesh"]
         if qd["device_count"] > 1:
             n = 1 << (qd["device_count"].bit_length() - 1)
@@ -736,6 +735,7 @@ def main() -> int:
         if not isinstance(e, SmokeFailure):
             traceback.print_exc()
         result["reason"] = str(e) if isinstance(e, SmokeFailure) else f"{type(e).__name__}: {e}"
+        print(f"chip_smoke: FAILED: {result['reason']}", file=sys.stderr, flush=True)
     result["total_s"] = round(time.time() - t0, 1)
     if workdir is not None:
         # what is too long for the end of the output goes to the output directory
@@ -746,10 +746,13 @@ def main() -> int:
             shutil.copy(workdir / "server.log", out_dir / "chip_smoke_server.log")
         if not args.keep:
             shutil.rmtree(workdir, ignore_errors=True)
-    # everything observed, then the one-object verdict as the LAST line
+    if "device" not in result:
+        # no accelerator (or no repository around the script): no result
+        return 1
+    # everything observed, then the verdict as the LAST line: exactly
+    # {"ok", "device"}, the device as JAX reported it to the probe
     print(json.dumps({"detail": result}))
-    last = {k: result[k] for k in LAST_LINE_KEYS if k in result}
-    print(json.dumps(last))
+    print(json.dumps({"ok": result["ok"], "device": result["device"]}), flush=True)
     return 0 if result["ok"] else 1
 
 

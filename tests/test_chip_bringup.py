@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import json
 import os
+import shutil
 import subprocess
 import sys
 from pathlib import Path
@@ -16,8 +17,11 @@ import pytest
 REPO = Path(__file__).resolve().parent.parent
 
 
-def _last_json(stdout: str) -> dict:
-    return json.loads(stdout.strip().splitlines()[-1])
+def _verdict_and_detail(stdout: str) -> tuple[dict, dict]:
+    """The last stdout line (the verdict the driver reads) and the record
+    in the `{"detail": ...}` line before it."""
+    lines = stdout.strip().splitlines()
+    return json.loads(lines[-1]), json.loads(lines[-2])["detail"]
 
 
 def test_compile_cache_dir_is_fixed_or_left_to_the_environment(monkeypatch):
@@ -77,16 +81,27 @@ def test_metrics_scrape_never_initialises_a_backend(monkeypatch):
 
 def test_chip_smoke_refuses_to_pass_without_a_tpu():
     """Without the rehearsal flag a CPU-only machine is a failure: non-zero
-    exit, the reason in the last JSON line, and no data loaded first."""
+    exit, no result on stdout, the reason on stderr, no data loaded first."""
     proc = subprocess.run(
         [sys.executable, str(REPO / "chip_smoke.py")],
         env={**os.environ, "JAX_PLATFORMS": "cpu"},
         capture_output=True, text=True, timeout=120,
     )
     assert proc.returncode != 0
-    last = _last_json(proc.stdout)
-    assert last["ok"] is False and "no TPU" in last["reason"]
-    assert "device" not in last
+    assert proc.stdout.strip() == ""
+    assert "JAX found no TPU" in proc.stderr
+
+
+def test_chip_smoke_alone_in_a_directory_prints_no_result(tmp_path):
+    """The script without the repository around it has nothing to drive."""
+    shutil.copy(REPO / "chip_smoke.py", tmp_path)
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py"], cwd=tmp_path,
+        capture_output=True, text=True, timeout=60,
+    )
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == ""
+    assert "not the repository it drives" in proc.stderr
 
 
 @pytest.mark.slow
@@ -104,7 +119,11 @@ def test_chip_smoke_cpu_rehearsal():
          "--rows", "400000", "--batch-rows", "100000"],
         env=env, capture_output=True, text=True, timeout=900,
     )
-    last = _last_json(proc.stdout)
-    assert proc.returncode == 0 and last["ok"] is True, proc.stdout[-3000:]
-    assert last["rehearsal"] is True and last["platform"] == "cpu"
-    assert last["mesh"] == "data:4" and last["mesh_programs_built"] > 0
+    verdict, detail = _verdict_and_detail(proc.stdout)
+    assert proc.returncode == 0 and verdict["ok"] is True, proc.stdout[-3000:]
+    # the verdict line is exactly the contract's object, the device as JAX reports it
+    assert set(verdict) == {"ok", "device"}
+    assert set(verdict["device"]) == {"platform", "kind", "count"}
+    assert verdict["device"]["platform"] == "cpu" and verdict["device"]["count"] == 4
+    assert detail["rehearsal"] is True and detail["platform"] == "cpu"
+    assert detail["mesh"] == "data:4" and detail["mesh_programs_built"] > 0
