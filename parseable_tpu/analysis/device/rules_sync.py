@@ -45,9 +45,7 @@ _DEVICE_ROOTS = ("jnp",)
 _PROGRAM_HINTS = ("program", "cache", "prog")
 
 _PRICING_CALL_TAILS = frozenset({"record_h2d", "record_d2h"})
-_PRICING_NAMES = frozenset(
-    {"DEVICE_BYTES_TO_DEVICE", "DEVICE_TRANSFER_BYTES", "get_link"}
-)
+_PRICING_NAMES = frozenset({"DEVICE_BYTES_TO_DEVICE", "get_link"})
 _PRICING_KEYS = frozenset({"h2d_bytes", "d2h_bytes"})
 
 

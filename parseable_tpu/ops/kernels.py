@@ -164,18 +164,19 @@ def fused_groupby_block(
         )
 
         if group_ids.shape[0] % ROW_TILE == 0:
-            rows = jnp.concatenate(
-                [
-                    mask[None, :].astype(jnp.float32),
-                    vmask.astype(jnp.float32),
-                    jnp.where(vmask[:n_sum], sum_values, 0.0),
-                ],
-                axis=0,
-            )
-            adds = additive_groupby_pallas(
-                group_ids, rows, num_groups, interpret=pallas_mode == "interpret"
-            )
-            additive = (adds[0], adds[1 : 1 + n_all], adds[1 + n_all :])
+            with jax.named_scope("pallas"):
+                rows = jnp.concatenate(
+                    [
+                        mask[None, :].astype(jnp.float32),
+                        vmask.astype(jnp.float32),
+                        jnp.where(vmask[:n_sum], sum_values, 0.0),
+                    ],
+                    axis=0,
+                )
+                adds = additive_groupby_pallas(
+                    group_ids, rows, num_groups, interpret=pallas_mode == "interpret"
+                )
+                additive = (adds[0], adds[1 : 1 + n_all], adds[1 + n_all :])
 
     n_rows = group_ids.shape[0]
     # the one-hot dot is the MXU's fast path; every other backend (the
@@ -192,54 +193,56 @@ def fused_groupby_block(
         num_groups <= MATMUL_MAX_GROUPS
         and n_rows * num_groups <= max_onehot
     ):
-        # Split-precision one-hot reduction: the 0/1 rows (count + per-agg
-        # counts) ride a bf16 x bf16 -> f32 MXU dot — 0 and 1 are exactly
-        # representable in bf16 and accumulation is f32, so counts stay
-        # EXACT while the one-hot's HBM traffic halves (the gain is not
-        # measured on today's code). The value sums use their own
-        # independently-generated f32 one-hot: deriving it from the bf16
-        # tensor (astype) gave the one-hot two consumers and forced XLA to
-        # materialize it — each dot must be the sole consumer of its
-        # operand for fusion.
-        iota = jnp.arange(num_groups, dtype=jnp.int32)[None, :]
-        onehot_bf16 = (group_ids[:, None] == iota).astype(jnp.bfloat16)
-        count_rows = jnp.concatenate(
-            [mask[None, :].astype(jnp.bfloat16), vmask.astype(jnp.bfloat16)], axis=0
-        )
-        count_adds = jax.lax.dot_general(
-            count_rows, onehot_bf16, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        count = count_adds[0]
-        per_agg_count = count_adds[1 : 1 + n_all]
-        if n_sum:
-            onehot_f32 = (group_ids[:, None] == iota).astype(jnp.float32)
-            sum_rows = jnp.where(vmask[:n_sum], sum_values, 0.0)
-            sums = jax.lax.dot_general(
-                sum_rows, onehot_f32, (((1,), (0,)), ((), ())),
+        with jax.named_scope("onehot_dot"):
+            # Split-precision one-hot reduction: the 0/1 rows (count + per-agg
+            # counts) ride a bf16 x bf16 -> f32 MXU dot — 0 and 1 are exactly
+            # representable in bf16 and accumulation is f32, so counts stay
+            # EXACT while the one-hot's HBM traffic halves (the gain is not
+            # measured on today's code). The value sums use their own
+            # independently-generated f32 one-hot: deriving it from the bf16
+            # tensor (astype) gave the one-hot two consumers and forced XLA to
+            # materialize it — each dot must be the sole consumer of its
+            # operand for fusion.
+            iota = jnp.arange(num_groups, dtype=jnp.int32)[None, :]
+            onehot_bf16 = (group_ids[:, None] == iota).astype(jnp.bfloat16)
+            count_rows = jnp.concatenate(
+                [mask[None, :].astype(jnp.bfloat16), vmask.astype(jnp.bfloat16)], axis=0
+            )
+            count_adds = jax.lax.dot_general(
+                count_rows, onehot_bf16, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-                precision=SUM_DOT_PRECISION,
             )
-        else:
-            sums = jnp.zeros((0, num_groups), jnp.float32)
-    else:
-        count = jax.ops.segment_sum(
-            mask.astype(jnp.float32), group_ids, num_segments=num_groups
-        )
-        per_agg_count = jax.vmap(
-            lambda vm: jax.ops.segment_sum(
-                vm.astype(jnp.float32), group_ids, num_segments=num_groups
-            )
-        )(vmask)
-        sums = (
-            jax.vmap(
-                lambda vals, vm: jax.ops.segment_sum(
-                    jnp.where(vm, vals, 0.0), group_ids, num_segments=num_groups
+            count = count_adds[0]
+            per_agg_count = count_adds[1 : 1 + n_all]
+            if n_sum:
+                onehot_f32 = (group_ids[:, None] == iota).astype(jnp.float32)
+                sum_rows = jnp.where(vmask[:n_sum], sum_values, 0.0)
+                sums = jax.lax.dot_general(
+                    sum_rows, onehot_f32, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                    precision=SUM_DOT_PRECISION,
                 )
-            )(sum_values, vmask[:n_sum])
-            if n_sum
-            else jnp.zeros((0, num_groups), jnp.float32)
-        )
+            else:
+                sums = jnp.zeros((0, num_groups), jnp.float32)
+    else:
+        with jax.named_scope("segment_sum"):
+            count = jax.ops.segment_sum(
+                mask.astype(jnp.float32), group_ids, num_segments=num_groups
+            )
+            per_agg_count = jax.vmap(
+                lambda vm: jax.ops.segment_sum(
+                    vm.astype(jnp.float32), group_ids, num_segments=num_groups
+                )
+            )(vmask)
+            sums = (
+                jax.vmap(
+                    lambda vals, vm: jax.ops.segment_sum(
+                        jnp.where(vm, vals, 0.0), group_ids, num_segments=num_groups
+                    )
+                )(sum_values, vmask[:n_sum])
+                if n_sum
+                else jnp.zeros((0, num_groups), jnp.float32)
+            )
 
     def seg_min(vals, vm):
         return jax.ops.segment_min(jnp.where(vm, vals, F32_MAX), group_ids, num_segments=num_groups)

@@ -79,9 +79,10 @@ from parseable_tpu.utils.metrics import (
     DEVICE_BYTES_TO_DEVICE,
     DEVICE_EXECUTE_TIME,
     DEVICE_JIT_PROGRAMS,
+    DEVICE_PHASE_SECONDS,
     DEVICE_RECOMPILES,
-    DEVICE_TRANSFER_BYTES,
 )
+from parseable_tpu.utils.telemetry import TRACER
 from parseable_tpu.utils.timeutil import parse_duration, parse_rfc3339
 
 logger = logging.getLogger(__name__)
@@ -912,15 +913,16 @@ def _block_m2(dev, layout, ids, mask, pac, sums, kernel_groups):
     n_rows = []
     s_rows = []
     for qi, colname in enumerate(layout.sq_cols):
-        n_b = pac[n_sum + qi]
-        s_b = sums[n_sum + qi]
-        mean_g = s_b / jnp.maximum(n_b, 1.0)
-        v = dev[colname].astype(jnp.float32)
-        vm = jnp.logical_and(mask, dev[f"{colname}__valid"])
-        centered = jnp.where(vm, v - mean_g[ids], 0.0)
-        m2_rows.append(
-            jax.ops.segment_sum(centered * centered, ids, num_segments=kernel_groups)
-        )
+        with jax.named_scope("m2"):
+            n_b = pac[n_sum + qi]
+            s_b = sums[n_sum + qi]
+            mean_g = s_b / jnp.maximum(n_b, 1.0)
+            v = dev[colname].astype(jnp.float32)
+            vm = jnp.logical_and(mask, dev[f"{colname}__valid"])
+            centered = jnp.where(vm, v - mean_g[ids], 0.0)
+            m2_rows.append(
+                jax.ops.segment_sum(centered * centered, ids, num_segments=kernel_groups)
+            )
         n_rows.append(n_b)
         s_rows.append(s_b)
     return m2_rows, n_rows, s_rows
@@ -936,12 +938,13 @@ def _psum_m2(m2_loc, m2_n, m2_s, sq_cols):
 
     m2_tot, n_tot, s_tot = [], [], []
     for qi in range(len(sq_cols)):
-        n_t = jax.lax.psum(m2_n[qi], "data")
-        s_t = jax.lax.psum(m2_s[qi], "data")
-        mean_t = s_t / jnp.maximum(n_t, 1.0)
-        mean_l = m2_s[qi] / jnp.maximum(m2_n[qi], 1.0)
-        d = mean_l - mean_t
-        m2_tot.append(jax.lax.psum(m2_loc[qi] + m2_n[qi] * d * d, "data"))
+        with jax.named_scope("m2"):
+            n_t = jax.lax.psum(m2_n[qi], "data")
+            s_t = jax.lax.psum(m2_s[qi], "data")
+            mean_t = s_t / jnp.maximum(n_t, 1.0)
+            mean_l = m2_s[qi] / jnp.maximum(m2_n[qi], 1.0)
+            d = mean_l - mean_t
+            m2_tot.append(jax.lax.psum(m2_loc[qi] + m2_n[qi] * d * d, "data"))
         n_tot.append(n_t)
         s_tot.append(s_t)
     return m2_tot, n_tot, s_tot
@@ -950,15 +953,17 @@ def _psum_m2(m2_loc, m2_n, m2_s, sq_cols):
 def _chan_merge_m2(acc_n, acc_s, acc_m2, b_n, b_s, b_m2):
     """Chan's parallel variance update: combine (n, sum, M2) partials
     without forming raw sums of squares. Guarded for empty sides."""
+    import jax
     import jax.numpy as jnp
 
-    tot = acc_n + b_n
-    both = jnp.logical_and(acc_n > 0, b_n > 0)
-    delta = acc_s / jnp.maximum(acc_n, 1.0) - b_s / jnp.maximum(b_n, 1.0)
-    corr = jnp.where(
-        both, delta * delta * acc_n * b_n / jnp.maximum(tot, 1.0), 0.0
-    )
-    return acc_m2 + b_m2 + corr
+    with jax.named_scope("m2"):
+        tot = acc_n + b_n
+        both = jnp.logical_and(acc_n > 0, b_n > 0)
+        delta = acc_s / jnp.maximum(acc_n, 1.0) - b_s / jnp.maximum(b_n, 1.0)
+        corr = jnp.where(
+            both, delta * delta * acc_n * b_n / jnp.maximum(tot, 1.0), 0.0
+        )
+        return acc_m2 + b_m2 + corr
 
 
 # Jitted programs cached process-wide: two identical queries (or two
@@ -998,36 +1003,121 @@ def _note_program_build(program: str, key: tuple, stats: dict | None = None) -> 
         _PROGRAM_KEYS_BUILT.add(marker)
 
 
+# The phases of `stats.stages.execute`, in the order a block meets them. Each
+# brackets host code that stands where it stood; none adds a wait.
+PHASES = (
+    "encode",  # _encoded_block: hot-set look-up; on a miss encode + enccache + _transfer
+    "prepare",  # LUTs, gdict remaps, _host_codes + np.unique, puts of arguments
+    "dispatch",  # program look-up and the call into it up to its return (enqueue)
+    "device_wait",  # the wait for pending compute that _timed_readback makes
+    "readback",  # the np.asarray after it
+    "partial",  # dense arrays -> partial / interim tables
+    "merge",  # _merge_partials
+    "finalize",  # finalize_from_interim / finalize_aggregate
+)
+
+
+class RouteStats(dict):
+    """One query's route counters (the items: what `device_routes` and
+    `stages.programs` publish) and, as attributes, its execute-phase clock
+    (what `stages.execute` publishes): `ns[phase]` monotonic nanoseconds,
+    `blocks` and `readbacks` counts, and two time stamps on the same clock,
+    `first_dispatch_ns` (the first program call returned) and
+    `last_readback_ns` (the last readback ended), 0 where none happened.
+
+    One phase runs at a time: `enter` closes the running one and opens the
+    next, so phases never overlap and their sum is at most the wall time.
+    A nested bracket hands the clock back with the value `enter` returned."""
+
+    __slots__ = ("ns", "blocks", "readbacks", "first_dispatch_ns", "last_readback_ns", "_phase", "_since")
+
+    def __init__(self) -> None:
+        super().__init__(
+            device_warm=0,  # hot-set resident: zero bytes shipped
+            device_cold=0,  # encoded + shipped this query
+            cpu_adaptive=0,  # link cost model routed to host
+            cpu_fallback=0,  # declared UnsupportedOnDevice (incl. budgets)
+            h2d_bytes=0,
+            d2h_bytes=0,
+            # program-cache traffic (stages.programs reads these): builds
+            # this query, cache hits this query, rebuilds of a key that
+            # was already built once (0 in steady state)
+            programs_built=0,
+            programs_reused=0,
+            recompiles=0,
+        )
+        self.ns = dict.fromkeys(PHASES, 0)
+        self.blocks = self.readbacks = 0
+        self.first_dispatch_ns = self.last_readback_ns = 0
+        self._phase: str | None = None
+        self._since = 0
+
+    def enter(self, phase: str | None) -> str | None:
+        now = _time.perf_counter_ns()
+        prev = self._phase
+        if prev is not None:
+            self.ns[prev] += now - self._since
+        self._phase, self._since = phase, now
+        return prev
+
+    def dispatched(self, then: str | None) -> None:
+        """A program call has returned: on to `then`."""
+        self.enter(then)
+        if not self.first_dispatch_ns:
+            self.first_dispatch_ns = self._since
+
+    def read_back(self, then: str | None) -> None:
+        """A readback has ended: on to `then`."""
+        self.enter(then)
+        self.readbacks += 1
+        self.last_readback_ns = self._since
+
+
 # the ONE declared d2h readback — waits out pending compute, times pure
 # transfer, prices wire bytes into route_stats and the link-profile EWMA
 # sync-boundary: every hot-path device->host read must flow through here
-def _timed_readback(x, stats: dict | None = None, dtype=np.float64) -> np.ndarray:
+def _timed_readback(
+    x, stats: dict | None = None, dtype=np.float64, feed_link: bool = True
+) -> np.ndarray:
     """Device->host readback with link-profile recording. Pending compute
     is waited out BEFORE the timer starts so the d2h sample measures pure
     transfer — compute/compile waits folded in would poison the adaptive
     cost model's latency EWMA. `stats` (a route_stats dict) gets the wire
-    bytes added for EXPLAIN ANALYZE observability.
+    bytes added for EXPLAIN ANALYZE observability; a `RouteStats` also gets
+    the wait under `device_wait` and the copy under `readback`.
 
     `dtype` is the HOST-side representation (np.float64 for f32
     accumulators headed into host arithmetic; None keeps the device
     dtype — int32 indices, bool masks). Wire bytes are priced at the
     DEVICE dtype's width capped at 4: the device layer is f32/int32/bool
-    end to end, so a float64 host target still crossed the link as f32."""
+    end to end, so a float64 host target still crossed the link as f32.
+
+    `feed_link=False` is for the reads no dispatch decision was priced on
+    (a stripped key column, a histogram's occupancy probe): counted and
+    clocked like any other, but no sample for the EWMA that steers
+    `_adaptive_gate`."""
     if isinstance(x, np.ndarray):
         return np.asarray(x) if dtype is None else np.asarray(x, dtype)
+    clock = stats if isinstance(stats, RouteStats) else None
+    prev = clock.enter("device_wait") if clock is not None else None
     # wait for pending compute FIRST so the timing below is pure transfer
     # — folding compile/compute waits into the d2h latency EWMA would
     # poison the adaptive cost model. An async device failure surfaces
     # here, at the declared readback, and fails the query.
     x.block_until_ready()
+    if clock is not None:
+        clock.enter("readback")
     t0 = _time.perf_counter()
     arr = np.asarray(x) if dtype is None else np.asarray(x, dtype)
     wire = arr.size * min(x.dtype.itemsize, 4)
     if stats is not None:
         stats["d2h_bytes"] += wire
-    from parseable_tpu.ops.link import get_link
+    if feed_link:
+        from parseable_tpu.ops.link import get_link
 
-    get_link().record_d2h(wire, _time.perf_counter() - t0)
+        get_link().record_d2h(wire, _time.perf_counter() - t0)
+    if clock is not None:
+        clock.read_back(prev)
     return arr
 
 # blocks the adaptive dispatcher routed to the CPU because the measured
@@ -1190,21 +1280,9 @@ class TpuQueryExecutor(QueryExecutor):
         self.mesh = resolve_mesh(self.options)
         # per-query route observability (EXPLAIN ANALYZE surfaces this —
         # VERDICT r3 #10): how every scanned block was dispatched, plus
-        # the transfer bytes each direction actually cost
-        self.route_stats: dict[str, int] = {
-            "device_warm": 0,  # hot-set resident: zero bytes shipped
-            "device_cold": 0,  # encoded + shipped this query
-            "cpu_adaptive": 0,  # link cost model routed to host
-            "cpu_fallback": 0,  # declared UnsupportedOnDevice (incl. budgets)
-            "h2d_bytes": 0,
-            "d2h_bytes": 0,
-            # program-cache traffic (stages.programs reads these): builds
-            # this query, cache hits this query, rebuilds of a key that
-            # was already built once (0 in steady state)
-            "programs_built": 0,
-            "programs_reused": 0,
-            "recompiles": 0,
-        }
+        # the transfer bytes each direction actually cost, and the phase
+        # clock stages.execute is read from
+        self.route_stats = RouteStats()
         # query-aware prefetch (ops/prefetch.py): built lazily on the first
         # source-id'd block, once the scan has published its ordered stub
         # list; closed in execute()'s finally on every exit path
@@ -1228,6 +1306,10 @@ class TpuQueryExecutor(QueryExecutor):
             return self._execute_select_tpu(tables)
         finally:
             self._close_prefetcher()
+            # once a query, from the finished clock: never once a block
+            for phase, ns in self.route_stats.ns.items():
+                if ns:
+                    DEVICE_PHASE_SECONDS.labels(phase).inc(ns / 1e9)
 
     # ------------------------------------------------- select (mask on device)
 
@@ -1427,7 +1509,7 @@ class TpuQueryExecutor(QueryExecutor):
         from parseable_tpu.ops.link import warm_async
 
         warmer = copy.copy(self)
-        warmer.route_stats = dict.fromkeys(self.route_stats, 0)
+        warmer.route_stats = RouteStats()
         warmer._prefetcher, warmer._prefetch_tried = None, True
         warm_async(key, lambda t=table: warmer._encoded_block(t, needed, dict_cols))
 
@@ -1443,7 +1525,8 @@ class TpuQueryExecutor(QueryExecutor):
     def _encoded_block(
         self, table: pa.Table, needed: set[str] | None, dict_cols: set[str]
     ) -> tuple[EncodedBatch, dict]:
-        """Encode a table (or fetch its device-resident encoding).
+        """Encode a table (or fetch its device-resident encoding), on the
+        `encode` phase's clock.
 
         Resolution order per source-id'd block: device hot set (zero
         transfer) -> encoded-block disk cache (zero parquet decode /
@@ -1451,6 +1534,15 @@ class TpuQueryExecutor(QueryExecutor):
         writes-behind into the disk cache. Staging data (no source id)
         always encodes live.
         """
+        prev = self.route_stats.enter("encode")
+        try:
+            return self._resolve_block(table, needed, dict_cols)
+        finally:
+            self.route_stats.enter(prev)
+
+    def _resolve_block(
+        self, table: pa.Table, needed: set[str] | None, dict_cols: set[str]
+    ) -> tuple[EncodedBatch, dict]:
         hotset = get_hotset()
         meta = table.schema.metadata or {}
         source = meta.get(SOURCE_ID_META)
@@ -1515,6 +1607,7 @@ class TpuQueryExecutor(QueryExecutor):
         import jax.numpy as jnp
 
         sel = self.plan.select
+        rs = self.route_stats
         agg, rewritten, group_names = self.build_aggregator()
         specs = agg.specs
 
@@ -1609,7 +1702,9 @@ class TpuQueryExecutor(QueryExecutor):
                 (si, self._read_hist(h, num_groups))
                 for si, h in zip(pct_idx, pacc)
             ]
+            prev = rs.enter("partial")
             self._flush_state(arr, key_specs, agg, specs, lay, dists, pcts)
+            rs.enter(prev)
 
         # Coalesce scan tables into larger device blocks: dispatch latency is
         # the budget, so fewer/bigger blocks win (Options.device_block_rows).
@@ -1683,6 +1778,7 @@ class TpuQueryExecutor(QueryExecutor):
                 pct_cols=[specs[i].arg.name for i in pct_idx],
                 cnt_cols=[specs[i].arg.name for i in countcol_idx],
             )
+            prev = rs.enter("dispatch")
             try:
                 program = self._get_program(
                     enc0,
@@ -1704,12 +1800,15 @@ class TpuQueryExecutor(QueryExecutor):
                     tuple(x[5] for x in pending),
                     tuple(x[6] for x in pending),
                 )
+                rs.dispatched(prev)
                 dacc = list(dacc_out)
                 pacc = list(pacc_out)
                 pending.clear()
             except UnsupportedOnDevice as e:
+                rs.enter(None)  # the CPU's fold is no phase of the device path
                 logger.debug("pending blocks on CPU (%s)", e)
                 fold_pending_on_cpu()
+                rs.enter(prev)
 
         # block-local (two-phase) state: partial-format tables awaiting the
         # vectorized host merge (high-cardinality group spaces)
@@ -1779,209 +1878,235 @@ class TpuQueryExecutor(QueryExecutor):
         # this query's group space: stop paying encode+transfer per block
         # just to rediscover it — the rest of the scan is host-side
         force_cpu_rest = False
-        for table in blocks(tables):  # device-hot: per-block agg dispatch
-            self._check_deadline()
-            if force_cpu_rest:
-                self.route_stats["cpu_fallback"] += 1
-                cpu_block(table)
-                continue
-            # adaptive routing decides OUTSIDE the device-fallback try: the
-            # fallback handler re-aggregates the block, and a block that
-            # cpu_block already (even partially) folded must never reach it
-            if adaptive and not dkeys:
-                # two-phase (local) blocks read back a dense G-sized
-                # partial; the dense path reads back nothing per block
-                route, k0, _ = self._adaptive_gate(
-                    table,
-                    needed,
-                    dict_cols,
-                    link,
-                    hotset_obj,
-                    (
-                        (lambda r: min(r, LOCAL_G_MAX) * n_acc_rows * 4)
-                        if local_mode
-                        else (lambda r: 0)
-                    ),
-                )
-                if route:
-                    ADAPTIVE_CPU_BLOCKS[0] += 1
-                    self.route_stats["cpu_adaptive"] += 1
+        with TRACER.span("execute.blocks") as sp_blocks:
+            for table in blocks(tables):  # device-hot: per-block agg dispatch
+                rs.blocks += 1
+                self._check_deadline()
+                if force_cpu_rest:
+                    self.route_stats["cpu_fallback"] += 1
                     cpu_block(table)
-                    if k0 is not None:
-                        self._warm_block(k0, table, needed, dict_cols)
                     continue
-            try:
-                enc, dev = self._encoded_block(table, self.plan.needed_columns, dict_cols)
-                for i in stacked_idx + pct_idx:
-                    col = enc.columns.get(specs[i].arg.name)
-                    if col is None:
-                        raise UnsupportedOnDevice(f"aggregate column {specs[i].arg.name} missing")
-                    if col.kind in ("dict", "time") and i not in countcol_idx:
-                        raise UnsupportedOnDevice(f"numeric aggregate over {col.kind} column")
-                luts = compiler.collect_luts(sel.where, enc)
-                if local_mode:
-                    self._local_block(
-                        partials, enc, dev, luts, key_specs, specs, local_layout, lay,
+                # adaptive routing decides OUTSIDE the device-fallback try: the
+                # fallback handler re-aggregates the block, and a block that
+                # cpu_block already (even partially) folded must never reach it
+                if adaptive and not dkeys:
+                    # two-phase (local) blocks read back a dense G-sized
+                    # partial; the dense path reads back nothing per block
+                    route, k0, _ = self._adaptive_gate(
+                        table,
+                        needed,
+                        dict_cols,
+                        link,
+                        hotset_obj,
+                        (
+                            (lambda r: min(r, LOCAL_G_MAX) * n_acc_rows * 4)
+                            if local_mode
+                            else (lambda r: 0)
+                        ),
                     )
-                    continue
-                remaps = [
-                    ks.gdict.absorb(enc.columns[ks.column].dictionary)
-                    if ks.kind == "dict" and ks.column in enc.columns
-                    else None
-                    for ks in key_specs
-                ]
-                if any(r is None and ks.kind == "dict" for r, ks in zip(remaps, key_specs)):
-                    raise UnsupportedOnDevice("group key column missing from batch")
-                dremaps_np = []
-                for dk, sk in zip(dkeys, dk_sketch):
-                    col = enc.columns.get(dk.column)
-                    if col is None or col.kind != "dict":
-                        raise UnsupportedOnDevice(f"distinct column {dk.column} not dict-encoded")
-                    if sk:
-                        # HLL (idx, rank) LUT over THIS block's dictionary:
-                        # no global dictionary grows, cached per batch
-                        dremaps_np.append(self._hll_lut(enc, col))
-                    else:
-                        dremaps_np.append(dk.gdict.absorb(col.dictionary))
+                    if route:
+                        ADAPTIVE_CPU_BLOCKS[0] += 1
+                        self.route_stats["cpu_adaptive"] += 1
+                        cpu_block(table)
+                        if k0 is not None:
+                            self._warm_block(k0, table, needed, dict_cols)
+                        continue
+                try:
+                    enc, dev = self._encoded_block(table, self.plan.needed_columns, dict_cols)
+                    rs.enter("prepare")
+                    for i in stacked_idx + pct_idx:
+                        col = enc.columns.get(specs[i].arg.name)
+                        if col is None:
+                            raise UnsupportedOnDevice(f"aggregate column {specs[i].arg.name} missing")
+                        if col.kind in ("dict", "time") and i not in countcol_idx:
+                            raise UnsupportedOnDevice(f"numeric aggregate over {col.kind} column")
+                    luts = compiler.collect_luts(sel.where, enc)
+                    if local_mode:
+                        self._local_block(
+                            partials, enc, dev, luts, key_specs, specs, local_layout, lay,
+                        )
+                        continue
+                    remaps = [
+                        ks.gdict.absorb(enc.columns[ks.column].dictionary)
+                        if ks.kind == "dict" and ks.column in enc.columns
+                        else None
+                        for ks in key_specs
+                    ]
+                    if any(r is None and ks.kind == "dict" for r, ks in zip(remaps, key_specs)):
+                        raise UnsupportedOnDevice("group key column missing from batch")
+                    dremaps_np = []
+                    for dk, sk in zip(dkeys, dk_sketch):
+                        col = enc.columns.get(dk.column)
+                        if col is None or col.kind != "dict":
+                            raise UnsupportedOnDevice(f"distinct column {dk.column} not dict-encoded")
+                        if sk:
+                            # HLL (idx, rank) LUT over THIS block's dictionary:
+                            # no global dictionary grows, cached per batch
+                            dremaps_np.append(self._hll_lut(enc, col))
+                        else:
+                            dremaps_np.append(dk.gdict.absorb(col.dictionary))
 
-                layouts = [self._required_layout(ks, enc) for ks in key_specs]
-                caps = tuple(c for _, c in layouts)
-                origins = tuple(o for o, _ in layouts)
-                dlayouts = [
-                    (0, HLL_M) if sk else self._required_layout(dk, enc)
-                    for dk, sk in zip(dkeys, dk_sketch)
-                ]
-                dcaps = tuple(c for _, c in dlayouts)
-                new_groups = 1
-                for c in caps:
-                    new_groups *= c
-                new_groups = max(new_groups, 1)
-                # presence bitmaps are device-resident [G, Vcap] f32 each —
-                # bound the footprint, else fall back (exact) to the CPU.
-                # HLL register files have a FIXED cap (HLL_M) so they get a
-                # larger budget (1<<27 slots = 512 MB f32 -> G up to 32k):
-                # group count, not value cardinality, is their only axis
-                if any(
-                    new_groups * c > ((1 << 27) if sk else (1 << 24))
-                    for c, sk in zip(dcaps, dk_sketch)
-                ):
-                    # caps only grow (gdict.absorb is monotonic; the group
-                    # space only widens): no later block can fit either,
-                    # so stop paying encode+transfer
-                    force_cpu_rest = True
-                    raise UnsupportedOnDevice(
-                        "distinct state exceeds device budget (G*V too large)"
-                    )
-                # percentile histograms are [G, DEVICE_NB] f32 each; past
-                # the footprint budget the whole scan aggregates host-side
-                # (exact sketches) rather than thrashing device HBM
-                if pct_idx and new_groups * DEVICE_NB > PCT_MAX_ELEMS:
-                    force_cpu_rest = True
-                    raise UnsupportedOnDevice(
-                        "percentile histogram exceeds device budget (G too large)"
-                    )
-                if new_groups > DENSE_G_MAX:
-                    # the dense global group space outgrew the device budget:
-                    # switch to block-local two-phase aggregation for the
-                    # rest of the scan (exact; no capacity-epoch churn)
-                    if dkeys or pct_idx:
+                    layouts = [self._required_layout(ks, enc) for ks in key_specs]
+                    caps = tuple(c for _, c in layouts)
+                    origins = tuple(o for o, _ in layouts)
+                    dlayouts = [
+                        (0, HLL_M) if sk else self._required_layout(dk, enc)
+                        for dk, sk in zip(dkeys, dk_sketch)
+                    ]
+                    dcaps = tuple(c for _, c in dlayouts)
+                    new_groups = 1
+                    for c in caps:
+                        new_groups *= c
+                    new_groups = max(new_groups, 1)
+                    # presence bitmaps are device-resident [G, Vcap] f32 each —
+                    # bound the footprint, else fall back (exact) to the CPU.
+                    # HLL register files have a FIXED cap (HLL_M) so they get a
+                    # larger budget (1<<27 slots = 512 MB f32 -> G up to 32k):
+                    # group count, not value cardinality, is their only axis
+                    if any(
+                        new_groups * c > ((1 << 27) if sk else (1 << 24))
+                        for c, sk in zip(dcaps, dk_sketch)
+                    ):
+                        # caps only grow (gdict.absorb is monotonic; the group
+                        # space only widens): no later block can fit either,
+                        # so stop paying encode+transfer
                         force_cpu_rest = True
                         raise UnsupportedOnDevice(
-                            "high-cardinality group space with sketch/set state"
+                            "distinct state exceeds device budget (G*V too large)"
                         )
-                    dispatch_pending()
-                    if acc is not None:
-                        pt = self._dense_to_partial(
-                            acc, acc_groups, key_specs, specs, lay,
+                    # percentile histograms are [G, DEVICE_NB] f32 each; past
+                    # the footprint budget the whole scan aggregates host-side
+                    # (exact sketches) rather than thrashing device HBM
+                    if pct_idx and new_groups * DEVICE_NB > PCT_MAX_ELEMS:
+                        force_cpu_rest = True
+                        raise UnsupportedOnDevice(
+                            "percentile histogram exceeds device budget (G too large)"
                         )
-                        if pt is not None:
-                            partials.append(pt)
-                        acc = None
-                        dacc = []
-                    local_mode = True
-                    logger.info(
-                        "group space %d exceeds dense budget; block-local two-phase mode",
-                        new_groups,
-                    )
-                    self._local_block(
-                        partials, enc, dev, luts, key_specs, specs, local_layout, lay,
-                    )
-                    continue
-                current = tuple((ks.origin_rel or 0, ks.capacity) for ks in key_specs)
-                dcurrent = tuple(dk.capacity for dk in dkeys)
-                if acc is None or tuple(zip(origins, caps)) != current or dcaps != dcurrent:
-                    dispatch_pending()  # under the old epoch's layout
-                    if acc is not None:
-                        if distinct_idx or pct_idx:
-                            # distinct bitmaps / percentile histograms
-                            # decode through the sparse agg
-                            flush(acc, acc_groups)
-                        else:
-                            # vectorized epoch flush: no per-group Python
+                    if new_groups > DENSE_G_MAX:
+                        # the dense global group space outgrew the device budget:
+                        # switch to block-local two-phase aggregation for the
+                        # rest of the scan (exact; no capacity-epoch churn)
+                        if dkeys or pct_idx:
+                            force_cpu_rest = True
+                            raise UnsupportedOnDevice(
+                                "high-cardinality group space with sketch/set state"
+                            )
+                        dispatch_pending()
+                        if acc is not None:
                             pt = self._dense_to_partial(
                                 acc, acc_groups, key_specs, specs, lay,
                             )
                             if pt is not None:
                                 partials.append(pt)
-                    for ks, (o, c) in zip(key_specs, layouts):
-                        ks.capacity = c
-                        ks.origin_rel = o if ks.kind == "timebin" else None
-                    for dk, c in zip(dkeys, dcaps):
-                        dk.capacity = c
-                    acc_groups = new_groups
-                    acc = new_acc(acc_groups)
-                    dacc = [new_flat(acc_groups * c) for c in dcaps]
-                    pacc = [new_flat(acc_groups * DEVICE_NB) for _ in pct_idx]
+                            acc = None
+                            dacc = []
+                        local_mode = True
+                        logger.info(
+                            "group space %d exceeds dense budget; block-local two-phase mode",
+                            new_groups,
+                        )
+                        self._local_block(
+                            partials, enc, dev, luts, key_specs, specs, local_layout, lay,
+                        )
+                        continue
+                    current = tuple((ks.origin_rel or 0, ks.capacity) for ks in key_specs)
+                    dcurrent = tuple(dk.capacity for dk in dkeys)
+                    if acc is None or tuple(zip(origins, caps)) != current or dcaps != dcurrent:
+                        dispatch_pending()  # under the old epoch's layout
+                        if acc is not None:
+                            if distinct_idx or pct_idx:
+                                # distinct bitmaps / percentile histograms
+                                # decode through the sparse agg
+                                flush(acc, acc_groups)
+                            else:
+                                # vectorized epoch flush: no per-group Python
+                                pt = self._dense_to_partial(
+                                    acc, acc_groups, key_specs, specs, lay,
+                                )
+                                if pt is not None:
+                                    partials.append(pt)
+                        for ks, (o, c) in zip(key_specs, layouts):
+                            ks.capacity = c
+                            ks.origin_rel = o if ks.kind == "timebin" else None
+                        for dk, c in zip(dkeys, dcaps):
+                            dk.capacity = c
+                        acc_groups = new_groups
+                        acc = new_acc(acc_groups)
+                        dacc = [new_flat(acc_groups * c) for c in dcaps]
+                        pacc = [new_flat(acc_groups * DEVICE_NB) for _ in pct_idx]
 
-                # per-block time scalars (bin shift/offset + bounds) append
-                # after the predicate LUTs; the fold consumes them from the
-                # tail so one compiled program serves every block origin
-                luts = luts + self._time_args(
-                    enc,
-                    key_specs,
-                    tuple(ks.origin_rel or 0 for ks in key_specs),
-                    self._bounds_ms(),
-                )
-                kinds = tuple(sorted((n, c.kind) for n, c in enc.columns.items()))
-                sig = (
-                    (enc.block_rows, kinds, "__rowmask" in dev),
-                    tuple(l.shape for l in luts),
-                    tuple(r.shape if r is not None else None for r in remaps),
-                    tuple(r.shape for r in dremaps_np),
-                )
-                if pending and sig != pending_sig:
-                    dispatch_pending()
-                pending_sig = sig
-                if self.mesh is not None:
-                    import jax
+                    # per-block time scalars (bin shift/offset + bounds) append
+                    # after the predicate LUTs; the fold consumes them from the
+                    # tail so one compiled program serves every block origin
+                    luts = luts + self._time_args(
+                        enc,
+                        key_specs,
+                        tuple(ks.origin_rel or 0 for ks in key_specs),
+                        self._bounds_ms(),
+                    )
+                    kinds = tuple(sorted((n, c.kind) for n, c in enc.columns.items()))
+                    sig = (
+                        (enc.block_rows, kinds, "__rowmask" in dev),
+                        tuple(l.shape for l in luts),
+                        tuple(r.shape if r is not None else None for r in remaps),
+                        tuple(r.shape for r in dremaps_np),
+                    )
+                    if pending and sig != pending_sig:
+                        dispatch_pending()
+                    pending_sig = sig
+                    if self.mesh is not None:
+                        import jax
 
-                    _, rep_s = _mesh_shardings(self.mesh)
+                        _, rep_s = _mesh_shardings(self.mesh)
 
-                    def put_rep(a, _s=rep_s, _jax=jax):
-                        # priced: LUT/remap ships ride outside _transfer's
-                        # packed payload, so the link accounting must see
-                        # them here (no latency sample — the puts are async
-                        # and a probe would serialize the batch loop)
-                        n = int(getattr(a, "nbytes", 0))
-                        self.route_stats["h2d_bytes"] += n
-                        DEVICE_BYTES_TO_DEVICE.labels("lut").inc(n)
-                        return _jax.device_put(a, _s)
-                else:
-                    put_rep = jnp.asarray
-                dev_luts = tuple(put_rep(l) for l in luts)
-                dev_remaps = tuple(put_rep(r) for r in remaps if r is not None)
-                dev_dremaps = tuple(put_rep(r) for r in dremaps_np)
-                row_mask = dev.get("__rowmask", dev["__ones"])
-                pending.append((table, enc, dev, dev_luts, dev_remaps, dev_dremaps, row_mask))
-                if len(pending) >= GROUP_N:
-                    dispatch_pending()
-            except UnsupportedOnDevice as e:
-                logger.debug("batch on CPU (%s)", e)
-                self.route_stats["cpu_fallback"] += 1
-                t = self._bounds_filter(self._materialize(table))
-                agg.update(t, self._where_mask(t))
+                        def put_rep(a, _s=rep_s, _jax=jax):
+                            # priced: LUT/remap ships ride outside _transfer's
+                            # packed payload, so the link accounting must see
+                            # them here (no latency sample — the puts are async
+                            # and a probe would serialize the batch loop)
+                            n = int(getattr(a, "nbytes", 0))
+                            self.route_stats["h2d_bytes"] += n
+                            DEVICE_BYTES_TO_DEVICE.labels("lut").inc(n)
+                            return _jax.device_put(a, _s)
+                    else:
+                        put_rep = jnp.asarray
+                    dev_luts = tuple(put_rep(l) for l in luts)
+                    dev_remaps = tuple(put_rep(r) for r in remaps if r is not None)
+                    dev_dremaps = tuple(put_rep(r) for r in dremaps_np)
+                    row_mask = dev.get("__rowmask", dev["__ones"])
+                    pending.append((table, enc, dev, dev_luts, dev_remaps, dev_dremaps, row_mask))
+                    if len(pending) >= GROUP_N:
+                        dispatch_pending()
+                except UnsupportedOnDevice as e:
+                    rs.enter(None)  # the CPU's fold is no phase of the device path
+                    logger.debug("batch on CPU (%s)", e)
+                    self.route_stats["cpu_fallback"] += 1
+                    t = self._bounds_filter(self._materialize(table))
+                    agg.update(t, self._where_mask(t))
+                finally:
+                    # the clock stands while the scan produces the next block
+                    rs.enter(None)
 
-        dispatch_pending()
+            dispatch_pending()
+            sp_blocks["rows"] = rs.blocks
+        def finish(interim: pa.Table | None) -> pa.Table:
+            """Every exit: the group-by's host wall time up to here (scan,
+            dispatch, device waits, readbacks and host merge, all of it),
+            then the projection / HAVING / ORDER BY over the interim table,
+            or over the sparse aggregator where there is none."""
+            DEVICE_EXECUTE_TIME.labels("groupby").observe(_t.monotonic() - t_start)
+            with TRACER.span("execute.finalize") as sp:
+                prev = rs.enter("finalize")
+                try:
+                    if interim is None:
+                        out = self.finalize_aggregate(agg, rewritten, group_names)
+                    else:
+                        out = self.finalize_from_interim(interim, rewritten)
+                finally:
+                    rs.enter(prev)
+                sp["rows"] = out.num_rows
+                return out
+
         if partials or (local_mode and (acc is not None or agg.groups)):
             # two-phase finalize: dense epoch + device block partials +
             # CPU-fallback groups all merge through ONE pyarrow group_by
@@ -1992,12 +2117,18 @@ class TpuQueryExecutor(QueryExecutor):
                 if pt is not None:
                     partials.append(pt)
                 acc = None
+            prev = rs.enter("partial")
             apt = self._agg_groups_to_partial(agg, specs, len(key_specs))
+            rs.enter(prev)
             if apt is not None:
                 partials.append(apt)
-            interim = self._merge_partials(partials, specs, len(key_specs))
-            DEVICE_EXECUTE_TIME.labels("groupby").observe(_t.monotonic() - t_start)
-            return self.finalize_from_interim(interim, rewritten)
+            with TRACER.span("execute.merge", rows=sum(p.num_rows for p in partials)):
+                prev = rs.enter("merge")
+                try:
+                    interim = self._merge_partials(partials, specs, len(key_specs))
+                finally:
+                    rs.enter(prev)
+            return finish(interim)
         # vectorized dense finalize: when the run stayed fully on device
         # (no CPU-fallback partials, no distinct sets), skip the per-group
         # Python fold entirely — at G=32k the sparse path is ~80% of query
@@ -2017,33 +2148,36 @@ class TpuQueryExecutor(QueryExecutor):
                 and topk_req[2] < acc_groups
             ):
                 tsi, tdesc, tk = topk_req
-                arr_k, ids = self._run_topk_program(
-                    acc, tsi, tdesc, tk, lay, specs,
-                )
+                with TRACER.span("execute.topk", rows=tk):
+                    arr_k, ids = self._run_topk_program(
+                        acc, tsi, tdesc, tk, lay, specs,
+                    )
+                prev = rs.enter("partial")
                 interim = self._dense_interim(
                     arr_k, acc_groups, key_specs, specs, lay,
                     group_ids=ids,
                 )
-                DEVICE_EXECUTE_TIME.labels("groupby").observe(
-                    _t.monotonic() - t_start
-                )
-                return self.finalize_from_interim(interim, rewritten)
+                rs.enter(prev)
+                return finish(interim)
             pcts = [
                 (si, self._read_hist(h, acc_groups))
                 for si, h in zip(pct_idx, pacc)
             ]
+            with TRACER.span("execute.readback", rows=acc_groups) as sp:
+                before = rs["d2h_bytes"]
+                arr = _timed_readback(acc, rs)
+                sp["bytes"] = rs["d2h_bytes"] - before
+            prev = rs.enter("partial")
             interim = self._dense_interim(
-                _timed_readback(acc, self.route_stats), acc_groups, key_specs,
-                specs, lay, pcts=pcts,
+                arr, acc_groups, key_specs, specs, lay, pcts=pcts,
             )
-            DEVICE_EXECUTE_TIME.labels("groupby").observe(_t.monotonic() - t_start)
+            rs.enter(prev)
             if interim.num_rows == 0 and not sel.group_by:
-                return self.finalize_aggregate(agg, rewritten, group_names)
-            return self.finalize_from_interim(interim, rewritten)
+                return finish(None)
+            return finish(interim)
         if acc is not None:
             flush(acc, acc_groups)
-        DEVICE_EXECUTE_TIME.labels("groupby").observe(_t.monotonic() - t_start)
-        return self.finalize_aggregate(agg, rewritten, group_names)
+        return finish(None)
 
     def _dense_interim(
         self,
@@ -2202,58 +2336,61 @@ class TpuQueryExecutor(QueryExecutor):
             val_row = pac_row
         sq_row = lay.sqm2_row(si) if kind in ("stddev", "var") else 0
         key = ("topk", acc.shape, kind, val_row, pac_row, sq_row, desc, k)
+        prev = self.route_stats.enter("dispatch")
         program = _PROGRAM_CACHE.get(key)
         if program is None:
 
             def run(a):
-                count = a[0]
-                pacv = a[pac_row]
-                if kind == "avg":
-                    keyv = a[val_row] / jnp.maximum(pacv, 1.0)
-                elif kind in ("stddev", "var"):
-                    n = jnp.maximum(pacv, 2.0)
-                    keyv = jnp.maximum(a[sq_row] / (n - 1.0), 0.0)
-                    if kind == "stddev":
-                        keyv = jnp.sqrt(keyv)
-                else:
-                    keyv = a[val_row]
-                if kind in ("sum", "avg", "min", "max"):
-                    notnull = pacv > 0
-                elif kind in ("stddev", "var"):
-                    notnull = pacv > 1  # n < 2 -> NULL variance
-                else:
-                    notnull = count > 0
-                occupied = count > 0
-                live = occupied & notnull
-                # Exact composite order in int32 (ADVICE r3 #1: a finite
-                # f32 sentinel let -inf/-3.4e38 real keys be displaced by
-                # NULL groups). The f32 bit pattern maps to a monotonic
-                # int32 whose range [-2139095040, 2139095040] (-inf..+inf)
-                # leaves headroom below for NaN keys, NULL-agg groups and
-                # empty slots — in that (nulls-last) order. top_k over the
-                # int32 scores is then a true three-class lexicographic
-                # sort with zero collisions against real keys.
-                kf = keyv.astype(jnp.float32)
-                nan = jnp.isnan(kf)
-                bits = jax.lax.bitcast_convert_type(kf, jnp.int32)
-                u = jnp.where(bits >= 0, bits, jnp.int32(-2147483648) - bits)
-                o = u if desc else jnp.where(
-                    u == jnp.int32(-2147483648), jnp.int32(2147483647), -u
-                )
-                score = jnp.where(
-                    live & ~nan,
-                    o,
-                    jnp.where(
-                        live, jnp.int32(-2139095339),  # NaN key: below reals
+                with jax.named_scope("topk"):
+                    count = a[0]
+                    pacv = a[pac_row]
+                    if kind == "avg":
+                        keyv = a[val_row] / jnp.maximum(pacv, 1.0)
+                    elif kind in ("stddev", "var"):
+                        n = jnp.maximum(pacv, 2.0)
+                        keyv = jnp.maximum(a[sq_row] / (n - 1.0), 0.0)
+                        if kind == "stddev":
+                            keyv = jnp.sqrt(keyv)
+                    else:
+                        keyv = a[val_row]
+                    if kind in ("sum", "avg", "min", "max"):
+                        notnull = pacv > 0
+                    elif kind in ("stddev", "var"):
+                        notnull = pacv > 1  # n < 2 -> NULL variance
+                    else:
+                        notnull = count > 0
+                    occupied = count > 0
+                    live = occupied & notnull
+                    # Exact composite order in int32 (ADVICE r3 #1: a finite
+                    # f32 sentinel let -inf/-3.4e38 real keys be displaced by
+                    # NULL groups). The f32 bit pattern maps to a monotonic
+                    # int32 whose range [-2139095040, 2139095040] (-inf..+inf)
+                    # leaves headroom below for NaN keys, NULL-agg groups and
+                    # empty slots — in that (nulls-last) order. top_k over the
+                    # int32 scores is then a true three-class lexicographic
+                    # sort with zero collisions against real keys.
+                    kf = keyv.astype(jnp.float32)
+                    nan = jnp.isnan(kf)
+                    bits = jax.lax.bitcast_convert_type(kf, jnp.int32)
+                    u = jnp.where(bits >= 0, bits, jnp.int32(-2147483648) - bits)
+                    o = u if desc else jnp.where(
+                        u == jnp.int32(-2147483648), jnp.int32(2147483647), -u
+                    )
+                    score = jnp.where(
+                        live & ~nan,
+                        o,
                         jnp.where(
-                            occupied, jnp.int32(-2147483647),  # NULL agg
-                            jnp.int32(-2147483648),  # empty slot
+                            live, jnp.int32(-2139095339),  # NaN key: below reals
+                            jnp.where(
+                                occupied, jnp.int32(-2147483647),  # NULL agg
+                                jnp.int32(-2147483648),  # empty slot
+                            ),
                         ),
-                    ),
-                )
-                _, idx = jax.lax.top_k(score, k)
-                return a[:, idx], idx
+                    )
+                    _, idx = jax.lax.top_k(score, k)
+                    return a[:, idx], idx
 
+            run.__name__ = run.__qualname__ = "executor_topk"  # XLA module jit_executor_topk
             # no donate_argnums: `acc` outlives the top-k (the flush path
             # reads it); see the executor.dense note in _get_program
             program = jax.jit(run)  # jit-cache: executor.topk
@@ -2262,6 +2399,7 @@ class TpuQueryExecutor(QueryExecutor):
         else:
             self.route_stats["programs_reused"] += 1
         gathered, idx = program(acc)
+        self.route_stats.dispatched(prev)
         return (
             _timed_readback(gathered, self.route_stats),
             _timed_readback(idx, self.route_stats, dtype=None),
@@ -2285,6 +2423,8 @@ class TpuQueryExecutor(QueryExecutor):
         nonzero groups as a partial-format table."""
         import jax.numpy as jnp
 
+        rs = self.route_stats
+        prev = rs.enter("prepare")
         caps: list[int] = []
         origins: list[int] = []
         keyinfo: list[tuple] = []
@@ -2345,7 +2485,7 @@ class TpuQueryExecutor(QueryExecutor):
             # np.unique and fold on dense pair codes instead
             comp = None
             for ks, cap, origin in zip(key_specs, caps, origins):
-                vals = self._host_codes(enc, dev, ks.column)
+                vals = self._host_codes(enc, dev, ks.column, rs)
                 if ks.kind == "dict":
                     codes = np.minimum(vals.astype(np.int64), cap - 1)
                 else:
@@ -2370,6 +2510,7 @@ class TpuQueryExecutor(QueryExecutor):
             full_luts = luts + self._time_args(enc, [], (), self._bounds_ms())
         dev_luts = tuple(put_rep(l) for l in full_luts)
 
+        rs.enter("dispatch")
         program = self._get_local_program(
             enc,
             tuple(caps),
@@ -2380,10 +2521,13 @@ class TpuQueryExecutor(QueryExecutor):
             tuple(sorted(dev.keys())),
             num_groups,
         )
-        out = _timed_readback(program(dev, dev_luts, row_mask), self.route_stats)
+        out_dev = program(dev, dev_luts, row_mask)
+        rs.dispatched("partial")
+        out = _timed_readback(out_dev, rs)
         pt = self._partial_from_arrays(
             out, lay, keyinfo, specs, composite_vals=composite_vals,
         )
+        rs.enter(prev)
         if pt is not None:
             partials.append(pt)
 
@@ -2407,17 +2551,20 @@ class TpuQueryExecutor(QueryExecutor):
         return hit
 
     @staticmethod
-    def _host_codes(enc: EncodedBatch, dev: dict, column: str) -> np.ndarray:
+    def _host_codes(
+        enc: EncodedBatch, dev: dict, column: str, stats: dict | None = None
+    ) -> np.ndarray:
         """A column's encoded codes on host: the encode-time array when it
-        still exists, else a readback (hot-set entries strip host copies)."""
+        still exists, else a readback (hot-set entries strip host copies,
+        so for a warm block this is every call). Its bytes and time are
+        `stats`'s; the link EWMA gets no sample, since no routing decision
+        priced this read."""
         col = enc.columns.get(column)
         if col is None:
             raise UnsupportedOnDevice(f"group key column {column} missing")
         if col.values is not None and len(col.values):
             return col.values
-        # rare readback — hot-set entries strip host copies, so
-        # sync-boundary: re-materializing the codes is the only source left
-        return np.asarray(dev[column])
+        return _timed_readback(dev[column], stats, dtype=None, feed_link=False)
 
     def _get_local_program(
         self,
@@ -2477,58 +2624,61 @@ class TpuQueryExecutor(QueryExecutor):
             # per-block time scalars ride the tail of the luts tuple
             # (_time_args layout); trace consumes the head
             extra = list(luts[len(luts) - n_time_args :]) if n_time_args else []
-            mask = compiler.trace(
-                sel_where, enc, dev, list(luts[: len(luts) - n_time_args])
-            )
-            mask = jnp.logical_and(mask, row_mask)
-            if n_bounds and DEFAULT_TIMESTAMP_KEY in enc.columns:
-                ts = dev[DEFAULT_TIMESTAMP_KEY]
-                bi = 2 * n_timebin
-                if bounds_ms[0] is not None:
-                    mask = jnp.logical_and(mask, ts >= extra[bi][0])
-                    bi += 1
-                if bounds_ms[1] is not None:
-                    mask = jnp.logical_and(mask, ts < extra[bi][0])
-                mask = jnp.logical_and(mask, dev[f"{DEFAULT_TIMESTAMP_KEY}__valid"])
-            if key_sig and key_sig[0][0] == "pair":
-                # host-compacted composite codes (multi-key high cardinality)
-                ids = jnp.minimum(dev["__pairkey"], num_groups - 1)
-            else:
-                ids = None
-                stride = 1
-                ti = 0
-                for (kind, column, bin_ms), cap in zip(key_sig, caps):
-                    if kind == "dict":
-                        codes = jnp.minimum(dev[column], cap - 1)
-                    else:
-                        shift, k_off = extra[ti][0], extra[ti + 1][0]
-                        ti += 2
-                        codes = jnp.clip(
-                            (dev[column] + shift) // jnp.int32(bin_ms) + k_off,
-                            0,
-                            cap - 1,
-                        )
-                    part = codes * jnp.int32(stride)
-                    ids = part if ids is None else ids + part
-                    stride *= cap
-                ids = (ids if ids is not None else jnp.zeros(local_rows, jnp.int32)).astype(jnp.int32)
-            ids = ids.astype(jnp.int32)
+            with jax.named_scope("where"):
+                mask = compiler.trace(
+                    sel_where, enc, dev, list(luts[: len(luts) - n_time_args])
+                )
+                mask = jnp.logical_and(mask, row_mask)
+                if n_bounds and DEFAULT_TIMESTAMP_KEY in enc.columns:
+                    ts = dev[DEFAULT_TIMESTAMP_KEY]
+                    bi = 2 * n_timebin
+                    if bounds_ms[0] is not None:
+                        mask = jnp.logical_and(mask, ts >= extra[bi][0])
+                        bi += 1
+                    if bounds_ms[1] is not None:
+                        mask = jnp.logical_and(mask, ts < extra[bi][0])
+                    mask = jnp.logical_and(mask, dev[f"{DEFAULT_TIMESTAMP_KEY}__valid"])
+            with jax.named_scope("keys"):
+                if key_sig and key_sig[0][0] == "pair":
+                    # host-compacted composite codes (multi-key high cardinality)
+                    ids = jnp.minimum(dev["__pairkey"], num_groups - 1)
+                else:
+                    ids = None
+                    stride = 1
+                    ti = 0
+                    for (kind, column, bin_ms), cap in zip(key_sig, caps):
+                        if kind == "dict":
+                            codes = jnp.minimum(dev[column], cap - 1)
+                        else:
+                            shift, k_off = extra[ti][0], extra[ti + 1][0]
+                            ti += 2
+                            codes = jnp.clip(
+                                (dev[column] + shift) // jnp.int32(bin_ms) + k_off,
+                                0,
+                                cap - 1,
+                            )
+                        part = codes * jnp.int32(stride)
+                        ids = part if ids is None else ids + part
+                        stride *= cap
+                    ids = (ids if ids is not None else jnp.zeros(local_rows, jnp.int32)).astype(jnp.int32)
+                ids = ids.astype(jnp.int32)
 
-            sum_v, min_v, max_v, valid_v, n_sumk, n_mink, n_maxk = _kernel_stacks(
-                dev, layout, local_rows
-            )
-            count, pac, sums, mins, maxs = kernels.fused_groupby_block(
-                ids,
-                mask,
-                sum_v,
-                min_v,
-                max_v,
-                valid_v,
-                num_groups,
-                n_sumk,
-                n_mink,
-                n_maxk,
-            )
+            with jax.named_scope("fold"):
+                sum_v, min_v, max_v, valid_v, n_sumk, n_mink, n_maxk = _kernel_stacks(
+                    dev, layout, local_rows
+                )
+                count, pac, sums, mins, maxs = kernels.fused_groupby_block(
+                    ids,
+                    mask,
+                    sum_v,
+                    min_v,
+                    max_v,
+                    valid_v,
+                    num_groups,
+                    n_sumk,
+                    n_mink,
+                    n_maxk,
+                )
             m2_loc, m2_n, m2_s = _block_m2(
                 dev, layout, ids, mask, pac, sums, num_groups
             )
@@ -2559,6 +2709,7 @@ class TpuQueryExecutor(QueryExecutor):
         else:
             body = fold
 
+        body.__name__ = body.__qualname__ = "executor_local"  # XLA module jit_executor_local
         # no donate_argnums here either — see the executor.dense note in
         # _get_program
         prog = jax.jit(body)  # jit-cache: executor.local
@@ -2658,13 +2809,16 @@ class TpuQueryExecutor(QueryExecutor):
         block-local mode mid-query: the dense epoch's results merge through
         the same vectorized group_by as the block partials)."""
         arr = _timed_readback(acc, self.route_stats)
+        prev = self.route_stats.enter("partial")
         keyinfo: list[tuple] = []
         for ks in key_specs:
             if ks.kind == "dict":
                 keyinfo.append(("dict", ks.epoch_values() + [None], ks.capacity))
             else:
                 keyinfo.append(("timebin", ks.origin_rel or 0, ks.bin_ms, ks.capacity))
-        return self._partial_from_arrays(arr, lay, keyinfo, specs)
+        pt = self._partial_from_arrays(arr, lay, keyinfo, specs)
+        self.route_stats.enter(prev)
+        return pt
 
     def _read_hist(self, h, num_groups: int) -> np.ndarray:
         """Percentile-histogram readback: flat [G * DEVICE_NB] device f32
@@ -2680,18 +2834,17 @@ class TpuQueryExecutor(QueryExecutor):
 
         total = num_groups * DEVICE_NB
         if self.mesh is not None or total <= (1 << 20):
-            return np.asarray(
-                _timed_readback(h, self.route_stats)
-            ).reshape(num_groups, DEVICE_NB)
+            return _timed_readback(h, self.route_stats).reshape(num_groups, DEVICE_NB)
         mat = h.reshape(num_groups, DEVICE_NB)
-        # NB-sized (~8 KB) occupancy probe gating a readback 10-50x larger
-        # sync-boundary: when sparse — the probe pays for itself
-        colsum = np.asarray(jnp.sum(mat, axis=0))
+        # NB-sized (~8 KB) occupancy probe gating a readback 10-50x larger:
+        # when sparse the probe pays for itself. Counted and clocked, but no
+        # sample for the link EWMA (the gate priced the histogram, not this)
+        colsum = _timed_readback(
+            jnp.sum(mat, axis=0), self.route_stats, dtype=None, feed_link=False
+        )
         active = np.nonzero(colsum > 0)[0]
         if len(active) * 2 >= DEVICE_NB:
-            return np.asarray(
-                _timed_readback(h, self.route_stats)
-            ).reshape(num_groups, DEVICE_NB)
+            return _timed_readback(h, self.route_stats).reshape(num_groups, DEVICE_NB)
         out = np.zeros((num_groups, DEVICE_NB))
         if len(active):
             gathered = _timed_readback(mat[:, jnp.asarray(active)], self.route_stats)
@@ -2850,69 +3003,72 @@ class TpuQueryExecutor(QueryExecutor):
             # per-block time scalars ride the tail of the luts tuple
             # (_time_args layout); trace consumes the head
             extra = list(luts[len(luts) - n_time_args :]) if n_time_args else []
-            mask = compiler.trace(
-                sel_where, enc, dev, list(luts[: len(luts) - n_time_args])
-            )
-            mask = jnp.logical_and(mask, row_mask)
-            if n_bounds and DEFAULT_TIMESTAMP_KEY in enc.columns:
-                ts = dev[DEFAULT_TIMESTAMP_KEY]
-                bi = 2 * n_timebin
-                if bounds_ms[0] is not None:
-                    mask = jnp.logical_and(mask, ts >= extra[bi][0])
-                    bi += 1
-                if bounds_ms[1] is not None:
-                    mask = jnp.logical_and(mask, ts < extra[bi][0])
-                mask = jnp.logical_and(mask, dev[f"{DEFAULT_TIMESTAMP_KEY}__valid"])
-            if not key_specs:
-                ids = jnp.zeros(local_rows, dtype=jnp.int32)
-            else:
-                ids = None
-                stride = 1
-                ri = 0
-                ti = 0
-                for ks in key_specs:
-                    cap = ks.capacity
-                    if ks.kind == "dict":
-                        codes = jnp.minimum(remaps[ri][_as_index(dev[ks.column])], cap - 1)
-                        ri += 1
-                    else:
-                        shift, k_off = extra[ti][0], extra[ti + 1][0]
-                        ti += 2
-                        codes = jnp.clip(
-                            (dev[ks.column] + shift) // jnp.int32(ks.bin_ms) + k_off,
-                            0,
-                            cap - 1,
-                        )
-                    part = codes * jnp.int32(stride)
-                    ids = part if ids is None else ids + part
-                    stride *= cap
-                ids = ids.astype(jnp.int32)
+            with jax.named_scope("where"):
+                mask = compiler.trace(
+                    sel_where, enc, dev, list(luts[: len(luts) - n_time_args])
+                )
+                mask = jnp.logical_and(mask, row_mask)
+                if n_bounds and DEFAULT_TIMESTAMP_KEY in enc.columns:
+                    ts = dev[DEFAULT_TIMESTAMP_KEY]
+                    bi = 2 * n_timebin
+                    if bounds_ms[0] is not None:
+                        mask = jnp.logical_and(mask, ts >= extra[bi][0])
+                        bi += 1
+                    if bounds_ms[1] is not None:
+                        mask = jnp.logical_and(mask, ts < extra[bi][0])
+                    mask = jnp.logical_and(mask, dev[f"{DEFAULT_TIMESTAMP_KEY}__valid"])
+            with jax.named_scope("keys"):
+                if not key_specs:
+                    ids = jnp.zeros(local_rows, dtype=jnp.int32)
+                else:
+                    ids = None
+                    stride = 1
+                    ri = 0
+                    ti = 0
+                    for ks in key_specs:
+                        cap = ks.capacity
+                        if ks.kind == "dict":
+                            codes = jnp.minimum(remaps[ri][_as_index(dev[ks.column])], cap - 1)
+                            ri += 1
+                        else:
+                            shift, k_off = extra[ti][0], extra[ti + 1][0]
+                            ti += 2
+                            codes = jnp.clip(
+                                (dev[ks.column] + shift) // jnp.int32(ks.bin_ms) + k_off,
+                                0,
+                                cap - 1,
+                            )
+                        part = codes * jnp.int32(stride)
+                        ids = part if ids is None else ids + part
+                        stride *= cap
+                    ids = ids.astype(jnp.int32)
 
-            # group-sharded (2D) layout: this device owns one contiguous
-            # window of the group space; rows outside it mask off instead
-            # of routing (parallel/mesh.py distributed_groupby_2d design)
-            if shard_groups > 1:
-                gshard = jax.lax.axis_index("groups")
-                local = ids - gshard * jnp.int32(kernel_groups)
-                in_window = jnp.logical_and(local >= 0, local < kernel_groups)
-                mask = jnp.logical_and(mask, in_window)
-                ids = jnp.clip(local, 0, kernel_groups - 1)
+                # group-sharded (2D) layout: this device owns one contiguous
+                # window of the group space; rows outside it mask off instead
+                # of routing (parallel/mesh.py distributed_groupby_2d design)
+                if shard_groups > 1:
+                    gshard = jax.lax.axis_index("groups")
+                    local = ids - gshard * jnp.int32(kernel_groups)
+                    in_window = jnp.logical_and(local >= 0, local < kernel_groups)
+                    mask = jnp.logical_and(mask, in_window)
+                    ids = jnp.clip(local, 0, kernel_groups - 1)
 
-            sum_v, min_v, max_v, valid_v, n_sumk, n_mink, n_maxk = _kernel_stacks(
-                dev, layout, local_rows
-            )
-            count, pac, sums, mins, maxs = kernels.fused_groupby_block(
-                ids,
-                mask,
-                sum_v,
-                min_v,
-                max_v,
-                valid_v,
-                kernel_groups,
-                n_sumk,
-                n_mink,
-                n_maxk,
-            )
+            with jax.named_scope("fold"):
+                sum_v, min_v, max_v, valid_v, n_sumk, n_mink, n_maxk = _kernel_stacks(
+                    dev, layout, local_rows
+                )
+                count, pac, sums, mins, maxs = kernels.fused_groupby_block(
+                    ids,
+                    mask,
+                    sum_v,
+                    min_v,
+                    max_v,
+                    valid_v,
+                    kernel_groups,
+                    n_sumk,
+                    n_mink,
+                    n_maxk,
+                )
             # stddev/var: centered per-group second moments for this block
             # (local to the device's row shard under a mesh)
             m2_loc, m2_n, m2_s = _block_m2(
@@ -3049,6 +3205,9 @@ class TpuQueryExecutor(QueryExecutor):
         else:
             prog_body = prog_fn
 
+        # the name the XLA module (jit_executor_dense) and the profiler's
+        # scope paths carry: its _note_program_build family
+        prog_body.__name__ = prog_body.__qualname__ = "executor_dense"
         # NOTE: no donate_argnums — the choice was made for a backend this
         # code no longer runs on; donation's cost against the G-sized
         # accumulator copy is not measured on a directly attached chip
@@ -3326,7 +3485,6 @@ def _transfer(enc: EncodedBatch, mesh=None) -> tuple[dict, int]:
             dev["__rowmask"] = put_row(enc.row_mask)
             nbytes += enc.row_mask.nbytes
         DEVICE_BYTES_TO_DEVICE.labels("scan").inc(nbytes)
-        DEVICE_TRANSFER_BYTES.inc(nbytes)
         return dev, nbytes
 
     parts: list[tuple[str, np.dtype, int, int]] = []  # key, dtype, count, offset
@@ -3373,7 +3531,6 @@ def _transfer(enc: EncodedBatch, mesh=None) -> tuple[dict, int]:
             dev[f"{name}__valid"] = ones
     dev["__ones"] = ones
     DEVICE_BYTES_TO_DEVICE.labels("scan").inc(nbytes)
-    DEVICE_TRANSFER_BYTES.inc(nbytes)
     return dev, nbytes
 
 

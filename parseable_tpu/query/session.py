@@ -314,7 +314,8 @@ class QuerySession:
             self._plan_cache_state = None
             self._result_cache_state = None
             tp = _time.perf_counter()
-            select = self._parse_cached(sql_text)
+            with TRACER.span("query.parse"):
+                select = self._parse_cached(sql_text)
             self._parse_ms = round((_time.perf_counter() - tp) * 1000, 3)
             self._sql_text = sql_text
             result = self._query_ast(
@@ -371,10 +372,13 @@ class QuerySession:
         cte_tables = getattr(self, "_cte_tables", None)
         if cte_tables is not None and select.table in cte_tables:
             return self._query_cte_table(select, cte_tables[select.table], t0)
+        from parseable_tpu.utils.telemetry import TRACER
+
         tplan = _time.perf_counter()
-        lp = self._plan_ast(
-            select, start_time, end_time, allowed_streams, t0, sql_key=sql_key
-        )
+        with TRACER.span("query.plan"):
+            lp = self._plan_ast(
+                select, start_time, end_time, allowed_streams, t0, sql_key=sql_key
+            )
         plan_ms = round((_time.perf_counter() - tplan) * 1000, 3)
 
         scan = StreamScan(
@@ -385,13 +389,17 @@ class QuerySession:
         )
         texec = _time.perf_counter()
         self._fanout_stats = None
+        self._execute_clock = None
         # pushdown ships the ORIGINAL statement text to peers (they re-plan
         # it locally); only the top-level single-statement path has it —
         # CTE bodies / resolved-subquery selects executed through here are
         # derived statements with no faithful text, so they stay central
         self._exec_sql = sql_key
-        result, timer = self._execute(lp, scan)
-        exec_s = _time.perf_counter() - texec
+        with TRACER.span("query.execute", stream=lp.stream) as sp:
+            result, timer = self._execute(lp, scan)
+            sp["rows"] = result.table.num_rows
+        tdone = _time.perf_counter()
+        exec_s = tdone - texec
         elapsed = _time.monotonic() - t0
         QUERY_EXECUTE_TIME.labels(lp.stream).observe(elapsed)
         result.stats.update(
@@ -412,6 +420,10 @@ class QuerySession:
                     "plan_ms": plan_ms,
                     "scan_ms": round(timer.seconds * 1000, 3),
                     "execute_ms": round(max(exec_s - timer.seconds, 0.0) * 1000, 3),
+                    # execute_ms split where the work happens: the TPU
+                    # executor's phase clock (None where none ran: the CPU
+                    # engine, a result-cache hit, the manifest fast path)
+                    "execute": self._execute_stage(self._execute_clock, texec, tdone),
                     "total_ms": round(elapsed * 1000, 3),
                     "bytes_saved_by_projection": scan.stats.bytes_saved_by_projection,
                     # cross-query contention: time this query's scan tasks
@@ -486,6 +498,45 @@ class QuerySession:
             if routes and k in routes:
                 snap[k] = routes[k]
         return snap
+
+    @staticmethod
+    def _execute_stage(clock, t_begin: float, t_end: float) -> dict | None:
+        """stats.stages.execute: the finished phase clock of the TPU
+        executor's `route_stats` (executor_tpu.RouteStats), in ms. The
+        eight phases never overlap and bracket host code only, so their
+        sum is at most `execute_ms`; what is left is the executor's own
+        bookkeeping between them, the session's work around it and any
+        block the CPU folded. `head_ms` and `tail_ms` are no phases but
+        the request's two edges with nothing of it on the device, on the
+        wall clock: execute's start to the first program call's return
+        (the scan's waits before it included), and the last readback's end
+        to execute's end; None where no program ran or nothing was read."""
+        if clock is None:
+            return None
+        ns = clock.ns
+        t0_ns, t1_ns = t_begin * 1e9, t_end * 1e9
+        return {
+            "encode_ms": round(ns["encode"] / 1e6, 3),
+            "prepare_ms": round(ns["prepare"] / 1e6, 3),
+            "dispatch_ms": round(ns["dispatch"] / 1e6, 3),
+            "device_wait_ms": round(ns["device_wait"] / 1e6, 3),
+            "readback_ms": round(ns["readback"] / 1e6, 3),
+            "partial_ms": round(ns["partial"] / 1e6, 3),
+            "merge_ms": round(ns["merge"] / 1e6, 3),
+            "finalize_ms": round(ns["finalize"] / 1e6, 3),
+            "head_ms": (
+                round((clock.first_dispatch_ns - t0_ns) / 1e6, 3)
+                if clock.first_dispatch_ns
+                else None
+            ),
+            "tail_ms": (
+                round((t1_ns - clock.last_readback_ns) / 1e6, 3)
+                if clock.last_readback_ns
+                else None
+            ),
+            "blocks": clock.blocks,
+            "readbacks": clock.readbacks,
+        }
 
     def _programs_stage(self, routes: dict | None) -> dict | None:
         """stats.stages.programs: this query's program-cache traffic —
@@ -1268,6 +1319,8 @@ class QuerySession:
             # adaptive-dispatch observability (EXPLAIN ANALYZE surfaces
             # this): per-block route decisions + actual transfer bytes
             stats["device_routes"] = dict(routes)
+            # the phase clock beside the counters (stages.execute reads it)
+            self._execute_clock = routes
         return QueryResult(table, table.column_names, stats), timer
 
     @staticmethod

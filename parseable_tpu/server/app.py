@@ -940,10 +940,13 @@ async def query(request: web.Request) -> web.Response:
         if permit is not None:
             permit.release()
 
-    rows = result.to_json_rows()
-    if send_fields:
-        return web.json_response({"fields": result.fields, "records": rows, "stats": result.stats})
-    return web.json_response(rows)
+    # rows -> JSON text: outside every stage of stats.stages, inside the request
+    with telemetry.TRACER.span("response.encode") as sp:
+        rows = result.to_json_rows()
+        sp["rows"] = len(rows)
+        if send_fields:
+            return web.json_response({"fields": result.fields, "records": rows, "stats": result.stats})
+        return web.json_response(rows)
 
 
 async def _query_streaming(

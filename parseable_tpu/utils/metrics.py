@@ -183,24 +183,31 @@ SCAN_PROJECTION_BYTES_SAVED = _counter(
 )
 DEVICE_EXECUTE_TIME = Histogram(
     "tpu_execute_time",
-    "On-device operator execution seconds",
+    "Host wall seconds of one TPU-engine operator from its first block to "
+    "its merged result: scan waits, dispatch, device waits, readbacks and "
+    "the host merge (not device kernel time)",
     ["op"],
     namespace=METRICS_NAMESPACE,
     registry=REGISTRY,
 )
+# where those seconds go: the executor's phase clock (executor_tpu.PHASES),
+# added once per query from its finished route_stats
+DEVICE_PHASE_SECONDS = _counter(
+    "tpu_execute_phase_seconds",
+    "Host seconds the TPU executor spent in each phase of a query "
+    "(encode, prepare, dispatch, device_wait, readback, partial, merge, finalize)",
+    ["phase"],
+)
 DEVICE_BYTES_TO_DEVICE = _counter("tpu_bytes_to_device", "Bytes shipped host->device", ["op"])
 # JAX accelerator health next to the execute-time histogram: live HBM usage
-# per local device (scrape-time collection, ops/device.py), cumulative
-# host->device transfer bytes, and XLA programs compiled (a jit cache miss
-# costs seconds — compile churn must be visible on a dashboard)
+# per local device (scrape-time collection, ops/device.py) and XLA programs
+# compiled (a jit cache miss costs seconds — compile churn must be visible
+# on a dashboard)
 DEVICE_MEMORY_IN_USE = _gauge(
     "tpu_device_memory_in_use", "Accelerator memory in use (bytes)", ["device"]
 )
 DEVICE_MEMORY_PEAK = _gauge(
     "tpu_device_memory_peak", "Accelerator memory high-water mark (bytes)", ["device"]
-)
-DEVICE_TRANSFER_BYTES = _gauge(
-    "tpu_host_transfer_bytes", "Cumulative host->device transfer bytes", []
 )
 DEVICE_JIT_PROGRAMS = _gauge(
     "tpu_jit_programs", "XLA programs compiled (jit cache misses)", []

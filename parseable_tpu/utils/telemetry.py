@@ -28,6 +28,7 @@ import contextvars
 import json
 import logging
 import random
+import sys
 import threading
 import time
 from collections import deque
@@ -298,6 +299,16 @@ class Tracer:
             trace_id, parent_id = new_trace_id(), None
         span_id = new_span_id()
         token = _TRACE_CTX.set((trace_id, span_id))
+        # one more sink, on the profiler's own clock: while a jax.profiler
+        # session runs, the span lies in its trace beside the device
+        # operations (an atomic flag test when none does). Never the
+        # import: a node that has not imported JAX has no profiler either,
+        # and one that is importing it on another thread has none yet
+        annotate = getattr(sys.modules.get("jax.profiler"), "TraceAnnotation", None)
+        annotation = None
+        if annotate is not None:
+            annotation = annotate(name, trace_id=trace_id, span_id=span_id)
+            annotation.__enter__()
         start_ns = time.time_ns()
         err = None
         try:
@@ -307,6 +318,8 @@ class Tracer:
             raise
         finally:
             end_ns = time.time_ns()
+            if annotation is not None:
+                annotation.__exit__(None, None, None)
             _TRACE_CTX.reset(token)
             self._finish(
                 name, trace_id, span_id, parent_id, start_ns, end_ns, err, attrs
