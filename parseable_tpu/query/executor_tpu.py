@@ -16,7 +16,16 @@ step 5). Per scanned block:
 4. ONE jitted program per (plan, layout, block-shape) runs mask + remap +
    group ids + `fused_groupby_block` in a single dispatch per block, folding
    into a device accumulator; the host syncs once per flush and accumulates
-   G-sized partials in float64.
+   G-sized partials in float64;
+5. past `DENSE_G_MAX` groups the fold is block-local (`jit_executor_local`,
+   each block on its own dictionary codes) and the cross-block merge is the
+   host's: `partials.merge_partials`, f64 across blocks. A plain GROUP BY
+   hands it every block's nonzero groups. A top-K over one aggregate
+   (`ORDER BY <aggregate> LIMIT k`) leaves the blocks' partials on the
+   device, where `jit_executor_merge` sorts, totals and ranks them with an
+   error margin, and hands it only the per-block rows of the groups that
+   can be in the answer (`_merge_program`; `RouteStats` says which
+   way a query went, and why).
 
 The single-dispatch + async + resident-data design assumes per-query
 host<->device traffic — not FLOPs — is the budget; neither the kernel's
@@ -79,6 +88,7 @@ from parseable_tpu.utils.metrics import (
     DEVICE_BYTES_TO_DEVICE,
     DEVICE_EXECUTE_TIME,
     DEVICE_JIT_PROGRAMS,
+    DEVICE_MERGES,
     DEVICE_PHASE_SECONDS,
     DEVICE_RECOMPILES,
 )
@@ -94,13 +104,19 @@ STUB_META = b"ptpu_hot_stub"
 
 # High-cardinality group-by (VERDICT r2 #2): past this dense global group
 # space the executor switches to block-local two-phase aggregation — the
-# device folds each block on its OWN dictionary codes (already dense), the
-# host extracts the nonzero groups as a partial table, and ONE vectorized
-# pyarrow group_by merges all partials at finalize. No capacity epochs, no
-# global remap (whose LUT transfer grows with the dictionary), no per-group
-# Python — a 1M-distinct GROUP BY degrades gracefully instead of falling
-# off a cliff (DataFusion hash-aggregate parity:
-# /root/reference/src/query/mod.rs:212-276).
+# device folds each block on its OWN dictionary codes (already dense). What
+# happens to the blocks' partials depends on the plan. A top-K over one
+# aggregate (`ORDER BY <aggregate> LIMIT k`) keeps them on the device:
+# `jit_executor_merge` sorts, totals and ranks them there with a margin and
+# hands the host only the groups that can be in the answer, some tens of
+# rows. Every other plan reads each block's dense partial back, extracts its
+# nonzero groups as a partial table, and ONE vectorized pyarrow group_by
+# merges all partials at finalize — the same `partials.merge_partials` that
+# the survivors of a top-K go through, so values and order are made in one
+# place. No capacity epochs, no global remap on the device (whose LUT
+# transfer grows with the dictionary), no per-group Python — a 1M-distinct
+# GROUP BY degrades gracefully instead of falling off a cliff (DataFusion
+# hash-aggregate parity: /root/reference/src/query/mod.rs:212-276).
 DENSE_G_MAX = 1 << 19
 # per-block group-space ceiling in local mode (beyond -> that block folds
 # on the CPU; multi-key blocks with two 1M-card keys can't product-combine)
@@ -109,6 +125,31 @@ LOCAL_G_MAX = 1 << 22
 # approx_percentile spec (64 MB at the default 2049-slot sketch layout);
 # beyond it the scan stays host-side with exact sketches
 PCT_MAX_ELEMS = 1 << 24
+# Device merge of a block-local top-K: the most entries (sum of the kept
+# blocks' G_block) that `jit_executor_merge` is given. Per entry the device
+# holds, while the program runs: the kept partial (R f32 rows, 3 for a
+# count + sum: 12 B) and key lanes (4 B a key) twice, as the blocks' own
+# arrays and stacked as the program's arguments; the sort's operands and
+# results (keys, place, the one or two rows ranked: 4 B each, in and out);
+# the scanned lanes with a shifted copy each and the scores. With two keys
+# and three rows the chip's compiler counts 24 B of arguments and 45 B of
+# temporaries an entry (402 MB + 756 MB at 2^24 entries), 89 B with the
+# blocks' own arrays. 2^25 entries are 3.0 GB beside a hot set whose
+# budget (P_TPU_HOT_BYTES, 8 GB) is half of a v5e chip's 16 GB; 2^26 would
+# be 6 GB and leave the folds' own temporaries and the dense paths of
+# concurrent queries too little. Past it the kept partials go through the
+# host merge: exact, and slow.
+MERGE_DEVICE_MAX_ENTRIES = 1 << 25
+# survivors gathered at most, whatever k asks for (k' = max(4k, 64) rounded
+# up to a power of two, capped here); more survivors than k' (mass ties at
+# the k-th value) also means the host merge
+SURVIVORS_MAX = 1 << 14
+# key lanes of the device merge: a NULL key's global code (what
+# GlobalDict.absorb gives a null or a padding slot), and the code of a slot
+# that holds no group, which sorts last. Real codes and time-bin offsets
+# stay under 2^30 in size.
+_LANE_NULL = np.int32(1 << 30)
+_LANE_DEAD = np.int32(2**31 - 1)
 
 
 class UnsupportedOnDevice(Exception):
@@ -168,10 +209,11 @@ class GlobalDict:
         self.values: list[Any] = []
         self._chunks: list[pa.Array] = []  # same values, arrow-side
 
-    def absorb(self, batch_dict: list[Any]) -> np.ndarray:
+    def absorb(self, batch_dict: list[Any], batch_arr: pa.Array | None = None) -> np.ndarray:
         """Register a batch dictionary; return the batch->global int32 remap,
         padded to pow2 with a large sentinel (nulls + padding decode as the
-        null group)."""
+        null group). `batch_arr` is the same dictionary as an arrow array,
+        where the caller has one cached."""
         card = len(batch_dict)
         lut = np.full(_pow2(card + 1), np.int32(2**30), dtype=np.int32)
         if card == 0:
@@ -182,10 +224,11 @@ class GlobalDict:
             # a previous batch fell back to slow mode; the arrow-side view
             # is stale, so stay on the slow path for dictionary consistency
             return self._absorb_slow(batch_dict, lut)
-        try:
-            batch_arr = pa.array(batch_dict)
-        except (pa.ArrowInvalid, pa.ArrowTypeError):
-            return self._absorb_slow(batch_dict, lut)
+        if batch_arr is None:
+            try:
+                batch_arr = pa.array(batch_dict)
+            except (pa.ArrowInvalid, pa.ArrowTypeError):
+                return self._absorb_slow(batch_dict, lut)
         if self._chunks:
             value_set: pa.Array | pa.ChunkedArray = (
                 self._chunks[0]
@@ -1007,8 +1050,8 @@ def _note_program_build(program: str, key: tuple, stats: dict | None = None) -> 
 # brackets host code that stands where it stood; none adds a wait.
 PHASES = (
     "encode",  # _encoded_block: hot-set look-up; on a miss encode + enccache + _transfer
-    "prepare",  # LUTs, gdict remaps, _host_codes + np.unique, puts of arguments
-    "dispatch",  # program look-up and the call into it up to its return (enqueue)
+    "prepare",  # LUTs, gdict remaps, _host_codes + np.unique, global key lanes, puts of arguments
+    "dispatch",  # program look-up and the call into it up to its return (enqueue); the merge program's too
     "device_wait",  # the wait for pending compute that _timed_readback makes
     "readback",  # the np.asarray after it
     "partial",  # dense arrays -> partial / interim tables
@@ -1045,6 +1088,17 @@ class RouteStats(dict):
             programs_built=0,
             programs_reused=0,
             recompiles=0,
+            # a block-local GROUP BY's cross-block merge: ran on the device
+            # (jit_executor_merge; the host merged the survivors only) or
+            # took every partial row through the host; entries the device
+            # sorted, and groups that survived its ranking. A host merge
+            # says why under `merge_host_reason` (a string, so no sum
+            # takes it): plan | aggregate | mesh | host_partials | budget |
+            # survivors
+            merge_device=0,
+            merge_host=0,
+            merge_entries=0,
+            merge_survivors=0,
         )
         self.ns = dict.fromkeys(PHASES, 0)
         self.blocks = self.readbacks = 0
@@ -1119,6 +1173,212 @@ def _timed_readback(
     if clock is not None:
         clock.read_back(prev)
     return arr
+
+
+def _f32_order(x):
+    """f32 -> int32 whose order is the floats' (-inf..+inf maps onto
+    [-2139095040, 2139095040]; -0.0 and +0.0 meet at 0). NaN has no place in
+    it: callers rank NaN apart, as `_run_topk_program` does."""
+    import jax
+    import jax.numpy as jnp
+
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    return jnp.where(bits >= 0, bits, jnp.int32(-2147483648) - bits)
+
+
+# the int32 scores below every real key, in nulls-last order (the classes of
+# `_run_topk_program`): a NaN key, a group whose aggregate is NULL, no group
+_SCORE_NAN = -2139095339
+_SCORE_NULL = -2147483647
+_SCORE_EMPTY = -2147483648
+_SCORE_INF = 2139095040
+
+
+def _merge_program(
+    n_pad: int,
+    run_max: int,
+    n_rows: int,
+    nkeys: int,
+    kind: str,
+    t_row: int,
+    pac_row: int,
+    desc: bool,
+    k: int,
+    k_out: int,
+    stats: dict | None = None,
+) -> Callable:
+    """`jit_executor_merge`: the cross-block half of a block-local top-K, on
+    the device. Arguments of the program: the kept blocks' `[R, G_block]` f32
+    partials (outputs of `jit_executor_local`, untouched) stacked side by side
+    in block order and padded to `[R, n_pad]`, and their int32 global key
+    lanes `[nkeys, n_pad]` likewise. A group has at most `run_max` entries
+    (one a block; a power of two, as `n_pad`, so that few shapes compile). It
+
+    - `merge/sort`: sorts the entries by the key lanes (stable: a group's
+      entries lie together, in block order), carrying along the entry's
+      place and the one or two rows the ranking reads: a gather through the
+      sorted places afterwards costs more than the sort (PERF.md, PR 28);
+    - `merge/scan`: totals each run without a scatter: log2(run_max) rounds
+      of "add the entry d places back if its keys are equal";
+    - `merge/topk`: ranks the groups by the ordering aggregate `kind`
+      ("sum" | "count" | "min" | "max", row `t_row`, non-null count row
+      `pac_row`) WITH A MARGIN, and gathers the per-block partials of at most
+      `k_out` survivors.
+
+    The margin is what makes the selection a superset of the answer. The
+    host's value of group g is S_g, the f64 sum of its blocks' f32 partials;
+    here T_g is an f32 sum of the same numbers, so |T_g - S_g| <= e_g with
+    e_g = (B + 2) * 2^-23 * A_g, A_g the sum of their absolute values (B - 1
+    roundings of 2^-24 relative to A_g at most in any order of summation; the
+    rest covers the roundings of A_g, T_g -/+ e_g themselves). Counts are
+    integers that f32 adds exactly below 2^24 (e = 0 there), min and max are
+    exact. tau is the k-th best lower bound; every group whose upper bound
+    reaches tau survives. A group of the true top k has S at least the k-th
+    best S, which is at least the k-th best lower bound, and its upper bound
+    is at least its S: it survives. A group holding a non-finite partial is
+    bounded by (-inf, +inf): it always survives and never raises tau.
+
+    Returns (`[R, k_out * run_max]` f32: survivor-major, block order within
+    a survivor, count 0 where a survivor has no entry there; `[nkeys + 1,
+    k_out]` int32: the survivors' key lanes, then the TRUE number of
+    survivors in every slot of the last row: more than `k_out` means the
+    gather is not the whole superset and the host must not use it).
+    Programs are cached process-wide like the executor's others; `stats`
+    (a route_stats dict) counts the build or the reuse."""
+    key = ("merge", n_pad, run_max, n_rows, nkeys, kind, t_row, pac_row, desc, k, k_out)
+    cached = _PROGRAM_CACHE.get(key)
+    if cached is not None:
+        if stats is not None:
+            stats["programs_reused"] += 1
+        return cached
+
+    import jax
+    import jax.numpy as jnp
+
+    err = np.float32((run_max + 2) * 2.0**-23)
+    i32 = jnp.int32
+
+    def back(x, d: int, fill):  # out[i] = x[i - d]
+        return jnp.concatenate([jnp.full((d,), fill, x.dtype), x[:-d]])
+
+    def kth_best(score):
+        """The k-th largest of `score` (int32), bit by bit from the top: 32
+        counting passes, where a `top_k` over 2^24 entries is a full sort."""
+        u = jax.lax.bitcast_convert_type(score, jnp.uint32) ^ jnp.uint32(0x80000000)
+
+        def bit(i, found):
+            cand = found | (jnp.uint32(0x80000000) >> i.astype(jnp.uint32))
+            return jnp.where(jnp.sum(u >= cand, dtype=i32) >= k, cand, found)
+
+        found = jax.lax.fori_loop(0, 32, bit, jnp.uint32(0))
+        return jax.lax.bitcast_convert_type(found ^ jnp.uint32(0x80000000), i32)
+
+    def merge(v, ln):
+        with jax.named_scope("merge"):
+            # a slot no row fell into (padding to a power of two, a group the
+            # WHERE emptied) is no group: it sorts last and joins no run
+            ln = jnp.where(v[0] > 0, ln, _LANE_DEAD)
+            with jax.named_scope("sort"):
+                carried = [jax.lax.iota(i32, n_pad), v[t_row]]
+                if kind != "count":
+                    carried.append(v[pac_row])
+                out = jax.lax.sort(
+                    (*(ln[i] for i in range(nkeys)), *carried), num_keys=nkeys, is_stable=True
+                )
+                sk, perm, t = out[:nkeys], out[nkeys], out[nkeys + 1]
+            with jax.named_scope("scan"):
+                if kind == "sum":
+                    lanes_ = [(t, jnp.add, 0.0), (jnp.abs(t), jnp.add, 0.0)]
+                elif kind == "count":
+                    lanes_ = [(t, jnp.add, 0.0)]
+                else:
+                    comb, ident = (
+                        (jnp.minimum, 3.4e38) if kind == "min" else (jnp.maximum, -3.4e38)
+                    )
+                    bad = (~jnp.isfinite(t)).astype(jnp.float32)
+                    lanes_ = [(t, comb, ident), (bad, jnp.maximum, 0.0)]
+                if kind != "count":
+                    lanes_.append((out[nkeys + 2], jnp.add, 0.0))
+                d = 1
+                while d < run_max:
+                    same = None
+                    for key in sk:
+                        eq = key == back(key, d, _LANE_DEAD)
+                        same = eq if same is None else same & eq
+                    lanes_ = [
+                        (op(x, jnp.where(same, back(x, d, ident), ident)), op, ident)
+                        for x, op, ident in lanes_
+                    ]
+                    d *= 2
+                last = None
+                for key in sk:
+                    ne = key != jnp.concatenate([key[1:], jnp.full((1,), _LANE_DEAD, i32)])
+                    last = ne if last is None else last | ne
+                group = last & (sk[0] != _LANE_DEAD)
+                total = lanes_[0][0]
+                if kind == "sum":
+                    a = lanes_[1][0]
+                    e = a * err
+                    unsure = ~jnp.isfinite(a)
+                elif kind == "count":
+                    e = jnp.where(total < 16777216.0, 0.0, total * err)
+                    unsure = jnp.zeros_like(group)
+                else:
+                    e = jnp.zeros_like(total)
+                    unsure = lanes_[1][0] > 0
+                notnull = lanes_[-1][0] > 0 if kind != "count" else jnp.ones_like(group)
+            with jax.named_scope("topk"):
+                val = total if desc else -total
+                lo = jnp.where(unsure, i32(_SCORE_NAN), _f32_order(val - e))
+                hi = jnp.where(unsure, i32(_SCORE_INF), _f32_order(val + e))
+                lo = jnp.where(group, jnp.where(notnull, lo, i32(_SCORE_NULL)), i32(_SCORE_EMPTY))
+                hi = jnp.where(group, jnp.where(notnull, hi, i32(_SCORE_NULL)), i32(_SCORE_EMPTY))
+                surv = group & (hi >= kth_best(lo))
+                # the j-th survivor ends its run where the running count first reads j
+                upto = jnp.cumsum(surv.astype(i32))
+                n_surv = upto[n_pad - 1]
+                j = jnp.arange(1, k_out + 1, dtype=i32)
+                pos = jnp.minimum(jnp.searchsorted(upto, j, side="left").astype(i32), n_pad - 1)
+                # a survivor's run is at most run_max entries long
+                idx = pos[:, None] - jnp.arange(run_max - 1, -1, -1, dtype=i32)[None, :]
+                at = jnp.maximum(idx, 0)
+                mine = (idx >= 0) & (j <= n_surv)[:, None]
+                for key in sk:
+                    mine = mine & (key[at] == key[pos][:, None])
+                got = v[:, perm[at].reshape(-1)]
+                got = jnp.concatenate([jnp.where(mine.reshape(-1), got[0], 0.0)[None, :], got[1:]])
+                meta = jnp.stack([key[pos] for key in sk] + [jnp.full((k_out,), n_surv, i32)])
+            return got, meta
+
+    merge.__name__ = merge.__qualname__ = "executor_merge"  # XLA module jit_executor_merge
+    # no donate_argnums: the outputs are a few KB, so no input's buffer could
+    # serve one; `v` is read to the program's last gather
+    program = jax.jit(merge)  # jit-cache: executor.merge
+    _note_program_build("executor.merge", key, stats)
+    _PROGRAM_CACHE[key] = program
+    return program
+
+
+class _KeptPartials:
+    """One query's block-local partials while they stay on the device for
+    `jit_executor_merge`: decided once, when block-local mode begins, from
+    what the executor can see (the plan, the mesh, partials the host already
+    made). `reason` says why the host merges instead, once it is known that
+    it will; `blocks` then is empty and nothing more is kept."""
+
+    def __init__(self, topk: tuple | None, nkeys: int, reason: str | None) -> None:
+        self.si, self.desc, self.k = topk if topk is not None else (0, False, 0)
+        self.reason = reason
+        # (out_dev [R, G_block], lanes_dev [nkeys, G_block], keyinfo, composite_vals)
+        self.blocks: list[tuple] = []
+        self.entries = 0
+        # per time-bin key: the absolute bin that lane value 0 stands for
+        self.bin_origin: list[int | None] = [None] * nkeys
+
+    @property
+    def active(self) -> bool:
+        return self.reason is None
+
 
 # blocks the adaptive dispatcher routed to the CPU because the measured
 # link made shipping a losing trade (observable in tests/metrics)
@@ -1814,6 +2074,9 @@ class TpuQueryExecutor(QueryExecutor):
         # vectorized host merge (high-cardinality group spaces)
         local_mode = False
         partials: list[pa.Table] = []
+        # set when local mode begins: what is kept on the device for the
+        # device merge, or why nothing is (_KeptPartials)
+        keep: _KeptPartials | None = None
         local_layout = PlanLayout(
             key_specs=key_specs,
             caps=(),
@@ -1852,6 +2115,9 @@ class TpuQueryExecutor(QueryExecutor):
             """Aggregate one block on the host, into partials when the
             specs allow (vectorized; a 1M-group block must not hit the
             per-group Python aggregator)."""
+            if keep is not None:
+                # a partial made on the host: the device cannot rank it
+                self._spill_kept(keep, partials, specs, lay, "host_partials")
             t0 = _time.perf_counter()
             # same row basis the gate prices on (raw block rows / stub
             # meta, BEFORE the bounds filter) — recording post-bounds rows
@@ -1923,7 +2189,7 @@ class TpuQueryExecutor(QueryExecutor):
                     luts = compiler.collect_luts(sel.where, enc)
                     if local_mode:
                         self._local_block(
-                            partials, enc, dev, luts, key_specs, specs, local_layout, lay,
+                            partials, enc, dev, luts, key_specs, specs, local_layout, lay, keep,
                         )
                         continue
                     remaps = [
@@ -2001,12 +2267,16 @@ class TpuQueryExecutor(QueryExecutor):
                             acc = None
                             dacc = []
                         local_mode = True
+                        keep = self._plan_device_merge(
+                            rewritten, specs, len(key_specs), bool(partials or agg.groups)
+                        )
                         logger.info(
-                            "group space %d exceeds dense budget; block-local two-phase mode",
+                            "group space %d exceeds dense budget; block-local two-phase mode, merge on the %s",
                             new_groups,
+                            "device" if keep.active else f"host ({keep.reason})",
                         )
                         self._local_block(
-                            partials, enc, dev, luts, key_specs, specs, local_layout, lay,
+                            partials, enc, dev, luts, key_specs, specs, local_layout, lay, keep,
                         )
                         continue
                     current = tuple((ks.origin_rel or 0, ks.capacity) for ks in key_specs)
@@ -2081,6 +2351,8 @@ class TpuQueryExecutor(QueryExecutor):
                     rs.enter(None)  # the CPU's fold is no phase of the device path
                     logger.debug("batch on CPU (%s)", e)
                     self.route_stats["cpu_fallback"] += 1
+                    if keep is not None:
+                        self._spill_kept(keep, partials, specs, lay, "host_partials")
                     t = self._bounds_filter(self._materialize(table))
                     agg.update(t, self._where_mask(t))
                 finally:
@@ -2107,9 +2379,12 @@ class TpuQueryExecutor(QueryExecutor):
                 sp["rows"] = out.num_rows
                 return out
 
-        if partials or (local_mode and (acc is not None or agg.groups)):
+        kept = keep is not None and bool(keep.blocks)
+        if kept or partials or (local_mode and (acc is not None or agg.groups)):
             # two-phase finalize: dense epoch + device block partials +
-            # CPU-fallback groups all merge through ONE pyarrow group_by
+            # CPU-fallback groups all merge through ONE pyarrow group_by.
+            # Blocks kept on the device (a top-K; then nothing else is
+            # here) reach it as the one small table of their survivors.
             if acc is not None:
                 pt = self._dense_to_partial(
                     acc, acc_groups, key_specs, specs, lay,
@@ -2122,12 +2397,24 @@ class TpuQueryExecutor(QueryExecutor):
             rs.enter(prev)
             if apt is not None:
                 partials.append(apt)
-            with TRACER.span("execute.merge", rows=sum(p.num_rows for p in partials)):
-                prev = rs.enter("merge")
-                try:
-                    interim = self._merge_partials(partials, specs, len(key_specs))
-                finally:
-                    rs.enter(prev)
+            with TRACER.span("execute.merge") as sp:
+                if kept:
+                    sp["rows"] = keep.entries
+                    self._device_merge(keep, partials, key_specs, specs, lay)
+                    sp["survivors"] = rs["merge_survivors"]
+                if not rs["merge_device"]:
+                    sp["rows"] = sum(p.num_rows for p in partials)
+                    if local_mode:
+                        rs["merge_host"] = 1
+                        rs["merge_host_reason"] = keep.reason
+                        DEVICE_MERGES.labels("host").inc()
+                interim = None
+                if partials:
+                    prev = rs.enter("merge")
+                    try:
+                        interim = self._merge_partials(partials, specs, len(key_specs))
+                    finally:
+                        rs.enter(prev)
             return finish(interim)
         # vectorized dense finalize: when the run stayed fully on device
         # (no CPU-fallback partials, no distinct sets), skip the per-group
@@ -2371,8 +2658,7 @@ class TpuQueryExecutor(QueryExecutor):
                     # sort with zero collisions against real keys.
                     kf = keyv.astype(jnp.float32)
                     nan = jnp.isnan(kf)
-                    bits = jax.lax.bitcast_convert_type(kf, jnp.int32)
-                    u = jnp.where(bits >= 0, bits, jnp.int32(-2147483648) - bits)
+                    u = _f32_order(kf)
                     o = u if desc else jnp.where(
                         u == jnp.int32(-2147483648), jnp.int32(2147483647), -u
                     )
@@ -2380,10 +2666,10 @@ class TpuQueryExecutor(QueryExecutor):
                         live & ~nan,
                         o,
                         jnp.where(
-                            live, jnp.int32(-2139095339),  # NaN key: below reals
+                            live, jnp.int32(_SCORE_NAN),  # NaN key: below reals
                             jnp.where(
-                                occupied, jnp.int32(-2147483647),  # NULL agg
-                                jnp.int32(-2147483648),  # empty slot
+                                occupied, jnp.int32(_SCORE_NULL),  # NULL agg
+                                jnp.int32(_SCORE_EMPTY),  # empty slot
                             ),
                         ),
                     )
@@ -2417,10 +2703,13 @@ class TpuQueryExecutor(QueryExecutor):
         specs: list[AggSpec],
         layout: PlanLayout,
         lay: AccLayout,
+        keep: _KeptPartials,
     ) -> None:
         """Two-phase step: fold one block on its OWN dictionary codes (no
-        global remap), read back the dense [G_block] partial, extract the
-        nonzero groups as a partial-format table."""
+        global remap on the device). While `keep` is active (a top-K whose
+        merge runs on the device) the dense [R, G_block] partial stays
+        there and only the block's global key lanes are shipped; else it
+        is read back and its nonzero groups become a partial-format table."""
         import jax.numpy as jnp
 
         rs = self.route_stats
@@ -2509,6 +2798,8 @@ class TpuQueryExecutor(QueryExecutor):
             key_sig = (("pair", "__pairkey", 0),)
             full_luts = luts + self._time_args(enc, [], (), self._bounds_ms())
         dev_luts = tuple(put_rep(l) for l in full_luts)
+        if keep.active and keep.entries + num_groups > MERGE_DEVICE_MAX_ENTRIES:
+            self._spill_kept(keep, partials, specs, lay, "budget")
 
         rs.enter("dispatch")
         program = self._get_local_program(
@@ -2522,7 +2813,22 @@ class TpuQueryExecutor(QueryExecutor):
             num_groups,
         )
         out_dev = program(dev, dev_luts, row_mask)
-        rs.dispatched("partial")
+        if keep.active:
+            # the fold runs while the host makes the lanes; nothing waits on it
+            rs.dispatched("prepare")
+            lanes = self._key_lanes(
+                enc, key_specs, caps, origins, composite_vals, num_groups, keep,
+            )
+            if lanes is not None:
+                keep.blocks.append((out_dev, jnp.asarray(lanes), keyinfo, composite_vals))
+                keep.entries += num_groups
+                rs.enter(prev)
+                return
+            # a time-bin lane that 31 bits do not hold
+            self._spill_kept(keep, partials, specs, lay, "budget")
+            rs.enter("partial")
+        else:
+            rs.dispatched("partial")
         out = _timed_readback(out_dev, rs)
         pt = self._partial_from_arrays(
             out, lay, keyinfo, specs, composite_vals=composite_vals,
@@ -2530,6 +2836,192 @@ class TpuQueryExecutor(QueryExecutor):
         rs.enter(prev)
         if pt is not None:
             partials.append(pt)
+
+    def _plan_device_merge(
+        self, rewritten: list[S.SelectItem], specs: list[AggSpec], nkeys: int, host_partials: bool
+    ) -> _KeptPartials:
+        """Whether this query's block-local partials stay on the device for
+        `jit_executor_merge`, from what the executor sees as local mode
+        begins: the plan is a top-K over one aggregate that has a simple
+        error bound, one device, and no partial made on the host so far."""
+        topk = self._device_topk_plan(rewritten)
+        if topk is None:
+            reason = "plan"
+        elif specs[topk[0]].func not in ("sum", "count", "count_star", "min", "max"):
+            reason = "aggregate"  # avg / stddev / var: no simple bound, the host ranks them
+        elif self.mesh is not None:
+            reason = "mesh"
+        elif host_partials:
+            reason = "host_partials"
+        else:
+            reason = None
+        return _KeptPartials(topk, nkeys, reason)
+
+    def _spill_kept(
+        self,
+        keep: _KeptPartials,
+        partials: list[pa.Table],
+        specs: list[AggSpec],
+        lay: AccLayout,
+        reason: str,
+    ) -> None:
+        """The device merge is off for this query (`reason`, unless one was
+        given before): what was kept is read back and turned into partial
+        tables by the code that does it per block otherwise, in block order."""
+        if keep.reason is None:
+            keep.reason = reason
+        rs = self.route_stats
+        for out_dev, _lanes, keyinfo, composite_vals in keep.blocks:
+            out = _timed_readback(out_dev, rs)
+            prev = rs.enter("partial")
+            pt = self._partial_from_arrays(out, lay, keyinfo, specs, composite_vals=composite_vals)
+            rs.enter(prev)
+            if pt is not None:
+                partials.append(pt)
+        keep.blocks = []
+        keep.entries = 0
+
+    @staticmethod
+    def _dict_arrow(enc: EncodedBatch, col: EncodedColumn) -> pa.Array | None:
+        """A block's dictionary as an arrow array, cached on the batch as
+        `_hll_lut` is (a hot block's dictionary is converted once, not once
+        a query); None where arrow cannot hold it."""
+        cache = PredicateCompiler._batch_cache(enc)
+        key = ("__arrow", col.name, len(col.dictionary))
+        if key not in cache:
+            try:
+                cache[key] = pa.array(col.dictionary)
+            except (pa.ArrowInvalid, pa.ArrowTypeError):
+                cache[key] = None
+        return cache[key]
+
+    def _key_lanes(
+        self,
+        enc: EncodedBatch,
+        key_specs: list[KeySpec],
+        caps: list[int],
+        origins: list[int],
+        composite_vals: np.ndarray | None,
+        num_groups: int,
+        keep: _KeptPartials,
+    ) -> np.ndarray | None:
+        """[nkeys, num_groups] int32: for each slot of a block's dense
+        partial the GLOBAL code of each of its keys, which is what makes
+        entries of different blocks comparable on the device. A dict key's
+        is its place in the query's GlobalDict (`_LANE_NULL` for the null
+        key), a time-bin key's the absolute bin less the first kept
+        block's first bin. Slots past the block's groups get `_LANE_DEAD`.
+        None where a time-bin offset leaves the lanes' 2^30.
+
+        Slot -> local codes as `_partial_from_arrays` decodes them (the
+        capacities are powers of two): the stride layout has the first key
+        minor, the np.unique compaction the first key major."""
+        shifts = [cap.bit_length() - 1 for cap in caps]
+        if composite_vals is None:
+            rem = np.arange(num_groups, dtype=np.int64)
+            codes = []
+            for cap, sh in zip(caps, shifts):
+                codes.append(rem & (cap - 1))
+                rem = rem >> sh
+        else:
+            rem = composite_vals
+            codes = []
+            for cap, sh in zip(reversed(caps[1:]), reversed(shifts[1:])):
+                codes.append(rem & (cap - 1))
+                rem = rem >> sh
+            codes.append(rem)
+            codes.reverse()
+        lanes = np.empty((len(key_specs), num_groups), np.int32)
+        lanes[:, len(codes[0]) :] = _LANE_DEAD
+        for i, (ks, code) in enumerate(zip(key_specs, codes)):
+            if ks.kind == "dict":
+                col = enc.columns[ks.column]
+                remap = ks.gdict.absorb(col.dictionary, self._dict_arrow(enc, col))
+                np.take(remap, code, out=lanes[i, : len(code)])
+            else:
+                if keep.bin_origin[i] is None:
+                    keep.bin_origin[i] = origins[i]
+                off = origins[i] - keep.bin_origin[i]
+                if abs(off) + caps[i] >= int(_LANE_NULL):
+                    return None
+                lanes[i, : len(code)] = code + off
+        return lanes
+
+    def _device_merge(
+        self,
+        keep: _KeptPartials,
+        partials: list[pa.Table],
+        key_specs: list[KeySpec],
+        specs: list[AggSpec],
+        lay: AccLayout,
+    ) -> None:
+        """Run `jit_executor_merge` over the kept blocks and add to
+        `partials` ONE table in the per-block format: the per-block rows of
+        the groups that can be in the top k, keys decoded for them only
+        (none where no group survived: there was none). The host merge and
+        `finish()` make the answer from it as from any partials. When more
+        groups survived than were gathered that table would not hold them
+        all, and the kept blocks go through the host ("survivors")."""
+        import jax.numpy as jnp
+
+        rs = self.route_stats
+        si, desc, k = keep.si, keep.desc, keep.k
+        func = specs[si].func
+        if func == "count_star":
+            kind, t_row, pac_row = "count", 0, 0
+        elif func == "count":
+            kind, t_row, pac_row = "count", lay.pac_row(si), lay.pac_row(si)
+        else:
+            kind, pac_row = func, lay.pac_row(si)
+            t_row = {"sum": lay.sum_row, "min": lay.min_row, "max": lay.max_row}[func](si)
+        nkeys = len(key_specs)
+        k_out = min(_pow2(max(4 * k, 64)), SURVIVORS_MAX)
+        # shapes in powers of two, so that few ever compile: a query over
+        # another number of blocks meets the program of its bucket
+        n_pad = _pow2(max(keep.entries, k, k_out))
+        run_max = _pow2(len(keep.blocks), 1)
+        n_rows = keep.blocks[0][0].shape[0]
+        prev = rs.enter("dispatch")
+        program = _merge_program(n_pad, run_max, n_rows, nkeys, kind, t_row, pac_row, desc, k, k_out, rs)
+        pad = n_pad - keep.entries
+        got_dev, meta_dev = program(
+            jnp.concatenate(
+                [b[0] for b in keep.blocks] + [jnp.zeros((n_rows, pad), jnp.float32)] * (pad > 0), axis=1
+            ),
+            jnp.concatenate(
+                [b[1] for b in keep.blocks] + [jnp.full((nkeys, pad), _LANE_DEAD)] * (pad > 0), axis=1
+            ),
+        )
+        rs.dispatched(prev)
+        meta = _timed_readback(meta_dev, rs, dtype=None)
+        rs["merge_entries"] = keep.entries
+        rs["merge_survivors"] = survivors = int(meta[nkeys, 0])
+        if survivors > k_out:
+            self._spill_kept(keep, partials, specs, lay, "survivors")
+            return
+        got = _timed_readback(got_dev, rs)
+        prev = rs.enter("partial")
+        keyinfo: list[tuple] = []
+        key_codes: list[np.ndarray] = []
+        for i, ks in enumerate(key_specs):
+            lane = meta[i].astype(np.int64)
+            if ks.kind == "dict":
+                # the survivors' own small dictionary; the null key and the
+                # lanes of slots that hold no survivor take its null slot
+                named = lane < len(ks.gdict.values)
+                uniq = np.unique(lane[named])
+                keyinfo.append(("dict", [ks.gdict.values[c] for c in uniq] + [None], 0))
+                lane = np.where(named, np.searchsorted(uniq, lane), len(uniq))
+            else:
+                keyinfo.append(("timebin", keep.bin_origin[i], ks.bin_ms, 0))
+            key_codes.append(np.repeat(lane, run_max))
+        pt = self._partial_from_arrays(got, lay, keyinfo, specs, key_codes=key_codes)
+        rs.enter(prev)
+        if pt is not None:
+            partials.append(pt)
+        keep.blocks = []  # the device arrays go with the query's last use of them
+        rs["merge_device"] = 1
+        DEVICE_MERGES.labels("device").inc()
 
     @staticmethod
     def _hll_lut(enc: EncodedBatch, col: EncodedColumn) -> np.ndarray:
@@ -2743,6 +3235,7 @@ class TpuQueryExecutor(QueryExecutor):
         keyinfo: list[tuple],
         specs: list[AggSpec],
         composite_vals: np.ndarray | None = None,
+        key_codes: list[np.ndarray] | None = None,
     ) -> pa.Table | None:
         """Nonzero groups of one dense partial -> partial-format table
         (__g{i} keys, __cnt, per-spec __pac/__sum/__min/__max), fully
@@ -2751,13 +3244,17 @@ class TpuQueryExecutor(QueryExecutor):
         Default layout: group id = sum(code_i * stride_i), first key minor.
         With `composite_vals` (pair-compacted mode): group g's keys decode
         from composite_vals[g] = ((c0*cap1 + c1)*cap2 + c2)..., first key
-        MAJOR — the np.unique compaction order."""
+        MAJOR — the np.unique compaction order. With `key_codes` (the
+        device merge's gather) column j's keys are key_codes[i][j]."""
         count = arr[0]
         idxs = np.nonzero(count > 0)[0]
         if len(idxs) == 0:
             return None
         cols: dict[str, pa.Array] = {}
-        if composite_vals is None:
+        if key_codes is not None:
+            for i, (info, code) in enumerate(zip(keyinfo, key_codes)):
+                cols[f"__g{i}"] = self._decode_key_col(info, code[idxs])
+        elif composite_vals is None:
             rem = idxs.copy()
             for i, info in enumerate(keyinfo):
                 cap = info[-1]

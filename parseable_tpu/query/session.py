@@ -510,7 +510,8 @@ class QuerySession:
         the request's two edges with nothing of it on the device, on the
         wall clock: execute's start to the first program call's return
         (the scan's waits before it included), and the last readback's end
-        to execute's end; None where no program ran or nothing was read."""
+        to execute's end; None where no program ran or nothing was read.
+        `blocks`, `readbacks` and the three `merge_*` are counts."""
         if clock is None:
             return None
         ns = clock.ns
@@ -536,6 +537,12 @@ class QuerySession:
             ),
             "blocks": clock.blocks,
             "readbacks": clock.readbacks,
+            # the block-local merge's counters beside the phases they
+            # explain (device_routes holds them too): whether it ran on
+            # the device, entries it sorted there, groups that survived
+            "merge_device": clock["merge_device"],
+            "merge_entries": clock["merge_entries"],
+            "merge_survivors": clock["merge_survivors"],
         }
 
     def _programs_stage(self, routes: dict | None) -> dict | None:

@@ -199,6 +199,14 @@ DEVICE_PHASE_SECONDS = _counter(
     ["phase"],
 )
 DEVICE_BYTES_TO_DEVICE = _counter("tpu_bytes_to_device", "Bytes shipped host->device", ["op"])
+# a block-local (high-cardinality) GROUP BY's cross-block merge: "device" when
+# jit_executor_merge ranked the partials and the host merged only the
+# survivors of a top-K, "host" when every partial row went through the host
+DEVICE_MERGES = _counter(
+    "tpu_device_merges",
+    "Block-local GROUP BY merges by where the cross-block merge ran",
+    ["path"],
+)
 # JAX accelerator health next to the execute-time histogram: live HBM usage
 # per local device (scrape-time collection, ops/device.py) and XLA programs
 # compiled (a jit cache miss costs seconds — compile churn must be visible

@@ -378,8 +378,10 @@ class Tracer:
         }
         # native-telemetry detail attrs ride along when present so the
         # stitched cluster trace shows WHICH shard/lane produced a span and
-        # why it declined — the fixed fields above stay the stable schema
-        for k in ("shard", "lane", "cause", "qwait_us"):
+        # why it declined — the fixed fields above stay the stable schema;
+        # `survivors` is execute.merge's (groups the device merge kept of
+        # the entries counted under `rows`)
+        for k in ("shard", "lane", "cause", "qwait_us", "survivors"):
             if k in attrs:
                 row[k] = attrs[k]
         _SPAN_RING.append(row)

@@ -10,7 +10,10 @@ Reads `stats.stages.execute` (the TPU executor's phase clock, query/session.py
              queues for a `benchmark` issue (a reader of `benchmark/metrics/` would
              return the same number from `run["responses"]`); None where no
              response has the split
-  by_query   by SQL text: requests, and the median of every stage and phase
+  by_query   by SQL text: requests, the median of every stage and phase, and of the
+             block-local merge (ISSUE 28): `merge_device` the requests whose merge ran
+             on the device, `merge_entries` and `merge_survivors` their medians (0
+             from a run of a program that has no such counters)
   gap        the host time between two requests' device work, made up from the
              clocks of consecutive requests: `tail_ms` of one, its
              `http_overhead_ms` (latency less `total_ms`: the way in and the way
@@ -80,6 +83,8 @@ def by_query(rows: list) -> dict:
             **{k: med(lambda r, k=k: r["stages"]["execute"][k] or 0.0) for k in
                (*(f"{p}_ms" for p in PHASES), "head_ms", "tail_ms", "blocks", "readbacks")},
             "unaccounted_ms": med(lambda r: r["stages"]["execute_ms"] - sum(r["stages"]["execute"][f"{p}_ms"] for p in PHASES)),
+            "merge_device": sum(r["stages"]["execute"].get("merge_device", 0) for r in mine),
+            **{k: med(lambda r, k=k: r["stages"]["execute"].get(k, 0)) for k in ("merge_entries", "merge_survivors")},
         }
     return out
 

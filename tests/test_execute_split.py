@@ -27,6 +27,7 @@ def stages(execute_ms: float, **phases) -> dict:
 SHORT = stages(100.0, encode=1, prepare=9, dispatch=2, device_wait=80, readback=1, partial=2, merge=0, finalize=1)
 LONG = stages(12_000.0, encode=10, prepare=1_500, dispatch=90, device_wait=700, readback=400, partial=1_000, merge=8_000, finalize=100)
 TAIL = stages(900.0, encode=4, prepare=30, dispatch=6, device_wait=840, readback=2, partial=3, merge=5, finalize=1)
+MERGE = ("merge_device", "merge_entries", "merge_survivors")
 
 
 def test_the_tail_metrics_are_parts_of_the_one_request_execute_tail_ms_reports():
@@ -67,8 +68,26 @@ def test_the_table_by_sql_text_and_the_gap_made_up_from_consecutive_requests(tmp
     assert split.main(["execute_split.py", str(f)]) == 0
     out = json.loads(capsys.readouterr().out)
     assert out["by_query"]["a"]["requests"] == 3 and out["by_query"]["a"]["device_wait_ms"] == 80
+    # a run of a program without the merge counters reads 0 for them
+    assert [out["by_query"]["a"][k] for k in MERGE] == [0, 0, 0]
     assert out["by_query"]["b"]["unaccounted_ms"] == pytest.approx(9.0)
     gap = out["gap"]
     assert gap["pairs"] == 4  # five pairs less the pause
     # tail 1 + overhead 2.5 + turn-around 0.4 + parse and plan 0.5 + head 1
     assert gap["gap_ms"] == pytest.approx(5.4, abs=1e-6)
+
+
+@pytest.mark.parametrize("merged", [0, 2, 3])
+def test_the_merge_counters_by_sql_text(merged):
+    """`merge_device` counts the requests whose merge ran on the device; entries and survivors are medians."""
+    rows = []
+    for i in range(3):
+        st = json.loads(json.dumps(TAIL))
+        on_device = i < merged
+        st["execute"].update(merge_device=int(on_device), merge_entries=16_777_216 if on_device else 0,
+                             merge_survivors=10 + i if on_device else 0)
+        rows.append({"query": "topk", "lookback": 16, "status": 200, "good": True, "sent_s": float(i), "latency_ms": 905.0, "stages": st})
+    got = split.by_query(rows)["topk"]
+    assert got["merge_device"] == merged
+    assert got["merge_entries"] == (16_777_216 if merged >= 2 else 0)
+    assert got["merge_survivors"] == {0: 0, 2: 10, 3: 11}[merged]

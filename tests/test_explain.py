@@ -131,6 +131,8 @@ def test_explain_analyze_surfaces_device_routes(loaded):
         # program-cache accounting (dlint): XLA builds/reuses per query and
         # rebuilt-key recompiles — 0 recompiles is the steady-state contract
         "programs_built", "programs_reused", "recompiles",
+        # the block-local merge (ISSUE 28): where it ran, entries, survivors
+        "merge_device", "merge_host", "merge_entries", "merge_survivors",
     }
     assert int(routes["recompiles"]) == 0
     total_blocks = sum(
