@@ -7,7 +7,14 @@ turns pyarrow columns into device-friendly ndarrays:
 - timestamps -> int32 MILLISECONDS relative to a per-batch day-aligned
   origin (exact ms comparison/bin semantics on device); the origin depends
   only on the batch's data, so encodings stay query-independent and
-  hot-set cacheable, and per-batch deltas ship as runtime scalars
+  hot-set cacheable, and per-batch deltas ship as runtime scalars. A
+  column that does not fit within TIME_REL_SPAN of that origin (an order's
+  ship date years before the minute it was ingested in) keeps an origin of
+  its own and the coarsest unit that divides every value
+  (`EncodedColumn.origin_ms` / `unit_ms`): still exact, since the literal
+  of a comparison is turned into that unit with floor or ceiling by its
+  operator (executor_tpu `_time_lit`). What no unit holds is declined and
+  counted (`parseable_tpu_encode_declined_total{reason}`), never rounded
 - strings -> host-side dictionary encode; int32 codes go to device, the
   dictionary stays on host. String predicates (=, LIKE, regex) evaluate over
   the (small) dictionary once, then become an O(1) boolean LUT gather on
@@ -25,6 +32,10 @@ from typing import Any
 import numpy as np
 import pyarrow as pa
 import pyarrow.compute as pc
+
+from parseable_tpu.utils.metrics import ENCODE_DECLINED
+
+DAY_MS = 86_400_000
 
 # Max |rel| for encoded time values: headroom below int32 so the device
 # bin shift (+ origin%bin_ms, itself < 2^30) can never wrap
@@ -50,6 +61,11 @@ class EncodedColumn:
     all_valid: bool = False  # True -> `valid` need not ship to device
     vmin: int | None = None  # time cols: min/max of valid values (rel units)
     vmax: int | None = None
+    # time cols off the batch origin: values are whole `unit_ms` steps from
+    # the column's own day-aligned `origin_ms` (None: ms from the batch's)
+    unit_ms: int = 1
+    origin_ms: int | None = None
+    integral: bool = False  # num cols: the source type was an integer
 
     @property
     def cardinality(self) -> int:
@@ -86,6 +102,18 @@ def _code_dtype(card: int) -> np.dtype:
     if card <= 32767:
         return np.dtype(np.int16)
     return np.dtype(np.int32)
+
+
+def _declined(reason: str) -> None:
+    """A column the device cannot hold: the caller takes the CPU path for
+    the queries that name it, and the decline is a number an operator can
+    read (`reason`: time_span | sub_ms | nested | other)."""
+    ENCODE_DECLINED.labels(reason).inc()
+    return None
+
+
+# the units an off-origin time column is tried in, coarsest first
+TIME_UNITS_MS = (DAY_MS, 3_600_000, 60_000, 1000, 1)
 
 
 def encode_column(
@@ -134,11 +162,11 @@ def encode_column(
                 # sub-ms residue would floor away: the device's ms values
                 # could then satisfy predicates the true values don't —
                 # decline the column, CPU compares at full precision
-                return None
+                return _declined("sub_ms")
             ms = raw // 1000
         elif str(t).startswith("timestamp[ns"):
             if len(raw) and (raw % 1_000_000).any():
-                return None
+                return _declined("sub_ms")
             ms = raw // 1_000_000
         elif str(t).startswith("timestamp[s"):
             ms = raw * 1000
@@ -150,8 +178,23 @@ def encode_column(
         # every block once the origin is per-block ms
         if not all_valid:
             rel = np.where(valid[: len(rel)], rel, 0)
+        unit_ms, origin_ms = 1, None
         if len(rel) and (rel.min() < -TIME_REL_SPAN or rel.max() > TIME_REL_SPAN):
-            return None  # would wrap int32 -> caller takes the CPU path
+            # would wrap int32 around the batch origin: the column keeps a
+            # day-aligned origin of its own and the coarsest unit that
+            # divides every live value, or the caller takes the CPU path
+            live_ms = ms if col.null_count == 0 else ms[valid[: len(ms)]]
+            origin_ms = int(live_ms.min()) // DAY_MS * DAY_MS
+            span = int(live_ms.max()) - origin_ms
+            unit_ms = next(
+                (u for u in TIME_UNITS_MS if span // u <= TIME_REL_SPAN and not (live_ms % u).any()),
+                0,
+            )
+            if not unit_ms:
+                return _declined("time_span")
+            rel = (ms - origin_ms) // unit_ms
+            if not all_valid:
+                rel = np.where(valid[: len(rel)], rel, 0)
         vals = _pad(rel.astype(np.int32), block_rows)
         if col.null_count == len(col):
             vmin = vmax = None
@@ -161,7 +204,8 @@ def encode_column(
             live = rel[np.asarray(pc.is_valid(col).to_numpy(zero_copy_only=False), bool)]
             vmin, vmax = (int(live.min()), int(live.max())) if len(live) else (None, None)
         return EncodedColumn(
-            name, "time", vals, valid, all_valid=all_valid, vmin=vmin, vmax=vmax
+            name, "time", vals, valid, all_valid=all_valid, vmin=vmin, vmax=vmax,
+            unit_ms=unit_ms, origin_ms=origin_ms,
         )
     if pa.types.is_boolean(t):
         vals = np.asarray(col.fill_null(False).to_numpy(zero_copy_only=False), dtype=np.float32)
@@ -170,7 +214,10 @@ def encode_column(
         vals = np.asarray(
             pc.cast(col, pa.float64()).fill_null(0.0).to_numpy(zero_copy_only=False)
         ).astype(np.float32)
-        return EncodedColumn(name, "num", _pad(vals, block_rows), valid, all_valid=all_valid)
+        return EncodedColumn(
+            name, "num", _pad(vals, block_rows), valid, all_valid=all_valid,
+            integral=pa.types.is_integer(t),
+        )
     if pa.types.is_string(t) or pa.types.is_large_string(t):
         denc = pc.dictionary_encode(col)
         if isinstance(denc, pa.ChunkedArray):
@@ -199,10 +246,8 @@ def encode_column(
             dictionary + [None],
             all_valid=all_valid,
         )
-    return None  # unsupported (lists, nested) -> caller falls back to CPU
-
-
-DAY_MS = 86_400_000
+    # unsupported (lists, nested) -> caller falls back to CPU
+    return _declined("nested" if pa.types.is_nested(t) else "other")
 
 
 def _batch_time_origin(table: pa.Table) -> int:
@@ -212,26 +257,45 @@ def _batch_time_origin(table: pa.Table) -> int:
     and enccache variant merges never thrash on origin mismatches. Day
     alignment means `origin % bin_ms == 0` for every sub-day bin, and the
     per-block rel values (minute-bucketed blocks span minutes) sit
-    comfortably inside TIME_REL_SPAN."""
-    lo: int | None = None
+    comfortably inside TIME_REL_SPAN.
+
+    Where the columns do not all fit around one origin (a 1992 ship date in
+    a block ingested in 2024), the origin stays with the block's own clock:
+    `p_timestamp`, or without it the column of the least span, and the
+    columns near it. The far ones keep an origin of their own
+    (`encode_column`), so a query that does not name them encodes the block
+    exactly as if they were not there."""
+    from parseable_tpu import DEFAULT_TIMESTAMP_KEY
+
+    spans: dict[str, tuple[int, int]] = {}
     for name in table.column_names:
         col = table.column(name)
         t = col.type
         if not pa.types.is_timestamp(t):
             continue
-        m = pc.cast(pc.min(col), pa.int64()).as_py()  # int in the col's unit
-        if m is None:
+        mm = pc.min_max(col)
+        lo, hi = pc.cast(mm["min"], pa.int64()).as_py(), pc.cast(mm["max"], pa.int64()).as_py()
+        if lo is None:
             continue
         if str(t).startswith("timestamp[us"):
-            m //= 1000
+            lo, hi = lo // 1000, hi // 1000
         elif str(t).startswith("timestamp[ns"):
-            m //= 1_000_000
+            lo, hi = lo // 1_000_000, hi // 1_000_000
         elif str(t).startswith("timestamp[s"):
-            m *= 1000
-        lo = m if lo is None else min(lo, m)
-    if lo is None:
+            lo, hi = lo * 1000, hi * 1000
+        spans[name] = (lo, hi)
+    if not spans:
         return 0
-    return (lo // DAY_MS) * DAY_MS
+    origin = min(lo for lo, _ in spans.values()) // DAY_MS * DAY_MS
+    if max(hi for _, hi in spans.values()) - origin <= TIME_REL_SPAN:
+        return origin
+    anchor = spans.get(DEFAULT_TIMESTAMP_KEY) or min(
+        spans.values(), key=lambda s: (s[1] - s[0], s[0])
+    )
+    base = anchor[0] // DAY_MS * DAY_MS
+    half = (TIME_REL_SPAN - DAY_MS) // 2
+    near = [lo for lo, hi in spans.values() if lo >= base - half and hi <= base + half]
+    return min(near, default=anchor[0]) // DAY_MS * DAY_MS
 
 
 def encode_table(

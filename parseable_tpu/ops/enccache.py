@@ -35,6 +35,7 @@ queue-depth gauge makes the pressure visible before drops start.
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import json
 import logging
@@ -122,13 +123,7 @@ class EncodedBlockCache:
         the next query re-encodes), never lost silently."""
         import queue as _q
 
-        snap_cols = {
-            name: EncodedColumn(
-                c.name, c.kind, c.values, c.valid, c.dictionary,
-                all_valid=c.all_valid, vmin=c.vmin, vmax=c.vmax,
-            )
-            for name, c in enc.columns.items()
-        }
+        snap_cols = {name: dataclasses.replace(c) for name, c in enc.columns.items()}
         snap = EncodedBatch(
             num_rows=enc.num_rows,
             block_rows=enc.block_rows,
@@ -284,6 +279,12 @@ class EncodedBlockCache:
                 "vmin": col.vmin,
                 "vmax": col.vmax,
             }
+            # said only where they differ from the defaults, so that an
+            # entry without such a column is the bytes it always was
+            if col.origin_ms is not None:
+                var["unit_ms"], var["origin_ms"] = col.unit_ms, col.origin_ms
+            if col.integral:
+                var["integral"] = True
             bufs = [values.tobytes()]
             if not col_all_valid:
                 valid = np.ascontiguousarray(col.valid[:block])
@@ -409,6 +410,9 @@ class EncodedBlockCache:
                         all_valid=bool(pick["all_valid"]) and n == block,
                         vmin=pick.get("vmin"),
                         vmax=pick.get("vmax"),
+                        unit_ms=pick.get("unit_ms", 1),
+                        origin_ms=pick.get("origin_ms"),
+                        integral=bool(pick.get("integral", False)),
                     )
             finally:
                 fh.close()

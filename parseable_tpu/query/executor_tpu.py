@@ -31,16 +31,34 @@ The single-dispatch + async + resident-data design assumes per-query
 host<->device traffic — not FLOPs — is the budget; neither the kernel's
 rate nor the cost of a device round trip is measured on today's code.
 
-Anything the device path can't express (nested types, aggregates over
-expressions, date_bin with custom origin or sub-millisecond bins, exact
-distinct beyond the bitmap budget) falls
-back to the CPU executor — whole-query when detected at plan time, per-table
-otherwise — merging into the same aggregator, so results stay complete and
-exact. That is the ONLY way work leaves the device: a declared
-`UnsupportedOnDevice` decision, counted in `route_stats["cpu_fallback"]`.
+What the device path does not run is a declared `UnsupportedOnDevice`,
+and the list is short. At plan time, which hands the whole query to the CPU
+engine: an aggregate whose argument is not a column or arithmetic over
+numeric columns (a function call, CASE, a string, boolean or timestamp
+operand, a divisor that is not a nonzero constant), a percentile or a
+distinct count over an expression, an unknown aggregate. Per block, which
+folds that block on the CPU engine into the same aggregator, so results stay
+complete and exact: a column ops/device.py declines (a nested type, a
+timestamp with sub-millisecond residue, a timestamp column no whole unit
+holds in int32), an integer division (it truncates on the CPU engine), a
+numeric aggregate over a string or timestamp column, `date_bin` with a
+custom origin or sub-millisecond bins or over a timestamp column that is off
+the block's origin, `p_timestamp` itself off that origin under time bounds,
+exact distinct or percentile state beyond its budget. That is the ONLY way
+work leaves the device: every table or block so handed is counted in
+`route_stats["cpu_fallback"]` (a plan-time rejection with its reason under
+`cpu_fallback_reason`), every declined encoding in `encode_declined` and
+`parseable_tpu_encode_declined_total{reason}`.
 Any other exception from building or running a device program (a compiler
 refusal, an HBM OOM) propagates and fails the query — a broken device path
 must not answer from the CPU and look healthy.
+
+Aggregates over arithmetic (`sum(price * (1 - discount))`: `+ - * /`, unary
+minus, CAST between numerics, over numeric columns and literals) fold inside
+the same programs: `AggExprCompiler` traces each tree in f32 under the scope
+`fold/expr` into one more value row, a shared subtree once, validity the
+AND of the operands'; `expr_aggs_device` / `expr_aggs_host` say where a
+request's expressions were evaluated.
 
 Precision: per-block reductions run in f32 (blocks <= 2^22 rows keep counts
 exact; sums carry ~1e-5 relative error vs the CPU engine's f64); cross-block
@@ -49,8 +67,12 @@ milliseconds relative to the block origin (see ops/device.py), so EVERY
 comparison op — `<`, `>=`, `>`, `<=`, `=`, `!=`, including sub-second
 literals — evaluates exactly on device with no second-granularity fallback;
 sub-millisecond literals floor to ms, matching the CPU engine's coercion
-(the two engines agree row-for-row). Columns with sub-ms residue decline
-device encoding and take the CPU path instead.
+(the two engines agree row-for-row). A timestamp column further than 12.4
+days from the block's origin (an order's ship date) is held in the coarsest
+whole unit that divides its values, from an origin of its own, and a
+literal is turned into that unit by its operator (`_time_lit`): still exact.
+Columns with sub-ms residue, or that no unit holds, decline device encoding
+and take the CPU path instead, counted.
 """
 
 from __future__ import annotations
@@ -87,6 +109,7 @@ from parseable_tpu.query.sketch import _SCALE as PCT_SCALE
 from parseable_tpu.utils.metrics import (
     DEVICE_BYTES_TO_DEVICE,
     DEVICE_EXECUTE_TIME,
+    DEVICE_EXPR_AGGREGATES,
     DEVICE_JIT_PROGRAMS,
     DEVICE_MERGES,
     DEVICE_PHASE_SECONDS,
@@ -401,7 +424,7 @@ class PredicateCompiler:
                     # per-block rel-ms literal as a runtime scalar: rides
                     # the LUT channel so one compiled program serves every
                     # block regardless of its time origin
-                    out.append(self._time_lit(enc, op, lit))
+                    out.append(self._time_lit(enc, col, op, lit))
                 return
             if e.op in ("like", "ilike", "not_like", "not_ilike"):
                 col = self._column_of(e.left, enc)
@@ -543,8 +566,8 @@ class PredicateCompiler:
         return jnp.logical_and(mask, valid)
 
     @staticmethod
-    def _time_lit(enc: EncodedBatch, op: str, lit: Any) -> np.ndarray:
-        """Literal as block-relative int32 ms, shipped as a runtime scalar.
+    def _time_lit(enc: EncodedBatch, col: EncodedColumn, op: str, lit: Any) -> np.ndarray:
+        """Literal in the column's own int32 steps, shipped as a runtime scalar.
 
         Sub-ms literals FLOOR to ms — matching the CPU engine, whose
         comparisons coerce the literal to the (ms) column type via
@@ -552,19 +575,29 @@ class PredicateCompiler:
         two engines must agree row-for-row, and device rows are
         ms-quantized anyway (encode declines columns with sub-ms residue).
 
+        A column off the batch origin (ops/device.py: `origin_ms`, whole
+        steps of `unit_ms`) holds no value between two steps, so the ms
+        literal is turned into steps toward the side that changes no row:
+        ceiling for `<` and `>=`, floor for `<=` and `>`; between two
+        steps `=` can hold for no row and `!=` holds for every one.
+
         Out-of-range literals clamp to just inside int32: encoded rel
         values are bounded by TIME_REL_SPAN (< 2^30), so a clamped bound
         compares uniformly true/false against every row — exactly the
         semantics of a literal beyond the block's representable window —
         and can never equal a live value."""
-        del op  # same floor for every comparison op (CPU-engine parity)
         if isinstance(lit, str):
             lit_dt = parse_rfc3339(lit)
         elif isinstance(lit, datetime):
             lit_dt = lit if lit.tzinfo else lit.replace(tzinfo=UTC)
         else:
             raise UnsupportedOnDevice("timestamp compared to non-time literal")
-        rel = _dt_to_us(lit_dt) // 1000 - enc.time_origin_ms
+        origin = enc.time_origin_ms if col.origin_ms is None else col.origin_ms
+        rel, between = divmod(_dt_to_us(lit_dt) // 1000 - origin, col.unit_ms)
+        if between and op in ("=", "!="):
+            rel = 2**31 - 2  # no live value
+        elif between and op in ("<", ">="):
+            rel += 1
         rel = max(-(2**31) + 2, min(2**31 - 2, rel))
         return np.asarray([rel], dtype=np.int32)
 
@@ -686,6 +719,14 @@ def _dt_to_us(dt: datetime) -> int:
     return (dt - _EPOCH_UTC) // timedelta(microseconds=1)
 
 
+def _require_on_origin(col: EncodedColumn | None) -> None:
+    """Time bins and the request's time bounds are arithmetic in ms from the
+    block's origin: a column that keeps an origin and a unit of its own
+    (ops/device.py) takes neither on the device."""
+    if col is not None and col.origin_ms is not None:
+        raise UnsupportedOnDevice(f"time column {col.name} is off the block's origin")
+
+
 def _num_cmp(values, op: str, threshold):
     import jax.numpy as jnp
 
@@ -698,6 +739,171 @@ def _num_cmp(values, op: str, threshold):
         ">": values > t,
         ">=": values >= t,
     }[op]
+
+
+# --------------------------------------------- aggregates over expressions
+
+_INT_CASTS = ("int", "integer", "bigint")
+_FLOAT_CASTS = ("float", "double", "real")
+
+
+class AggExprCompiler:
+    """Aggregate arguments that are arithmetic over numeric columns
+    (`sum(price * (1 - discount))`): trees of `+ - * /`, unary minus and
+    CAST between numerics over `num` columns and numeric literals.
+
+    An expression becomes one more value row of the fold that is there: it
+    is named by its canonical text (`name`), the layouts carry that name
+    like a column's (so it is part of every program's cache key), and
+    `trace` computes it in f32 from the block's column arrays inside the
+    program, its validity the AND of its operands' (a NULL operand makes a
+    NULL, which no aggregate takes, as pyarrow's arithmetic does on the CPU
+    engine). A subtree that two expressions share is traced once.
+
+    What stays a declared `UnsupportedOnDevice`: a function call or CASE, a
+    string, boolean or timestamp operand, a divisor that is not a nonzero
+    constant (x / 0 is +-inf on the CPU engine, and one non-finite addend
+    would reach every group of the one-hot fold), and a division of two
+    integers (which truncates there)."""
+
+    PREFIX = "__expr:"
+
+    @classmethod
+    def name(cls, e: S.Expr) -> str:
+        return cls.PREFIX + cls._text(e)
+
+    @classmethod
+    def _text(cls, e: S.Expr) -> str:
+        if isinstance(e, S.Column):
+            return e.name
+        if isinstance(e, S.Literal):
+            return repr(e.value)
+        if isinstance(e, S.UnaryOp):
+            return f"({e.op}{cls._text(e.operand)})"
+        if isinstance(e, S.BinaryOp):
+            return f"({cls._text(e.left)} {e.op} {cls._text(e.right)})"
+        if isinstance(e, S.Cast):
+            return f"cast({cls._text(e.expr)} as {e.type_name})"
+        raise UnsupportedOnDevice(f"aggregate over expression: {S.expr_name(e)}")
+
+    @classmethod
+    def constant(cls, e: S.Expr):
+        """The subtree's value where it names no column (folded as the CPU
+        engine folds it: python arithmetic), else None."""
+        from parseable_tpu.query.executor import _python_binop
+
+        if isinstance(e, S.Literal):
+            v = e.value
+            return v if isinstance(v, (int, float)) and not isinstance(v, bool) else None
+        if isinstance(e, S.UnaryOp) and e.op == "-":
+            v = cls.constant(e.operand)
+            return None if v is None else -v
+        if isinstance(e, S.BinaryOp) and e.op in ("+", "-", "*", "/"):
+            a, b = cls.constant(e.left), cls.constant(e.right)
+            if a is None or b is None or (e.op == "/" and b == 0):
+                return None
+            return _python_binop(e.op, a, b)
+        if isinstance(e, S.Cast):
+            v = cls.constant(e.expr)
+            if v is None or e.type_name not in _INT_CASTS + _FLOAT_CASTS:
+                return None
+            return int(v) if e.type_name in _INT_CASTS else float(v)
+        return None
+
+    @classmethod
+    def check(cls, e: S.Expr, enc: EncodedBatch | None = None) -> str:
+        """The tree's type, "int" or "float", as the CPU engine would have
+        it; raises `UnsupportedOnDevice` for what the device does not run.
+        Without a block only the tree's shape is checked (plan time);
+        with one, its columns' kinds and the integer divisions too."""
+        c = cls.constant(e)
+        if c is not None:
+            return "int" if isinstance(c, int) else "float"
+        if isinstance(e, S.Column):
+            if enc is None:
+                return "float"
+            col = enc.columns.get(e.name)
+            if col is None:
+                raise UnsupportedOnDevice(f"aggregate column {e.name} missing")
+            if col.kind != "num":
+                raise UnsupportedOnDevice(f"expression over a {col.kind} column: {e.name}")
+            return "int" if col.integral else "float"
+        if isinstance(e, S.UnaryOp) and e.op == "-":
+            return cls.check(e.operand, enc)
+        if isinstance(e, S.Cast) and e.type_name in _INT_CASTS + _FLOAT_CASTS:
+            cls.check(e.expr, enc)
+            return "int" if e.type_name in _INT_CASTS else "float"
+        if isinstance(e, S.BinaryOp) and e.op in ("+", "-", "*", "/"):
+            a, b = cls.check(e.left, enc), cls.check(e.right, enc)
+            if e.op == "/":
+                if not cls.constant(e.right):
+                    raise UnsupportedOnDevice(f"division by other than a nonzero constant: {cls._text(e)}")
+                if enc is not None and a == b == "int":
+                    raise UnsupportedOnDevice(f"integer division: {cls._text(e)}")
+            return "int" if a == b == "int" else "float"
+        raise UnsupportedOnDevice(f"aggregate over expression: {S.expr_name(e)}")
+
+    @classmethod
+    def nodes(cls, exprs: tuple) -> int:
+        """Arithmetic nodes `trace` makes a row for `exprs`, by the tree
+        alone (a CAST to a float type makes none)."""
+        seen: set[str] = set()
+
+        def visit(e: S.Expr) -> None:
+            if cls.constant(e) is not None or isinstance(e, S.Column):
+                return
+            if isinstance(e, S.Cast) and e.type_name in _FLOAT_CASTS:
+                return visit(e.expr)
+            seen.add(cls._text(e))
+            for child in (getattr(e, k, None) for k in ("operand", "expr", "left", "right")):
+                if child is not None:
+                    visit(child)
+
+        for _, tree in exprs:
+            visit(tree)
+        return len(seen)
+
+    @classmethod
+    def trace(cls, dev: dict, exprs: tuple) -> dict:
+        """`dev` with one value column and one validity column more for
+        each of `exprs` ((name, tree) pairs), shared subtrees traced once.
+        Runs under jit and shard_map alike: it only reads the block's
+        arrays."""
+        import jax.numpy as jnp
+
+        seen: dict[str, tuple] = {}
+
+        def both(a, b):
+            return b if a is None else a if b is None else jnp.logical_and(a, b)
+
+        def visit(e: S.Expr) -> tuple:
+            c = cls.constant(e)
+            if c is not None:
+                return jnp.float32(c), None
+            if isinstance(e, S.Column):
+                return dev[e.name].astype(jnp.float32), dev[f"{e.name}__valid"]
+            text = cls._text(e)
+            if text in seen:
+                return seen[text]
+            if isinstance(e, S.UnaryOp):
+                v, ok = visit(e.operand)
+                out = (-v, ok)
+            elif isinstance(e, S.Cast):
+                v, ok = visit(e.expr)
+                if e.type_name in _FLOAT_CASTS:
+                    return v, ok  # f32 already: no node
+                out = (jnp.trunc(v), ok)
+            else:
+                (a, ok_a), (b, ok_b) = visit(e.left), visit(e.right)
+                v = {"+": jnp.add, "-": jnp.subtract, "*": jnp.multiply, "/": jnp.divide}[e.op](a, b)
+                out = (v, both(ok_a, ok_b))
+            seen[text] = out
+            return out
+
+        out = dict(dev)
+        for name, tree in exprs:
+            out[name], out[f"{name}__valid"] = visit(tree)
+        return out
 
 
 # ------------------------------------------------------------ dense agg state
@@ -718,7 +924,8 @@ class AccLayout:
     Validity rows mirror the same order (percentile dup rows are NaN-aware
     so sketch counts match the host path, which drops NaN). Accumulator
     rows: [0] count(*) mask hits | [1, 1+n_allk) per-agg counts | n_sum
-    sums | n_sq sum(x) | n_sq M2 | n_mink mins | n_maxk maxs.
+    sums | n_sq sum(x) | n_sq M2 | n_mink mins | n_maxk maxs, and on the
+    device, after them, one carry row for each count row (`n_counts`).
 
     stddev/var keep CENTERED second moments (M2 = sum((x - mean_g)^2), the
     per-block per-group mean), merged across blocks/devices with Chan's
@@ -776,6 +983,16 @@ class AccLayout:
     @property
     def n_rows(self) -> int:  # total packed accumulator rows
         return 1 + self.n_allk + self.n_sumk + self.n_mink + self.n_maxk
+
+    @property
+    def n_counts(self) -> int:
+        """The count rows ([0] and the per-agg counts). On the device each
+        has a carry row after the packed rows (`n_rows + i`): the f32 count
+        row holds the rounded running total and the carry row what each
+        add's rounding left out (TwoSum), so that a group's count stays
+        exact past 2^24 rows, where f32 holds even numbers only.
+        `_read_counts_exact` adds the two on the host in f64."""
+        return 1 + self.n_allk
 
     # -------------------------------------------------- absolute acc row index
 
@@ -837,9 +1054,13 @@ class AccLayout:
             if spec.func == "count_star":
                 continue
             if not isinstance(spec.arg, S.Column):
-                raise UnsupportedOnDevice(
-                    f"aggregate over expression: {S.expr_name(spec.arg)}"
-                )
+                if spec.func not in ("sum", "avg", "stddev", "var", "min", "max", "count"):
+                    raise UnsupportedOnDevice(
+                        f"{spec.func} over expression: {S.expr_name(spec.arg)}"
+                    )
+                if AggExprCompiler.constant(spec.arg) is not None:
+                    raise UnsupportedOnDevice(f"aggregate over a constant: {S.expr_name(spec.arg)}")
+                AggExprCompiler.check(spec.arg)
             if spec.func in ("sum", "avg"):
                 sum_idx.append(i)
             elif spec.func in ("stddev", "var"):
@@ -889,6 +1110,10 @@ class PlanLayout:
     sq_cols: list[str] = dc_field(default_factory=list)  # stddev/var inputs
     pct_cols: list[str] = dc_field(default_factory=list)  # percentile inputs
     cnt_cols: list[str] = dc_field(default_factory=list)  # count(col) inputs
+    # aggregate arguments that are expressions: (name, tree) pairs. The col
+    # lists above hold the names (the tree's canonical text), so the
+    # program keys that hold those lists hold the expressions too
+    exprs: tuple = ()
 
 
 def _kernel_stacks(dev: dict, layout: "PlanLayout", local_rows: int):
@@ -1064,7 +1289,8 @@ class RouteStats(dict):
     """One query's route counters (the items: what `device_routes` and
     `stages.programs` publish) and, as attributes, its execute-phase clock
     (what `stages.execute` publishes): `ns[phase]` monotonic nanoseconds,
-    `blocks` and `readbacks` counts, and two time stamps on the same clock,
+    `blocks` and `readbacks` counts, `expr_nodes` (arithmetic nodes traced a
+    row for the aggregates over expressions), and two time stamps on the same clock,
     `first_dispatch_ns` (the first program call returned) and
     `last_readback_ns` (the last readback ended), 0 where none happened.
 
@@ -1072,7 +1298,7 @@ class RouteStats(dict):
     next, so phases never overlap and their sum is at most the wall time.
     A nested bracket hands the clock back with the value `enter` returned."""
 
-    __slots__ = ("ns", "blocks", "readbacks", "first_dispatch_ns", "last_readback_ns", "_phase", "_since")
+    __slots__ = ("ns", "blocks", "readbacks", "expr_nodes", "first_dispatch_ns", "last_readback_ns", "_phase", "_since")
 
     def __init__(self) -> None:
         super().__init__(
@@ -1099,9 +1325,17 @@ class RouteStats(dict):
             merge_host=0,
             merge_entries=0,
             merge_survivors=0,
+            # aggregate outputs whose argument is an arithmetic expression:
+            # folded inside the device program / evaluated by the CPU engine
+            # (a plan-time rejection, or a block it folded). A plan-time
+            # rejection says why under `cpu_fallback_reason` (a string)
+            expr_aggs_device=0,
+            expr_aggs_host=0,
+            # blocks whose encoding ops/device.py declined for a column
+            encode_declined=0,
         )
         self.ns = dict.fromkeys(PHASES, 0)
-        self.blocks = self.readbacks = 0
+        self.blocks = self.readbacks = self.expr_nodes = 0
         self.first_dispatch_ns = self.last_readback_ns = 0
         self._phase: str | None = None
         self._since = 0
@@ -1173,6 +1407,16 @@ def _timed_readback(
     if clock is not None:
         clock.read_back(prev)
     return arr
+
+
+def _read_counts_exact(arr: np.ndarray, lay: "AccLayout") -> np.ndarray:
+    """A dense accumulator (or columns gathered from one) as read back, f64:
+    the count rows made exact by their carry rows (`AccLayout.n_counts`),
+    the carry rows dropped, so every reader sees the packed rows alone (an
+    array of the packed rows alone is returned as it is)."""
+    if arr.shape[0] > lay.n_rows:
+        arr[: lay.n_counts] += arr[lay.n_rows :]
+    return arr[: lay.n_rows]
 
 
 def _f32_order(x):
@@ -1558,11 +1802,25 @@ class TpuQueryExecutor(QueryExecutor):
                     return self._execute_aggregate_tpu(tables)
                 except UnsupportedOnDevice as e:
                     # plan-time rejection: the iterator is untouched;
-                    # materialize any hot stubs for the CPU engine
+                    # materialize any hot stubs for the CPU engine, and
+                    # count every table it answers
                     logger.info("TPU path unsupported (%s); falling back to CPU", e)
-                    return super()._execute_aggregate(
-                        self._materialize(t) for t in tables
+                    rs = self.route_stats
+                    rs["cpu_fallback_reason"] = str(e)
+                    n_expr = sum(
+                        s.arg is not None and not isinstance(s.arg, (S.Column, S.Star))
+                        for s in self.build_aggregator()[0].specs
                     )
+                    if n_expr:
+                        rs["expr_aggs_host"] = n_expr
+                        DEVICE_EXPR_AGGREGATES.labels("host").inc(n_expr)
+
+                    def counted() -> Iterator[pa.Table]:
+                        for t in tables:
+                            rs["cpu_fallback"] += 1
+                            yield self._materialize(t)
+
+                    return super()._execute_aggregate(counted())
             return self._execute_select_tpu(tables)
         finally:
             self._close_prefetcher()
@@ -1847,6 +2105,7 @@ class TpuQueryExecutor(QueryExecutor):
         table = self._materialize(table)
         enc = encode_table(table, needed, dict_columns=dict_cols)
         if enc is None:
+            self.route_stats["encode_declined"] += 1
             raise UnsupportedOnDevice("unencodable column in batch")
         dev, nbytes = _transfer(enc, self.mesh)
         self.route_stats["device_cold"] += 1
@@ -1881,6 +2140,15 @@ class TpuQueryExecutor(QueryExecutor):
         pct_idx = list(lay.pct_idx)
         distinct_idx = list(lay.distinct_idx)
         stacked_idx = sum_idx + sq_idx + min_idx + max_idx + countcol_idx
+
+        def arg_name(i: int) -> str:
+            """The value row spec `i` reads: its column, or the name its
+            expression is traced under (AggExprCompiler)."""
+            arg = specs[i].arg
+            return arg.name if isinstance(arg, S.Column) else AggExprCompiler.name(arg)
+
+        expr_idx = [i for i in stacked_idx if not isinstance(specs[i].arg, S.Column)]
+        exprs = tuple({arg_name(i): specs[i].arg for i in expr_idx}.items())
 
         # count(distinct y): y dict-encodes like a group key; per block a
         # segment_max ORs presence bits into a [G, Vcap] device bitmap
@@ -1919,6 +2187,7 @@ class TpuQueryExecutor(QueryExecutor):
                 np.zeros((1 + lay.n_allk + lay.n_sumk, num_groups), np.float32),
                 np.full((lay.n_mink, num_groups), np.float32(3.4e38)),
                 np.full((lay.n_maxk, num_groups), np.float32(-3.4e38)),
+                np.zeros((lay.n_counts, num_groups), np.float32),  # the counts' carry rows
             ]
             host = np.concatenate(parts, axis=0)
             if self.mesh is not None:
@@ -1947,7 +2216,7 @@ class TpuQueryExecutor(QueryExecutor):
             """ONE device->host readback per accumulator, folded into the
             sparse agg (distinct presence bitmaps and percentile histograms
             decode alongside)."""
-            arr = _timed_readback(acc_dev, self.route_stats)
+            arr = _read_counts_exact(_timed_readback(acc_dev, self.route_stats), lay)
             dists = [
                 (
                     si,
@@ -2027,16 +2296,17 @@ class TpuQueryExecutor(QueryExecutor):
                 key_specs=key_specs,
                 caps=tuple(ks.capacity for ks in key_specs),
                 origins=tuple(ks.origin_rel or 0 for ks in key_specs),
-                sum_cols=[specs[i].arg.name for i in sum_idx],
-                min_cols=[specs[i].arg.name for i in min_idx],
-                max_cols=[specs[i].arg.name for i in max_idx],
-                stacked_cols=[specs[i].arg.name for i in stacked_idx],
+                sum_cols=[arg_name(i) for i in sum_idx],
+                min_cols=[arg_name(i) for i in min_idx],
+                max_cols=[arg_name(i) for i in max_idx],
+                stacked_cols=[arg_name(i) for i in stacked_idx],
                 distinct_cols=[dk.column for dk in dkeys],
                 distinct_caps=tuple(dk.capacity for dk in dkeys),
                 distinct_sketch=tuple(dk_sketch),
-                sq_cols=[specs[i].arg.name for i in sq_idx],
-                pct_cols=[specs[i].arg.name for i in pct_idx],
-                cnt_cols=[specs[i].arg.name for i in countcol_idx],
+                sq_cols=[arg_name(i) for i in sq_idx],
+                pct_cols=[arg_name(i) for i in pct_idx],
+                cnt_cols=[arg_name(i) for i in countcol_idx],
+                exprs=exprs,
             )
             prev = rs.enter("dispatch")
             try:
@@ -2081,12 +2351,13 @@ class TpuQueryExecutor(QueryExecutor):
             key_specs=key_specs,
             caps=(),
             origins=(),
-            sum_cols=[specs[i].arg.name for i in sum_idx],
-            min_cols=[specs[i].arg.name for i in min_idx],
-            max_cols=[specs[i].arg.name for i in max_idx],
-            stacked_cols=[specs[i].arg.name for i in stacked_idx],
-            sq_cols=[specs[i].arg.name for i in sq_idx],
-            cnt_cols=[specs[i].arg.name for i in countcol_idx],
+            sum_cols=[arg_name(i) for i in sum_idx],
+            min_cols=[arg_name(i) for i in min_idx],
+            max_cols=[arg_name(i) for i in max_idx],
+            stacked_cols=[arg_name(i) for i in stacked_idx],
+            sq_cols=[arg_name(i) for i in sq_idx],
+            cnt_cols=[arg_name(i) for i in countcol_idx],
+            exprs=exprs,
         )
 
         # adaptive dispatch: per non-resident block, estimated ship (+
@@ -2181,6 +2452,9 @@ class TpuQueryExecutor(QueryExecutor):
                     enc, dev = self._encoded_block(table, self.plan.needed_columns, dict_cols)
                     rs.enter("prepare")
                     for i in stacked_idx + pct_idx:
+                        if i in expr_idx:
+                            AggExprCompiler.check(specs[i].arg, enc)
+                            continue
                         col = enc.columns.get(specs[i].arg.name)
                         if col is None:
                             raise UnsupportedOnDevice(f"aggregate column {specs[i].arg.name} missing")
@@ -2361,6 +2635,19 @@ class TpuQueryExecutor(QueryExecutor):
 
             dispatch_pending()
             sp_blocks["rows"] = rs.blocks
+            sp_blocks["expr_aggs"] = len(expr_idx)
+        if expr_idx:
+            # where the expressions were evaluated: in the device program for
+            # the blocks it folded, by the CPU engine for the blocks that did
+            on_cpu = rs["cpu_fallback"] + rs["cpu_adaptive"]
+            if rs.blocks > on_cpu:
+                rs["expr_aggs_device"] = len(expr_idx)
+                rs.expr_nodes = AggExprCompiler.nodes(exprs)
+                DEVICE_EXPR_AGGREGATES.labels("device").inc(len(expr_idx))
+            if on_cpu:
+                rs["expr_aggs_host"] = len(expr_idx)
+                DEVICE_EXPR_AGGREGATES.labels("host").inc(len(expr_idx))
+
         def finish(interim: pa.Table | None) -> pa.Table:
             """Every exit: the group-by's host wall time up to here (scan,
             dispatch, device waits, readbacks and host merge, all of it),
@@ -2452,7 +2739,7 @@ class TpuQueryExecutor(QueryExecutor):
             ]
             with TRACER.span("execute.readback", rows=acc_groups) as sp:
                 before = rs["d2h_bytes"]
-                arr = _timed_readback(acc, rs)
+                arr = _read_counts_exact(_timed_readback(acc, rs), lay)
                 sp["bytes"] = rs["d2h_bytes"] - before
             prev = rs.enter("partial")
             interim = self._dense_interim(
@@ -2687,7 +2974,7 @@ class TpuQueryExecutor(QueryExecutor):
         gathered, idx = program(acc)
         self.route_stats.dispatched(prev)
         return (
-            _timed_readback(gathered, self.route_stats),
+            _read_counts_exact(_timed_readback(gathered, self.route_stats), lay),
             _timed_readback(idx, self.route_stats, dtype=None),
         )
 
@@ -3155,6 +3442,9 @@ class TpuQueryExecutor(QueryExecutor):
                     ids = (ids if ids is not None else jnp.zeros(local_rows, jnp.int32)).astype(jnp.int32)
                 ids = ids.astype(jnp.int32)
 
+            if layout.exprs:
+                with jax.named_scope("fold"), jax.named_scope("expr"):
+                    dev = AggExprCompiler.trace(dev, layout.exprs)
             with jax.named_scope("fold"):
                 sum_v, min_v, max_v, valid_v, n_sumk, n_mink, n_maxk = _kernel_stacks(
                     dev, layout, local_rows
@@ -3305,7 +3595,7 @@ class TpuQueryExecutor(QueryExecutor):
         """Dense global accumulator -> partial table (used when switching to
         block-local mode mid-query: the dense epoch's results merge through
         the same vectorized group_by as the block partials)."""
-        arr = _timed_readback(acc, self.route_stats)
+        arr = _read_counts_exact(_timed_readback(acc, self.route_stats), lay)
         prev = self.route_stats.enter("partial")
         keyinfo: list[tuple] = []
         for ks in key_specs:
@@ -3550,6 +3840,9 @@ class TpuQueryExecutor(QueryExecutor):
                     mask = jnp.logical_and(mask, in_window)
                     ids = jnp.clip(local, 0, kernel_groups - 1)
 
+            if layout.exprs:
+                with jax.named_scope("fold"), jax.named_scope("expr"):
+                    dev = AggExprCompiler.trace(dev, layout.exprs)
             with jax.named_scope("fold"):
                 sum_v, min_v, max_v, valid_v, n_sumk, n_mink, n_maxk = _kernel_stacks(
                     dev, layout, local_rows
@@ -3636,9 +3929,18 @@ class TpuQueryExecutor(QueryExecutor):
             a0 = adds.shape[0]  # 1 + n_allk + n_sum + n_sq (additive rows)
             n_sq = len(layout.sq_cols)
             n_sum_only = len(layout.sum_cols)
-            parts = [acc[:a0] + adds]
+            n_allk_ = valid_v.shape[0]
+            n_cnt = 1 + n_allk_  # count rows; their carry rows close the accumulator
+            n_packed = acc.shape[0] - n_cnt
+            # counts: TwoSum. `cnt` is the rounded f32 total, `lost` exactly what
+            # that rounding left out of this add (0 below 2^24): carried, so a
+            # group's count is exact however many rows it holds
+            cnt_old, cnt_add = acc[:n_cnt], adds[:n_cnt]
+            cnt = cnt_old + cnt_add
+            seen = cnt - cnt_old
+            lost = (cnt_old - (cnt - seen)) + (cnt_add - seen)
+            parts = [cnt, acc[n_cnt:a0] + adds[n_cnt:]]
             if n_sq:
-                n_allk_ = valid_v.shape[0]
                 m2_new = [
                     _chan_merge_m2(
                         acc[1 + n_sum_only + qi],  # pac (pre-block)
@@ -3650,7 +3952,8 @@ class TpuQueryExecutor(QueryExecutor):
                 ]
                 parts.append(jnp.stack(m2_new))
             parts.append(jnp.minimum(acc[a0 + n_sq : a0 + n_sq + n_mink], mins))
-            parts.append(jnp.maximum(acc[a0 + n_sq + n_mink :], maxs))
+            parts.append(jnp.maximum(acc[a0 + n_sq + n_mink : n_packed], maxs))
+            parts.append(acc[n_packed:] + lost)
             new_acc = jnp.concatenate(parts, axis=0)
             return new_acc, tuple(dacc_new), tuple(pacc_new)
 
@@ -3751,6 +4054,13 @@ class TpuQueryExecutor(QueryExecutor):
         block's bin offset inside the scan's group window, bounded by the
         group capacity). Bounds clamp like predicate literals."""
         out: list[np.ndarray] = []
+        from parseable_tpu import DEFAULT_TIMESTAMP_KEY
+
+        on_origin = [ks.column for ks in key_specs if ks.kind == "timebin"]
+        if any(b is not None for b in bounds_ms):
+            on_origin.append(DEFAULT_TIMESTAMP_KEY)
+        for name in on_origin:
+            _require_on_origin(enc.columns.get(name))
         for ks, origin_bin in zip(key_specs, origins):
             if ks.kind != "timebin":
                 continue
@@ -3779,6 +4089,7 @@ class TpuQueryExecutor(QueryExecutor):
         col = enc.columns.get(ks.column)
         if col is None:
             raise UnsupportedOnDevice(f"time column {ks.column} missing")
+        _require_on_origin(col)
         if col.vmin is None or col.vmax is None:
             return ks.origin_rel or 0, max(ks.capacity, 2)
         lo_bin = (enc.time_origin_ms + col.vmin) // ks.bin_ms

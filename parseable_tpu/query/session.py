@@ -537,6 +537,9 @@ class QuerySession:
             ),
             "blocks": clock.blocks,
             "readbacks": clock.readbacks,
+            # arithmetic nodes traced a row for the aggregates over
+            # expressions (after sharing): device time beside work asked for
+            "expr_nodes": clock.expr_nodes,
             # the block-local merge's counters beside the phases they
             # explain (device_routes holds them too): whether it ran on
             # the device, entries it sorted there, groups that survived

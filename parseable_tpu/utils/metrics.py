@@ -207,6 +207,24 @@ DEVICE_MERGES = _counter(
     "Block-local GROUP BY merges by where the cross-block merge ran",
     ["path"],
 )
+# aggregates whose argument is an arithmetic expression (sum(price * (1 -
+# discount))): folded inside the device program ("device") or evaluated by
+# the CPU engine ("host": a plan-time rejection, or a block it folded)
+DEVICE_EXPR_AGGREGATES = _counter(
+    "tpu_expr_aggregates",
+    "Aggregate outputs over an arithmetic expression by where the expression was evaluated",
+    ["path"],
+)
+# a column ops/device.py could not hold on the device, so every query that
+# names it takes the CPU engine for that block: time_span (a timestamp
+# column too wide for int32 in any whole unit), sub_ms, nested, other
+ENCODE_DECLINED = _counter(
+    "tpu_encode_declined",
+    "Columns the device encoder declined, by reason",
+    ["reason"],
+)
+for _reason in ("time_span", "sub_ms", "nested", "other"):
+    ENCODE_DECLINED.labels(_reason)  # a scrape reads 0, not nothing, before the first decline
 # JAX accelerator health next to the execute-time histogram: live HBM usage
 # per local device (scrape-time collection, ops/device.py) and XLA programs
 # compiled (a jit cache miss costs seconds — compile churn must be visible

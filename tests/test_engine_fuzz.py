@@ -11,6 +11,9 @@ percentiles, HAVING-on-aggregate; every trial is a THREE-way differential
 (conftest pins the mesh) — and a session-level lane fuzzes CTE / UNION /
 window shapes end-to-end.
 
+ISSUE 30: the grammar's aggregates take arithmetic expressions over the
+numeric columns too (sum(lat * 2), avg(lat + status), ...).
+
 Tolerance model per aggregate kind (alias prefix encodes it):
   a*  exact/f32 sums        rel 2e-4
   s*  stddev/var            rel 5e-3 abs 1e-3 (centered-M2 on device)
@@ -68,6 +71,11 @@ AGGS = [
     ("p", "approx_percentile_cont(lat, 0.9)"),
     ("p", "approx_percentile_cont(lat, 0.5)"),
     ("p", "approx_median(lat)"),
+    # arithmetic arguments fold inside the device program (ISSUE 30): a
+    # NULL `lat` makes the row NULL for that aggregate alone
+    ("a", "sum(lat * 2)"), ("a", "avg(lat + status)"), ("a", "sum(lat * (1 - status / 1000.0))"),
+    ("a", "max(status - lat)"), ("a", "min(-lat)"), ("a", "count(lat * status)"),
+    ("a", "sum(CAST(status AS double) / 100 - 1)"), ("s", "stddev(lat * 2 + 1)"),
 ]
 GROUPS = ["host", "path", "status", "date_bin(interval '10m', p_timestamp)",
           "date_trunc('minute', p_timestamp)"]

@@ -27,7 +27,7 @@ from parseable_tpu.query.sql import parse_sql
 from parseable_tpu.utils import metrics, telemetry
 
 PHASE_KEYS = {f"{p}_ms" for p in ET.PHASES}
-EXECUTE_KEYS = PHASE_KEYS | {"head_ms", "tail_ms", "blocks", "readbacks", "merge_device", "merge_entries", "merge_survivors"}
+EXECUTE_KEYS = PHASE_KEYS | {"head_ms", "tail_ms", "blocks", "readbacks", "expr_nodes", "merge_device", "merge_entries", "merge_survivors"}
 
 
 @pytest.fixture(autouse=True)

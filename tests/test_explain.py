@@ -133,6 +133,9 @@ def test_explain_analyze_surfaces_device_routes(loaded):
         "programs_built", "programs_reused", "recompiles",
         # the block-local merge (ISSUE 28): where it ran, entries, survivors
         "merge_device", "merge_host", "merge_entries", "merge_survivors",
+        # aggregates over expressions (ISSUE 30): where they were evaluated;
+        # blocks whose encoding was declined for a column
+        "expr_aggs_device", "expr_aggs_host", "encode_declined",
     }
     assert int(routes["recompiles"]) == 0
     total_blocks = sum(

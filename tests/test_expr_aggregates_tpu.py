@@ -1,0 +1,483 @@
+"""Aggregates over arithmetic expressions and year-wide timestamp columns on
+the device (ISSUE 30): TPC-H Q1 and Q6 as the served path gets them.
+
+Every case runs the TPU engine on the CPU backend against the CPU engine and
+against a numpy f64 evaluation, and reads the route counters: the device did
+the work (`cpu_fallback` 0, `expr_aggs_device` the expressions), or the CPU
+engine did and that is counted with its reason.
+
+Tolerance for float sums and averages, in the benchmark's own form
+(`benchmark/refcore.py` `f32_ulps`): the gap relative to max(1, |reference|)
+in units of 2^-24 x sqrt(rows in the group), which is what f32 accumulation
+over that many rows may cost. `ULPS` = 4 of those units: each input is
+rounded to f32 once (half a unit a row, averaging out over the group), an
+expression adds one rounding a node, the fold one a partial sum. The same
+expression over inputs held at bfloat16 reads in the hundreds and fails it
+(`test_bfloat16_inputs_fail_the_tolerance`).
+"""
+
+from __future__ import annotations
+
+from datetime import UTC, datetime
+
+import numpy as np
+import pyarrow as pa
+import pytest
+
+from parseable_tpu import DEFAULT_TIMESTAMP_KEY
+from parseable_tpu.query import executor_tpu as ET
+from parseable_tpu.query.executor import QueryExecutor
+from parseable_tpu.query.planner import plan as build_plan
+from parseable_tpu.query.sql import parse_sql
+
+ULPS = 4.0
+DAY = 86_400_000
+INGEST_MS = 1_714_521_600_000  # 2024-05-01: the minute the block was ingested in
+N = 4000
+
+
+def ms(text: str) -> int:
+    return int(datetime.fromisoformat(text).replace(tzinfo=UTC).timestamp() * 1000)
+
+
+def lineitem(seed: int = 7, n: int = N, nulls: bool = False) -> tuple[pa.Table, dict]:
+    """A block shaped as TPC-H LINEITEM lands: float64 numerics, a ship date of
+    1992-1998 at midnight as timestamp[ms], flags as strings, p_timestamp in 2024."""
+    rng = np.random.default_rng(seed)
+    cols = {
+        "qty": rng.integers(1, 51, n).astype(np.float64),
+        "price": rng.integers(90_000, 10_000_000, n) / 100.0,
+        "disc": rng.integers(0, 11, n) / 100.0,
+        "tax": rng.integers(0, 9, n) / 100.0,
+        "flag": rng.integers(0, 3, n),
+        "status": rng.integers(0, 2, n),
+        "ship": ms("1992-01-02") + rng.integers(0, 2500, n) * DAY,
+        "ts": INGEST_MS + np.sort(rng.integers(0, 60_000, n)),
+    }
+    valid = {k: np.ones(n, bool) for k in cols}
+    if nulls:
+        for k in ("qty", "price", "disc", "tax"):
+            valid[k] = rng.random(n) > 0.15
+    def num(k):
+        return pa.array(cols[k], mask=~valid[k])
+    table = pa.table({
+        DEFAULT_TIMESTAMP_KEY: pa.array(cols["ts"], pa.timestamp("ms")),
+        "l_shipdate": pa.array(cols["ship"], pa.timestamp("ms")),
+        "l_quantity": num("qty"), "l_extendedprice": num("price"), "l_discount": num("disc"), "l_tax": num("tax"),
+        "l_returnflag": pa.array(np.array(["A", "N", "R"])[cols["flag"]]),
+        "l_linestatus": pa.array(np.array(["F", "O"])[cols["status"]]),
+        "l_comment": pa.array([f"c{i % 97}" for i in range(n)]),
+    })
+    cols["valid"] = valid
+    return table, cols
+
+
+def run(sql: str, tables: list, mesh: bool = True) -> tuple[list, list, ET.TpuQueryExecutor]:
+    cpu = QueryExecutor(build_plan(parse_sql(sql))).execute(iter(tables)).to_pylist()
+    ex = ET.TpuQueryExecutor(build_plan(parse_sql(sql)))
+    if not mesh:
+        ex.mesh = None
+    return cpu, ex.execute(iter(tables)).to_pylist(), ex
+
+
+def close(got, want, rows: int) -> bool:
+    """Within ULPS of f32's own noise over `rows` rows (module docstring)."""
+    if want is None or got is None:
+        return got is None and want is None
+    if isinstance(want, float) and not np.isfinite(want):
+        return bool(got == want or (np.isnan(want) and np.isnan(got)))
+    return abs(got - want) / max(1.0, abs(want)) <= ULPS * 2.0**-24 * max(1.0, rows) ** 0.5
+
+
+def assert_rows(got: list, want: list, keys: tuple, rows_of=lambda r: N) -> None:
+    k = lambda r: tuple(str(r[c]) for c in keys)  # noqa: E731
+    got, want = sorted(got, key=k), sorted(want, key=k)
+    assert [k(r) for r in got] == [k(r) for r in want]
+    for g, w in zip(got, want):
+        for name, value in w.items():
+            if isinstance(value, float) or value is None:
+                assert close(g[name], value, rows_of(w)), (name, g, w)
+            else:
+                assert g[name] == value, (name, g, w)
+
+
+def device_only(ex, exprs: int) -> None:
+    rs = ex.route_stats
+    assert rs["cpu_fallback"] == 0 and rs["cpu_adaptive"] == 0 and rs["encode_declined"] == 0, dict(rs)
+    assert rs["expr_aggs_device"] == exprs and rs["expr_aggs_host"] == 0, dict(rs)
+
+
+# --------------------------------------------------------------- expressions
+
+Q1 = ("SELECT l_returnflag, l_linestatus, sum(l_quantity) AS sum_qty, sum(l_extendedprice) AS sum_base_price, "
+      "sum(l_extendedprice * (1 - l_discount)) AS sum_disc_price, sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) AS sum_charge, "
+      "avg(l_quantity) AS avg_qty, avg(l_extendedprice) AS avg_price, avg(l_discount) AS avg_disc, count(*) AS count_order "
+      "FROM t WHERE l_shipdate <= '1998-09-02T00:00:00Z' GROUP BY l_returnflag, l_linestatus ORDER BY l_returnflag, l_linestatus")
+Q6 = ("SELECT sum(l_extendedprice * l_discount) AS revenue FROM t WHERE l_shipdate >= '1994-01-01T00:00:00Z' AND "
+      "l_shipdate < '1995-01-01T00:00:00Z' AND l_discount BETWEEN 0.05 AND 0.07 AND l_quantity < 24")
+
+
+@pytest.mark.parametrize("mesh", [True, False], ids=["mesh8", "one_device"])
+def test_tpch_q1_and_q6_fold_on_the_device(mesh):
+    table, c = lineitem()
+    cpu, tpu, ex = run(Q1, [table], mesh)
+    device_only(ex, 2)
+    assert ex.route_stats.expr_nodes == 4  # 1 - d, price * that (shared by both), 1 + t, their product
+    keep = c["ship"] <= ms("1998-09-02")
+    want = []
+    for f, fl in enumerate("ANR"):
+        for s, st in enumerate("FO"):
+            m = keep & (c["flag"] == f) & (c["status"] == s)
+            disc_price = c["price"][m] * (1 - c["disc"][m])
+            want.append({"l_returnflag": fl, "l_linestatus": st, "sum_qty": c["qty"][m].sum(), "sum_base_price": c["price"][m].sum(),
+                         "sum_disc_price": disc_price.sum(), "sum_charge": (disc_price * (1 + c["tax"][m])).sum(),
+                         "avg_qty": c["qty"][m].mean(), "avg_price": c["price"][m].mean(), "avg_disc": c["disc"][m].mean(),
+                         "count_order": int(m.sum())})
+    assert [(r["l_returnflag"], r["l_linestatus"]) for r in tpu] == [(r["l_returnflag"], r["l_linestatus"]) for r in want]  # ORDER BY
+    rows_of = lambda r: r["count_order"]  # noqa: E731
+    assert_rows(tpu, want, ("l_returnflag", "l_linestatus"), rows_of)
+    assert_rows(tpu, cpu, ("l_returnflag", "l_linestatus"), rows_of)
+
+    cpu, tpu, ex = run(Q6, [table], mesh)
+    device_only(ex, 1)
+    m = ((c["ship"] >= ms("1994-01-01")) & (c["ship"] < ms("1995-01-01")) & (c["disc"] >= 0.05) & (c["disc"] <= 0.07) & (c["qty"] < 24))
+    assert m.sum() > 20
+    assert close(tpu[0]["revenue"], (c["price"][m] * c["disc"][m]).sum(), int(m.sum()))
+    assert close(tpu[0]["revenue"], cpu[0]["revenue"], int(m.sum()))
+
+
+def test_bfloat16_inputs_fail_the_tolerance():
+    """What the tolerance is tight enough for: Q6's revenue over inputs held at bfloat16 is not `close`."""
+    from benchmark.refcore import round_to
+
+    _, c = lineitem()
+    m = ((c["ship"] >= ms("1994-01-01")) & (c["ship"] < ms("1995-01-01")) & (c["disc"] >= 0.05) & (c["disc"] <= 0.07) & (c["qty"] < 24))
+    want = (c["price"][m] * c["disc"][m]).sum()
+    low = (round_to(c["price"][m], "bfloat16") * round_to(c["disc"][m], "bfloat16")).sum()
+    f32 = (round_to(c["price"][m], "float32") * round_to(c["disc"][m], "float32")).sum()
+    assert close(f32, want, int(m.sum())) and not close(low, want, int(m.sum()))
+    assert abs(low - want) / want > 25 * ULPS * 2.0**-24 * m.sum() ** 0.5
+
+
+OPERATORS = {
+    "add": ("l_extendedprice + l_tax", lambda c: c["price"] + c["tax"]),
+    "subtract": ("l_extendedprice - l_quantity", lambda c: c["price"] - c["qty"]),
+    "multiply": ("l_quantity * l_discount", lambda c: c["qty"] * c["disc"]),
+    "divide_by_a_constant": ("l_extendedprice / 1048576", lambda c: c["price"] / 1048576),
+    "divide_by_a_constant_tree": ("l_extendedprice / (4 * 0.25 + 1)", lambda c: c["price"] / 2.0),
+    "unary_minus": ("-l_extendedprice", lambda c: -c["price"]),
+    "minus_of_a_tree": ("-(l_quantity - l_extendedprice)", lambda c: -(c["qty"] - c["price"])),
+    "parentheses": ("(l_quantity + l_tax) * (l_discount - 1)", lambda c: (c["qty"] + c["tax"]) * (c["disc"] - 1)),
+    "literal_on_the_left": ("100 - l_quantity * 2", lambda c: 100 - c["qty"] * 2),
+    "cast_to_double": ("CAST(l_quantity AS double) * 0.5", lambda c: c["qty"] * 0.5),
+    "cast_to_int_truncates": ("CAST(l_extendedprice / 7.0 AS int)", lambda c: np.trunc(c["price"] / 7.0)),
+}
+
+
+@pytest.mark.parametrize("name", list(OPERATORS))
+def test_each_operator(name):
+    text, fn = OPERATORS[name]
+    table, c = lineitem(seed=11)
+    sql = f"SELECT l_returnflag, sum({text}) AS s, avg({text}) AS a, min({text}) AS lo, max({text}) AS hi, count(*) AS n FROM t GROUP BY l_returnflag"
+    cpu, tpu, ex = run(sql, [table], mesh=False)
+    device_only(ex, 4)
+    want = []
+    for f, fl in enumerate("ANR"):
+        v = fn(c)[c["flag"] == f]
+        want.append({"l_returnflag": fl, "s": v.sum(), "a": v.mean(), "lo": v.min(), "hi": v.max(), "n": len(v)})
+    # min and max are single f32 values: one rounding, whatever the group's size
+    loose = lambda r: r["n"]  # noqa: E731
+    assert_rows(tpu, want, ("l_returnflag",), loose)
+    assert_rows(tpu, cpu, ("l_returnflag",), loose)
+
+
+def test_null_operands_in_each_position_and_count_avg_stddev_of_an_expression():
+    table, c = lineitem(seed=3, nulls=True)
+    v = c["valid"]
+    sql = ("SELECT l_linestatus, sum(l_extendedprice * (1 - l_discount) * (1 + l_tax)) AS s, avg(l_extendedprice * l_discount) AS a, "
+           "count(l_quantity + l_tax) AS n, count(*) AS rows, stddev(l_quantity * 2 + l_tax) AS sd, var(l_quantity - l_discount) AS vr "
+           "FROM t GROUP BY l_linestatus")
+    cpu, tpu, ex = run(sql, [table])
+    device_only(ex, 5)
+    want = []
+    for s, st in enumerate("FO"):
+        g = c["status"] == s
+        m3 = g & v["price"] & v["disc"] & v["tax"]
+        m2 = g & v["price"] & v["disc"]
+        mq = g & v["qty"] & v["tax"]
+        md = g & v["qty"] & v["disc"]
+        assert 0 < m3.sum() < m2.sum() < g.sum()  # a NULL in any operand takes the row out of that aggregate alone
+        want.append({"l_linestatus": st, "s": (c["price"][m3] * (1 - c["disc"][m3]) * (1 + c["tax"][m3])).sum(),
+                     "a": (c["price"][m2] * c["disc"][m2]).mean(), "n": int(mq.sum()), "rows": int(g.sum()),
+                     "sd": float(np.std(c["qty"][mq] * 2 + c["tax"][mq], ddof=1)), "vr": float(np.var(c["qty"][md] - c["disc"][md], ddof=1))})
+    got = {r["l_linestatus"]: r for r in tpu}
+    for w in want:
+        g = got[w["l_linestatus"]]
+        assert g["n"] == w["n"] and g["rows"] == w["rows"]
+        assert close(g["s"], w["s"], w["rows"]) and close(g["a"], w["a"], w["rows"])
+        assert g["sd"] == pytest.approx(w["sd"], rel=5e-3) and g["vr"] == pytest.approx(w["vr"], rel=5e-3)  # the fuzz's stddev tolerance
+    by = {r["l_linestatus"]: r for r in cpu}
+    assert all(close(got[k]["s"], by[k]["s"], by[k]["rows"]) and got[k]["n"] == by[k]["n"] for k in by)
+
+
+def test_an_all_null_group_is_null_as_on_the_cpu_engine():
+    table, _ = lineitem(seed=5, n=64)
+    table = table.set_column(table.column_names.index("l_discount"), "l_discount", pa.array([None] * 64, pa.float64()))
+    cpu, tpu, ex = run("SELECT sum(l_extendedprice * l_discount) AS s, avg(l_extendedprice * l_discount) AS a, count(l_extendedprice * l_discount) AS n FROM t", [table])
+    device_only(ex, 3)
+    assert tpu == cpu == [{"s": None, "a": None, "n": 0}]
+
+
+@pytest.mark.parametrize("mesh", [True, False], ids=["mesh8", "one_device"])
+def test_a_block_local_group_by_folds_an_expression(monkeypatch, mesh):
+    """Past DENSE_G_MAX the fold is block by block (`jit_executor_local`): the same tracer makes its value rows."""
+    monkeypatch.setattr(ET, "DENSE_G_MAX", 64)
+    table, c = lineitem(seed=9)
+    sql = "SELECT l_comment, l_returnflag, sum(l_extendedprice * (1 - l_discount)) AS s, count(*) AS n FROM t GROUP BY l_comment, l_returnflag"
+    tables = [table.slice(0, 2500).replace_schema_metadata({ET.SOURCE_ID_META: b"blk-0"}),
+              table.slice(2500).replace_schema_metadata({ET.SOURCE_ID_META: b"blk-1"})]
+    cpu, tpu, ex = run(sql, tables, mesh)
+    device_only(ex, 1)
+    assert ex.route_stats["merge_host"] == 1 and len(tpu) == len(cpu) == 97 * 3
+    assert_rows(tpu, cpu, ("l_comment", "l_returnflag"), lambda r: r["n"])
+    s = c["price"] * (1 - c["disc"])
+    first = next(r for r in sorted(tpu, key=lambda r: (r["l_comment"], r["l_returnflag"])))
+    m = (np.arange(N) % 97 == int(first["l_comment"][1:])) & (c["flag"] == "ANR".index(first["l_returnflag"]))
+    assert close(first["s"], s[m].sum(), int(m.sum()))
+
+
+def test_the_expression_is_part_of_the_programs_key_and_a_warm_query_builds_nothing():
+    table, _ = lineitem(seed=13)
+    one = "SELECT l_returnflag, sum(l_extendedprice * (1 - l_discount)) AS s FROM t GROUP BY l_returnflag"
+    two = "SELECT l_returnflag, sum(l_extendedprice * (1 + l_discount)) AS s FROM t GROUP BY l_returnflag"
+    _, a, ex = run(one, [table], mesh=False)
+    built = ex.route_stats["programs_built"]
+    _, again, ex2 = run(one, [table], mesh=False)
+    assert built >= 1 and ex2.route_stats["programs_built"] == 0 and ex2.route_stats["recompiles"] == 0 and again == a
+    _, b, ex3 = run(two, [table], mesh=False)
+    assert ex3.route_stats["programs_built"] >= 1 and ex3.route_stats["recompiles"] == 0
+    assert all(x["s"] < y["s"] for x, y in zip(sorted(a, key=str), sorted(b, key=str)))
+
+
+UNSUPPORTED = {
+    "a_function_call": ("count(upper(l_comment))", "upper"),
+    "a_string_operand": ("sum(l_returnflag * 2)", None),
+    "a_timestamp_operand": ("max(l_shipdate - p_timestamp)", None),
+    "a_column_divisor": ("sum(l_extendedprice / l_quantity)", "division"),
+    "a_zero_divisor": ("sum(l_extendedprice / 0.0)", "division"),
+    "a_case": ("sum(CASE WHEN l_quantity > 3 THEN l_tax ELSE 0 END)", None),
+}
+
+
+@pytest.mark.parametrize("name", list(UNSUPPORTED))
+def test_what_the_device_does_not_run_is_declared_counted_and_named(name):
+    """A plan-time rejection hands every table to the CPU engine: `cpu_fallback` ticks once a table and the
+    reason is kept; a rejection that only a block's column kinds show folds that block on the CPU, counted too."""
+    text, said = UNSUPPORTED[name]
+    table, _ = lineitem(seed=17, n=500)
+    sql = f"SELECT l_linestatus, {text} AS x, count(*) AS n FROM t GROUP BY l_linestatus"
+    tables = [table, table.slice(0, 100), table.slice(100, 50)]
+    try:
+        cpu = QueryExecutor(build_plan(parse_sql(sql))).execute(iter(tables)).to_pylist()
+    except Exception:  # the CPU engine refuses it too (a string times two): both engines raise
+        with pytest.raises(Exception):
+            ET.TpuQueryExecutor(build_plan(parse_sql(sql))).execute(iter(tables))
+        return
+    ex = ET.TpuQueryExecutor(build_plan(parse_sql(sql)))
+    tpu = ex.execute(iter(tables)).to_pylist()
+    rs = ex.route_stats
+    assert rs["cpu_fallback"] >= 1 and rs["expr_aggs_device"] == 0 and rs["expr_aggs_host"] >= 1, dict(rs)
+    if "cpu_fallback_reason" in rs:  # plan time: every table, and why
+        assert rs["cpu_fallback"] == len(tables) and rs["device_cold"] == 0
+        assert said is None or said in rs["cpu_fallback_reason"]
+    key = lambda r: str(r["l_linestatus"])  # noqa: E731
+    for g, w in zip(sorted(tpu, key=key), sorted(cpu, key=key)):
+        assert g["n"] == w["n"] and (g["x"] == w["x"] or g["x"] == pytest.approx(w["x"], rel=1e-9) or (np.isinf(w["x"]) and np.isinf(g["x"])))
+
+
+def test_an_integer_division_stays_on_the_cpu_engine_block_by_block():
+    """int / int truncates on the CPU engine: only a block's column types show it, so the block folds there."""
+    table = pa.table({"g": pa.array(["a", "b"] * 50), "bytes": pa.array(np.arange(100) * 1000), "f": pa.array(np.arange(100) * 1.0)})
+    sql = "SELECT g, sum(bytes / 1024) AS kb, sum(f / 1024) AS fkb, sum(bytes / 1024.0) AS kbf FROM t GROUP BY g"
+    cpu, tpu, ex = run(sql, [table])
+    assert ex.route_stats["cpu_fallback"] == 1 and ex.route_stats["expr_aggs_host"] == 3 and ex.route_stats["expr_aggs_device"] == 0
+    assert tpu == cpu and cpu[0]["kb"] != cpu[0]["kbf"]
+    cpu, tpu, ex = run("SELECT g, sum(bytes / 1024.0) AS kbf, sum(bytes * 2 + 1) AS twice FROM t GROUP BY g", [table])
+    device_only(ex, 2)
+    assert_rows(tpu, cpu, ("g",), lambda r: 50)
+
+
+def test_a_planted_plan_time_rejection_reaches_the_benchmarks_guard(tmp_path):
+    """`judge()` sums `cpu_fallback` into `cpu_routed_blocks` (limit 0): with the fallback counted, a query that the
+    CPU engine answered whole can no longer read `correct` with the chip idle."""
+    from benchmark import run as harness
+
+    table, _ = lineitem(seed=19, n=300)
+    ex = ET.TpuQueryExecutor(build_plan(parse_sql("SELECT l_linestatus, count(upper(l_comment)) AS x FROM t GROUP BY l_linestatus")))
+    ex.execute(iter([table, table]))
+    cell = harness.load_cell("flog_lowcard.dash")
+    cell["cfg"]["minutes"], cell["cfg"]["rows_per_minute"] = 2, 500
+    harness.role_reference(cell["cfg"], cell["mix"], 1, tmp_path, None)
+    doc = {"records": [], "stats": {"device_routes": dict(ex.route_stats), "stages": {}}}
+    import json
+
+    response = {"query": "like_by_status", "lookback": 2, "status": 200, "raw": json.dumps(doc).encode()}
+    compared = harness.judge(cell, [response], tmp_path)["compared"]
+    assert compared["cpu_routed_blocks"] == (2, 0)
+
+
+# ----------------------------------------------------------------- timestamps
+
+SHIP_LO, SHIP_HI = ms("1992-01-02"), ms("1992-01-02") + 2499 * DAY
+LITERALS = {
+    "on_the_grid": "1995-06-17T00:00:00Z",
+    "off_the_grid_by_a_second": "1995-06-17T00:00:01Z",
+    "off_the_grid_by_a_millisecond": "1995-06-16T23:59:59.999Z",
+    "noon": "1996-02-29T12:00:00Z",
+    "the_day_before_the_least": "1992-01-01T00:00:00Z",
+    "the_least": "1992-01-02T00:00:00Z",
+    "just_after_the_least": "1992-01-02T00:00:00.001Z",
+    "just_before_the_largest": "1998-11-04T23:59:59.999Z",
+    "the_largest": "1998-11-05T00:00:00Z",
+    "the_day_after_the_largest": "1998-11-06T00:00:00Z",
+    "before_the_epoch": "1960-01-01T00:00:00Z",
+    "far_after": "2190-01-01T00:00:00Z",
+}
+
+
+@pytest.mark.parametrize("lit", list(LITERALS))
+def test_a_year_wide_timestamp_column_compares_exactly_under_every_operator(lit):
+    table, c = lineitem(seed=23)
+    ship = c["ship"].copy()
+    ship[:2] = SHIP_LO, SHIP_HI
+    table = table.set_column(1, "l_shipdate", pa.array(ship, pa.timestamp("ms")))
+    assert datetime.fromtimestamp(SHIP_HI / 1000, UTC).strftime("%Y-%m-%d") == "1998-11-05"
+    when = ms(LITERALS[lit].rstrip("Z"))
+    ops = {"<": ship < when, "<=": ship <= when, "=": ship == when, "!=": ship != when, ">=": ship >= when, ">": ship > when}
+    for op, mask in ops.items():
+        sql = f"SELECT count(*) AS n, sum(l_quantity) AS q FROM t WHERE l_shipdate {op} '{LITERALS[lit]}'"
+        cpu, tpu, ex = run(sql, [table], mesh=False)
+        device_only(ex, 0)
+        assert tpu[0]["n"] == cpu[0]["n"] == int(mask.sum()), (op, lit)
+        assert tpu[0]["q"] == cpu[0]["q"] == (c["qty"][mask].sum() if mask.any() else None), (op, lit)
+    other = "1994-01-01T06:00:00Z"
+    lo, hi = sorted((LITERALS[lit], other), key=lambda t: ms(t.rstrip("Z")))
+    for neg in ("", "NOT "):
+        sql = f"SELECT count(*) AS n FROM t WHERE l_shipdate {neg}BETWEEN '{lo}' AND '{hi}'"
+        cpu, tpu, ex = run(sql, [table])
+        device_only(ex, 0)
+        inside = (ship >= ms(lo.rstrip("Z"))) & (ship <= ms(hi.rstrip("Z")))
+        assert tpu[0]["n"] == cpu[0]["n"] == int((~inside if neg else inside).sum())
+
+
+def test_the_unit_is_the_coarsest_that_divides_every_value_and_the_origin_stays_with_the_blocks_clock():
+    from parseable_tpu.ops.device import encode_table
+
+    table, c = lineitem(seed=29)
+    enc = encode_table(table, None)
+    alone = encode_table(table.drop_columns(["l_shipdate"]), None)
+    ship, ts = enc.columns["l_shipdate"], enc.columns[DEFAULT_TIMESTAMP_KEY]
+    assert (ship.unit_ms, ship.origin_ms) == (DAY, c["ship"].min() // DAY * DAY) and (ts.unit_ms, ts.origin_ms) == (1, None)
+    assert enc.time_origin_ms == alone.time_origin_ms == INGEST_MS // DAY * DAY
+    assert (ts.values == alone.columns[DEFAULT_TIMESTAMP_KEY].values).all()
+    assert (ship.values[:N].astype(np.int64) * DAY + ship.origin_ms == c["ship"]).all()
+    # on the hour and on the second: the coarsest unit that still divides every value
+    for step, unit in ((3_600_000, 3_600_000), (1000, 1000), (90_000, 1000)):
+        t2 = table.set_column(1, "l_shipdate", pa.array(c["ship"] + (np.arange(N) % 7) * step, pa.timestamp("ms")))
+        assert encode_table(t2, None).columns["l_shipdate"].unit_ms == unit
+    # a column near the block's clock is encoded as it always was, and moves the origin as it always did
+    near = table.set_column(1, "l_shipdate", pa.array(c["ts"] - 3 * DAY, pa.timestamp("ms")))
+    e3 = encode_table(near, None)
+    assert e3.time_origin_ms == (INGEST_MS - 3 * DAY) // DAY * DAY and e3.columns["l_shipdate"].origin_ms is None
+
+
+def declined(reason: str) -> float:
+    from parseable_tpu.utils.metrics import ENCODE_DECLINED
+
+    return ENCODE_DECLINED.labels(reason)._value.get()
+
+
+def test_a_column_no_unit_holds_is_declined_and_counted_and_costs_only_the_queries_that_name_it():
+    table, c = lineitem(seed=31)
+    odd = table.set_column(1, "l_shipdate", pa.array(c["ship"] + np.arange(N) % 997, pa.timestamp("ms")))  # ms residue over 6.8 years
+    before = declined("time_span")
+    cpu, tpu, ex = run("SELECT count(*) AS n, sum(l_quantity) AS q FROM t WHERE l_shipdate <= '1995-06-17T00:00:00.500Z'", [odd])
+    assert tpu == cpu and cpu[0]["n"] > 0
+    assert ex.route_stats["cpu_fallback"] == 1 and ex.route_stats["encode_declined"] == 1 and declined("time_span") == before + 1
+    # the same table under a query that does not name the column: the device, and nothing declined
+    cpu, tpu, ex = run("SELECT l_returnflag, sum(l_quantity) AS q FROM t GROUP BY l_returnflag", [odd])
+    device_only(ex, 0)
+    assert declined("time_span") == before + 1
+    us = pa.table({"t_us": pa.array(np.arange(50) * 1000 + 7, pa.timestamp("us")), "v": pa.array(np.ones(50))})
+    before = declined("sub_ms")
+    _, _, ex = run("SELECT count(*) AS n FROM t WHERE t_us > '1970-01-01T00:00:00Z'", [us])
+    assert ex.route_stats["encode_declined"] == 1 and declined("sub_ms") == before + 1
+    nested = pa.table({"g": pa.array(["a", "b"]), "tags": pa.array([[1], [2, 3]])})
+    before = declined("nested")
+    _, _, ex = run("SELECT g, count(tags) AS n FROM t GROUP BY g", [nested])
+    assert ex.route_stats["encode_declined"] == 1 and declined("nested") == before + 1
+
+
+def test_a_table_that_merely_holds_a_wide_column_runs_the_program_it_ran_without_it(monkeypatch):
+    """The probe of ISSUE 30: `GROUP BY l_returnflag` read `cpu_fallback` 1 with `l_shipdate` merely present."""
+    table, _ = lineitem(seed=37)
+    sql = "SELECT l_returnflag, sum(l_quantity) AS q, count(*) AS n FROM t WHERE p_timestamp >= '2024-05-01T00:00:10Z' GROUP BY l_returnflag"
+    keys = []
+    real = ET._note_program_build
+    monkeypatch.setattr(ET, "_note_program_build", lambda program, key, stats=None: (keys.append(key), real(program, key, stats))[1])
+    monkeypatch.setattr(ET, "_PROGRAM_CACHE", {})
+    # the executor handed the whole table (needed columns None would encode every column): the plan names what it needs
+    _, with_col, ex = run(sql, [table], mesh=False)
+    device_only(ex, 0)
+    monkeypatch.setattr(ET, "_PROGRAM_CACHE", {})
+    _, without, ex2 = run(sql, [table.drop_columns(["l_shipdate"])], mesh=False)
+    device_only(ex2, 0)
+    assert with_col == without and len(keys) == 2 and keys[0] == keys[1]
+
+
+def test_date_bin_over_a_column_off_the_origin_is_declared():
+    table, _ = lineitem(seed=41, n=200)
+    sql = "SELECT date_bin(interval '1 day', l_shipdate) AS d, count(*) AS n FROM t GROUP BY d"
+    cpu, tpu, ex = run(sql, [table])
+    assert ex.route_stats["cpu_fallback"] == 1 and sorted(map(str, tpu)) == sorted(map(str, cpu))
+
+
+def test_the_encoding_survives_the_encoded_block_cache(tmp_path):
+    from parseable_tpu.ops.device import encode_table
+    from parseable_tpu.ops.enccache import EncodedBlockCache
+
+    table, _ = lineitem(seed=43)
+    ints = table.append_column("n_int", pa.array(np.arange(N)))
+    enc = encode_table(ints, {"l_shipdate", DEFAULT_TIMESTAMP_KEY, "n_int", "l_quantity"})
+    cache = EncodedBlockCache(tmp_path)
+    assert cache.put(b"src", enc)
+    back = cache.get(b"src", {"l_shipdate", DEFAULT_TIMESTAMP_KEY, "n_int", "l_quantity"}, set())
+    for name in ("l_shipdate", DEFAULT_TIMESTAMP_KEY, "n_int", "l_quantity"):
+        a, b = enc.columns[name], back.columns[name]
+        assert (a.unit_ms, a.origin_ms, a.integral, a.vmin, a.vmax) == (b.unit_ms, b.origin_ms, b.integral, b.vmin, b.vmax)
+        assert (a.values == b.values).all()
+    assert back.columns["n_int"].integral and not back.columns["l_quantity"].integral and back.columns["l_shipdate"].unit_ms == DAY
+
+
+# ------------------------------------------------------------- exact counts
+
+
+@pytest.mark.parametrize("mesh", [True, False], ids=["mesh8", "one_device"])
+def test_a_groups_count_is_exact_past_two_to_the_24(mesh):
+    """What `tpch_lineitem.q1q6` met on the chip: Q1's N/O group holds 29,158,055 rows of 60 blocks, the dense path adds
+    the blocks' counts into an f32 accumulator on the device, and f32 holds even numbers only past 2^24: the count came
+    back one too many. The count rows carry what each add's rounding leaves out (`AccLayout.n_counts`)."""
+    rows, blocks = (1 << 20) - 1, 17  # 17,825,775 rows in one group: odd, and past 2^24
+    v = np.ones(rows)
+    v[::64] = np.nan  # count(v) of a column with NULLs: another total past 2^24
+    t = pa.table({"g": pa.array(["a"] * rows), "v": pa.array(v, from_pandas=True)})
+    tables = [t.replace_schema_metadata({ET.SOURCE_ID_META: f"count-{mesh}-{i}".encode()}) for i in range(blocks)]
+    ex = ET.TpuQueryExecutor(build_plan(parse_sql("SELECT g, count(*) AS n, count(v) AS nv, sum(v) AS s FROM t GROUP BY g")))
+    if not mesh:
+        ex.mesh = None
+    out = ex.execute(iter(tables)).to_pylist()
+    live = int((~np.isnan(v)).sum())
+    assert ex.route_stats["cpu_fallback"] == 0 and ex.route_stats["device_cold"] == blocks
+    assert out[0]["n"] == rows * blocks and rows * blocks % 2 == 1 and rows * blocks > 1 << 24
+    assert out[0]["nv"] == live * blocks > 1 << 24
+    assert out[0]["s"] == pytest.approx(live * blocks, rel=1e-6)

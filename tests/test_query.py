@@ -252,12 +252,17 @@ def test_tpu_nulls_in_group_and_agg():
 
 
 def test_tpu_fallback_unsupported():
-    # aggregate over an arithmetic expression falls back to CPU transparently
+    # an aggregate over a function call is no device program: the CPU engine
+    # answers the whole query, every table counted and the reason kept (an
+    # arithmetic argument, this test's old query, now folds on the device:
+    # tests/test_expr_aggregates_tpu.py)
     t = make_table()
-    sql = "SELECT host, sum(latency * 2) s FROM t GROUP BY host"
-    cpu = run_cpu(sql, [t]).to_pylist()
-    tpu = run_tpu(sql, [t]).to_pylist()
-    assert sorted(map(tuple_sorted, cpu)) == sorted(map(tuple_sorted, tpu))
+    sql = "SELECT host, count(upper(msg)) s FROM t GROUP BY host"
+    cpu = run_cpu(sql, [t, t.slice(0, 10)]).to_pylist()
+    ex = TpuQueryExecutor(build_plan(parse_sql(sql)))
+    tpu = ex.execute(iter([t, t.slice(0, 10)])).to_pylist()
+    assert cpu and sorted(map(tuple_sorted, cpu)) == sorted(map(tuple_sorted, tpu))
+    assert ex.route_stats["cpu_fallback"] == 2 and "upper" in ex.route_stats["cpu_fallback_reason"]
 
 
 # ------------------------------------------------------------- full session
