@@ -78,6 +78,7 @@ and take the CPU path instead, counted.
 from __future__ import annotations
 
 import logging
+import math
 import re
 import time as _time
 from dataclasses import dataclass, field as dc_field
@@ -213,6 +214,55 @@ def _pow2(n: int, minimum: int = 8) -> int:
     while p < n:
         p <<= 1
     return p
+
+
+# The dense block loop's small operands (predicate LUTs, time scalars, key
+# and distinct remaps) cross to the device packed: one flat host buffer per
+# dtype for a whole dispatch group, one transfer each, sliced apart again
+# inside the program at offsets that follow from the operands' signature.
+
+
+def _operand_sig(arrays) -> tuple:
+    """(dtype, shape) of each operand: all the packed layout depends on."""
+    return tuple((a.dtype.str, a.shape) for a in arrays)
+
+
+def _pack_operands(blocks: list[tuple]) -> tuple[np.ndarray, ...]:
+    """The operands of a group's blocks (a tuple of operand tuples a block),
+    flattened block by block in their own order into one buffer per dtype,
+    the buffers in sorted dtype order."""
+    by: dict[str, list[np.ndarray]] = {}
+    for ops in blocks:
+        for part in ops:
+            for a in part:
+                by.setdefault(a.dtype.str, []).append(a.reshape(-1))
+    return tuple(np.concatenate(by[d]) for d in sorted(by))
+
+
+def _operand_dtypes(sig: tuple) -> list[str]:
+    """The packed buffers' dtypes, in their order, for one block's `sig`
+    (its `_operand_sig` a part)."""
+    return sorted({d for part in sig for d, _ in part})
+
+
+def _unpack_operands(packed: tuple, sig: tuple, n_blocks: int) -> list[tuple]:
+    """`_pack_operands` undone inside a trace: every block's operand tuples
+    as static slices of the packed buffers, values, dtypes and shapes as
+    they were on the host."""
+    bufs = dict(zip(_operand_dtypes(sig), packed))
+    offs = dict.fromkeys(bufs, 0)
+    blocks = []
+    for _ in range(n_blocks):
+        parts = []
+        for part in sig:
+            arrs = []
+            for d, shape in part:
+                n = math.prod(shape)
+                arrs.append(bufs[d][offs[d] : offs[d] + n].reshape(shape))
+                offs[d] += n
+            parts.append(tuple(arrs))
+        blocks.append(tuple(parts))
+    return blocks
 
 
 # ------------------------------------------------------------- global dicts
@@ -1308,6 +1358,10 @@ class RouteStats(dict):
             cpu_fallback=0,  # declared UnsupportedOnDevice (incl. budgets)
             h2d_bytes=0,
             d2h_bytes=0,
+            # host-to-device transfers of small operands (LUTs, time
+            # scalars, remaps) the dense block loop made: one a dtype a
+            # dispatch group
+            operand_puts=0,
             # program-cache traffic (stages.programs reads these): builds
             # this query, cache hits this query, rebuilds of a key that
             # was already built once (0 in steady state)
@@ -2269,14 +2323,30 @@ class TpuQueryExecutor(QueryExecutor):
             if buf:
                 yield _concat_tables(buf)
 
-        # Blocks with identical shape signatures batch into one dispatch of
-        # up to GROUP_N unrolled folds, to amortize per-dispatch latency.
-        # Eight was chosen for a link this code no longer runs on; the
-        # trade against compile time is not measured on a directly
-        # attached chip.
+        # Blocks with identical shape signatures batch into one dispatch
+        # group of up to GROUP_N: one program call of that many unrolled
+        # folds, and one transfer a dtype for all their small operands
+        # (`_pack_operands`), where a put costs the host a quarter of a
+        # millisecond whatever it carries (0.57 ms replicated over four
+        # chips). The trade of eight against compile time and against the
+        # first program's start is not measured.
         GROUP_N = 8
-        pending: list[tuple] = []  # (table, enc, dev, dev_luts, dev_remaps, row_mask)
+        pending: list[tuple] = []  # (table, enc, dev, operands, row_mask)
         pending_sig: tuple | None = None
+        if self.mesh is not None:
+            import jax
+
+            rep_s = _mesh_shardings(self.mesh)[1]
+
+            def put_rep(a: np.ndarray):
+                # priced: these ride outside _transfer's packed payload, so
+                # the link accounting must see them here (no latency sample:
+                # the puts are async and a probe would serialize the loop)
+                rs["h2d_bytes"] += a.nbytes
+                DEVICE_BYTES_TO_DEVICE.labels("lut").inc(a.nbytes)
+                return jax.device_put(a, rep_s)
+        else:
+            put_rep = jnp.asarray
 
         def fold_pending_on_cpu() -> None:
             """The plan layout was declared UnsupportedOnDevice at program
@@ -2315,22 +2385,23 @@ class TpuQueryExecutor(QueryExecutor):
                     layout,
                     acc_groups,
                     pending_sig[1],
-                    pending_sig[2],
                     n_blocks=len(pending),
                     dev_keys=tuple(sorted(pending[0][2].keys())),
-                    dremap_shapes=pending_sig[3],
                 )
+                # only a group that has its program ships its operands
+                rs.enter("prepare")
+                packed = tuple(put_rep(b) for b in _pack_operands([x[3] for x in pending]))
+                rs.enter("dispatch")
                 acc, dacc_out, pacc_out = program(
                     acc,
                     tuple(dacc),
                     tuple(pacc),
                     tuple(x[2] for x in pending),
-                    tuple(x[3] for x in pending),
+                    packed,
                     tuple(x[4] for x in pending),
-                    tuple(x[5] for x in pending),
-                    tuple(x[6] for x in pending),
                 )
                 rs.dispatched(prev)
+                rs["operand_puts"] += len(packed)  # of a group that went
                 dacc = list(dacc_out)
                 pacc = list(pacc_out)
                 pending.clear()
@@ -2589,36 +2660,22 @@ class TpuQueryExecutor(QueryExecutor):
                         self._bounds_ms(),
                     )
                     kinds = tuple(sorted((n, c.kind) for n, c in enc.columns.items()))
+                    # the small operands stay on the host until their group
+                    # goes: dispatch_pending packs and ships them together
+                    operands = (
+                        tuple(luts),
+                        tuple(r for r in remaps if r is not None),
+                        tuple(dremaps_np),
+                    )
                     sig = (
                         (enc.block_rows, kinds, "__rowmask" in dev),
-                        tuple(l.shape for l in luts),
-                        tuple(r.shape if r is not None else None for r in remaps),
-                        tuple(r.shape for r in dremaps_np),
+                        tuple(_operand_sig(part) for part in operands),
                     )
                     if pending and sig != pending_sig:
                         dispatch_pending()
                     pending_sig = sig
-                    if self.mesh is not None:
-                        import jax
-
-                        _, rep_s = _mesh_shardings(self.mesh)
-
-                        def put_rep(a, _s=rep_s, _jax=jax):
-                            # priced: LUT/remap ships ride outside _transfer's
-                            # packed payload, so the link accounting must see
-                            # them here (no latency sample — the puts are async
-                            # and a probe would serialize the batch loop)
-                            n = int(getattr(a, "nbytes", 0))
-                            self.route_stats["h2d_bytes"] += n
-                            DEVICE_BYTES_TO_DEVICE.labels("lut").inc(n)
-                            return _jax.device_put(a, _s)
-                    else:
-                        put_rep = jnp.asarray
-                    dev_luts = tuple(put_rep(l) for l in luts)
-                    dev_remaps = tuple(put_rep(r) for r in remaps if r is not None)
-                    dev_dremaps = tuple(put_rep(r) for r in dremaps_np)
                     row_mask = dev.get("__rowmask", dev["__ones"])
-                    pending.append((table, enc, dev, dev_luts, dev_remaps, dev_dremaps, row_mask))
+                    pending.append((table, enc, dev, operands, row_mask))
                     if len(pending) >= GROUP_N:
                         dispatch_pending()
                 except UnsupportedOnDevice as e:
@@ -3702,14 +3759,17 @@ class TpuQueryExecutor(QueryExecutor):
         enc: EncodedBatch,
         layout: PlanLayout,
         num_groups: int,
-        lut_shapes: tuple,
-        remap_shapes: tuple,
+        operand_sig: tuple,
         n_blocks: int = 1,
         dev_keys: tuple = (),
-        dremap_shapes: tuple = (),
     ) -> Callable:
         """One jitted dispatch: WHERE mask + dict remap + group ids + fused
         aggregate + fold into the device accumulator.
+
+        `operand_sig` is one block's `_operand_sig` of its predicate LUTs
+        (with the time scalars), its key remaps and its distinct remaps: the
+        program takes all `n_blocks` blocks' operands as `_pack_operands`
+        left them and slices them apart at the offsets that follow from it.
 
         With a mesh active, the whole fold runs under `shard_map`: each
         device computes the fused partial aggregate for its row shard and
@@ -3747,8 +3807,7 @@ class TpuQueryExecutor(QueryExecutor):
             layout.caps,
             # origins deliberately NOT in the key: bin offsets ship as
             # runtime scalars, so origin epoch changes reuse the program
-            lut_shapes,
-            remap_shapes,
+            operand_sig,
             num_groups,
             n_blocks,
             None if mesh is None else id(mesh),
@@ -3756,7 +3815,6 @@ class TpuQueryExecutor(QueryExecutor):
             tuple(layout.distinct_cols),
             layout.distinct_caps,
             layout.distinct_sketch,
-            dremap_shapes,
             shard_groups,
             tuple(layout.sq_cols),
             tuple(layout.pct_cols),
@@ -3962,25 +4020,20 @@ class TpuQueryExecutor(QueryExecutor):
             dacc: tuple,
             pacc: tuple,
             devs: tuple,
-            luts_all: tuple,
-            remaps_all: tuple,
-            dremaps_all: tuple,
+            packed: tuple,
             row_masks: tuple,
         ):
             # unrolled folds: N blocks per dispatch amortize round-trip
             # latency; XLA sees one big program and schedules it as a unit
+            operands = _unpack_operands(packed, operand_sig, n_blocks)
             for i in range(n_blocks):
-                acc, dacc, pacc = fold_one(
-                    acc, dacc, pacc, devs[i], luts_all[i], remaps_all[i], dremaps_all[i], row_masks[i]
-                )
+                acc, dacc, pacc = fold_one(acc, dacc, pacc, devs[i], *operands[i], row_masks[i])
             return acc, dacc, pacc
 
         if mesh is not None:
             from jax import shard_map
             from jax.sharding import PartitionSpec as P
 
-            n_remaps = sum(1 for s in remap_shapes if s is not None)
-            n_dremaps = len(dremap_shapes)
             dev_spec = {k: P("data") for k in dev_keys}
             # accumulator: replicated on 1D meshes; its G axis shards over
             # `groups` on the 2D layout (each device owns G/shard buckets)
@@ -3991,9 +4044,7 @@ class TpuQueryExecutor(QueryExecutor):
                 tuple(dacc_spec for _ in layout.distinct_caps),  # presence bitmaps
                 tuple(dacc_spec for _ in layout.pct_cols),  # pct histograms
                 tuple(dev_spec for _ in range(n_blocks)),
-                tuple(tuple(P() for _ in lut_shapes) for _ in range(n_blocks)),
-                tuple(tuple(P() for _ in range(n_remaps)) for _ in range(n_blocks)),
-                tuple(tuple(P() for _ in range(n_dremaps)) for _ in range(n_blocks)),
+                tuple(P() for _ in _operand_dtypes(operand_sig)),  # the packed small operands
                 tuple(P("data") for _ in range(n_blocks)),
             )
             out_specs = (

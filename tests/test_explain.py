@@ -136,6 +136,8 @@ def test_explain_analyze_surfaces_device_routes(loaded):
         # aggregates over expressions (ISSUE 30): where they were evaluated;
         # blocks whose encoding was declined for a column
         "expr_aggs_device", "expr_aggs_host", "encode_declined",
+        # transfers of small operands the dense block loop made (ISSUE 31)
+        "operand_puts",
     }
     assert int(routes["recompiles"]) == 0
     total_blocks = sum(
