@@ -1,34 +1,10 @@
-"""Benchmarks: the BASELINE.md configs, TPU engine vs CPU baseline.
-
-Runs through the full stack (staging -> parquet -> catalog -> manifest-
-pruned scan -> engine) over one synthesized flog/OTel-style stream:
-
-- config 2: time-bucketed GROUP BY (p_timestamp, status) aggregation;
-- config 3: LIKE substring filter on the message column (the dictionary-
-  LUT predicate path's showcase);
-- config 4 (north star): top-K + multi-column GROUP BY, reported COLD
-  (first scan: parquet read + encode + transfer overlapped via the
-  prefetcher) and WARM (device hot set resident);
-- config 5: the distributed psum-tree path, validated on a virtual
-  8-device CPU mesh in a subprocess (this process holds the chip, and a
-  chip belongs to one process).
-
-Needs an accelerator: without one main() exits non-zero and emits nothing.
-
-Prints one JSON line per config; the LAST line is the headline north-star
-metric the driver records. Env knobs: BENCH_ROWS (default 32_000_000),
-BENCH_REPEATS (default 3).
+"""`build_dataset`, the access-log generator that `BENCHMARK.json` and three `benchmark/configs/` files cite as
+their `source`, and `CONFIGS`, the three BASELINE SQL texts that `tests/benchmark_suite/test_reference.py` parses
+and `chip_smoke.py` imports: nothing else. The benchmark is `benchmark/run.py`; importing this pulls in no JAX.
 """
 
 from __future__ import annotations
 
-import json
-import os
-import shutil
-import subprocess
-import sys
-import tempfile
-import time
 from datetime import UTC, datetime, timedelta
 
 import numpy as np
@@ -180,1987 +156,3 @@ CONFIGS = {
         "GROUP BY path, host ORDER BY s DESC LIMIT 10"
     ),
 }
-
-
-def run_query(p, stream: str, engine: str, sql: str) -> tuple[float, int, list, dict]:
-    from parseable_tpu.query.session import QuerySession
-
-    sess = QuerySession(p, engine=engine)
-    t0 = time.perf_counter()
-    res = sess.query(sql.format(stream=stream))
-    dt = time.perf_counter() - t0
-    rows = sorted(
-        (tuple(r.values()) for r in res.to_json_rows()),
-        key=lambda t: tuple(str(v) for v in t),
-    )
-    return dt, res.stats["rows_scanned"], rows, res.stats
-
-
-def percentile(times: list[float], q: float) -> float:
-    """Nearest-rank percentile over the measured repeats."""
-    if not times:
-        return 0.0
-    xs = sorted(times)
-    return xs[min(len(xs) - 1, int(round(q * (len(xs) - 1))))]
-
-
-def rows_match(a: list, b: list) -> bool:
-    """Exact on keys/counts; 1e-4 relative on floats (device sums are f32
-    per block; BENCH parity tolerance matches the test suite's)."""
-    if len(a) != len(b):
-        return False
-    for ra, rb in zip(a, b):
-        if len(ra) != len(rb):
-            return False
-        for va, vb in zip(ra, rb):
-            if isinstance(va, float) and isinstance(vb, float):
-                if abs(va - vb) > 1e-4 * max(1.0, abs(va)):
-                    return False
-            elif va != vb:
-                return False
-    return True
-
-
-def timed_runs(p, stream, engine, sql, repeats) -> dict:
-    """Run `repeats` times and report latency PERCENTILES, not a single
-    shot or best-of (VERDICT missing #5: p50/p95 per config — a best-of
-    hides tail variance the latency north star is supposed to capture)."""
-    times: list[float] = []
-    rows_scanned, result, stats = 0, [], {}
-    for _ in range(max(1, repeats)):
-        dt, scanned, rows, st = run_query(p, stream, engine, sql)
-        times.append(dt)
-        rows_scanned = max(rows_scanned, scanned)
-        result, stats = rows, st
-    return {
-        "times": times,
-        "p50": percentile(times, 0.50),
-        "p95": percentile(times, 0.95),
-        "best": min(times),
-        "rows_scanned": rows_scanned,
-        "rows": result,
-        "stats": stats,
-    }
-
-
-def clear_hot_state() -> None:
-    """Force the next TPU run cold: drop device-resident blocks."""
-    from parseable_tpu.ops.hotset import get_hotset
-
-    get_hotset().clear()
-
-
-def emit(name: str, tpu_rps: float, speedup: float, extra: dict | None = None) -> None:
-    line = {
-        "metric": name,
-        "value": round(tpu_rps, 1),
-        "unit": "rows/s",
-        "vs_baseline": round(speedup, 3),
-    }
-    if extra:
-        line.update(extra)
-    print(json.dumps(line), flush=True)
-    # every emission also lands in the machine-readable artifact
-    # (BENCH_JSON_OUT, one JSON object per line, appended) so the perf
-    # trajectory — gb_per_sec, rows_per_sec_per_core, latency percentiles —
-    # is diffable across rounds without scraping stdout
-    out = os.environ.get("BENCH_JSON_OUT", "/tmp/bench.json")
-    if out:
-        try:
-            with open(out, "a", encoding="utf-8") as f:
-                f.write(json.dumps(line) + "\n")
-        except OSError:
-            pass
-
-
-def bench_distributed_subprocess(total_rows: int) -> None:
-    """Config 5: the shard_map psum path on a virtual 8-device CPU mesh.
-
-    Runs in a subprocess because this process's JAX holds the chip; the
-    child is pinned to the CPU backend (it must never reach for the chip
-    its parent owns). The virtual mesh validates the distributed path
-    end-to-end and reports its (CPU-device) throughput for the record.
-
-    Measurement protocol (VERDICT r4 #9 — the raw number swung 3x across
-    rounds purely with host size/load): the emission is load-qualified.
-    It always carries `cpus` (the affinity-mask size the 8 virtual
-    devices actually share) and `rows_per_sec_per_cpu` (the cross-round
-    comparable figure), and is marked `degraded: true` when load1/cpus
-    exceeds 0.25 at the start of the run — a degraded number is recorded
-    for continuity but must not be read as a regression."""
-    script = r"""
-import os, time, json
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS","") + " --xla_force_host_platform_device_count=8").strip()
-import jax
-jax.config.update("jax_platforms", "cpu")
-import numpy as np, pyarrow as pa
-from datetime import datetime, timedelta
-from parseable_tpu import DEFAULT_TIMESTAMP_KEY
-from parseable_tpu.query.sql import parse_sql
-from parseable_tpu.query.planner import plan as build_plan
-from parseable_tpu.query import executor_tpu as ET
-
-n = %d
-rng = np.random.default_rng(0)
-base = datetime(2024, 5, 1)
-ts = [base + timedelta(seconds=int(i)) for i in rng.integers(0, 3600, n)]
-t = pa.table({
-    DEFAULT_TIMESTAMP_KEY: pa.array(ts, pa.timestamp("ms")),
-    "status": pa.array(rng.choice(["200","404","500"], n).tolist()),
-    "bytes": pa.array(rng.random(n) * 1000),
-})
-sql = "SELECT status, count(*) c, sum(bytes) s FROM t GROUP BY status"
-lp = build_plan(parse_sql(sql))
-ex = ET.TpuQueryExecutor(lp)
-assert ex.mesh is not None and ex.mesh.size == 8
-ex.execute(iter([t]))  # warm/compile
-# best-of-3: the r02->r03 "34%% regression" (6.9M->4.5M rows/s) was pure
-# end-of-round machine load — r02/r03/r04 code measured back-to-back on
-# an idle box all sit at ~11-13M rows/s (bisected round 4); a single
-# timed run is hostage to whatever the driver is doing
-best = 0.0
-for _ in range(3):
-    t0 = time.perf_counter()
-    out = ex.execute(iter([t]))
-    dt = time.perf_counter() - t0
-    best = max(best, n / dt)
-assert ET.MESH_PROGRAMS_BUILT > 0, "mesh program missing"
-assert sum(r["c"] for r in out.to_pylist()) == n
-load1 = os.getloadavg()[0]
-cpus = len(os.sched_getaffinity(0)) if hasattr(os, "sched_getaffinity") else (os.cpu_count() or 1)
-print(json.dumps({"ok": True, "rows_per_sec": best, "devices": 8, "load1": load1, "cpus": cpus}))
-""" % min(total_rows, 2_000_000)
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    try:
-        out = subprocess.run(
-            [sys.executable, "-c", script],
-            capture_output=True,
-            text=True,
-            timeout=600,
-            env=env,
-            cwd=os.path.dirname(os.path.abspath(__file__)),
-        )
-        last = out.stdout.strip().splitlines()[-1] if out.stdout.strip() else "{}"
-        data = json.loads(last)
-        print(
-            f"# distributed (virtual 8-dev mesh): ok={data.get('ok')} "
-            f"{data.get('rows_per_sec', 0):,.0f} rows/s",
-            file=sys.stderr,
-        )
-        rps = float(data.get("rows_per_sec", 0.0))
-        cpus = int(data.get("cpus") or 1)
-        load1 = float(data.get("load1") or 0.0)
-        emit(
-            "distributed_mesh_groupby_rows_per_sec",
-            rps,
-            1.0,
-            {
-                "devices": 8,
-                "note": "virtual CPU mesh validation (1 real chip on host)",
-                "best_of": 3,
-                "host_load1": load1,
-                "cpus": cpus,
-                "rows_per_sec_per_cpu": round(rps / cpus, 1),
-                "degraded": load1 / cpus > 0.25,
-            },
-        )
-    except Exception as e:
-        print(f"# distributed bench failed: {e}", file=sys.stderr)
-        if "out" in dir():
-            print(out.stderr[-2000:], file=sys.stderr)
-
-
-def bench_config1(p) -> None:
-    """BASELINE config 1: `SELECT count(*) FROM demo WHERE host='...'` over
-    the demo-data stream (reference: resources/ingest_demo_data.sh feeding
-    handlers/http/query.rs:221-271's counts path).
-
-    Ingests the packaged demo workload through the real JSON event path
-    (server/extras.py generate_demo_events — the in-process port of the
-    reference's demo script), then emits one line per engine for the
-    filtered count, plus the manifest-count fast path for the unfiltered
-    count validated against a full scan."""
-    from parseable_tpu.event.json_format import JsonEvent
-    from parseable_tpu.server.extras import generate_demo_events
-
-    n = int(os.environ.get("BENCH_DEMO_ROWS", "1000000"))
-    chunk = 50_000
-    stream = p.create_stream_if_not_exists("demodata")
-    t0 = time.perf_counter()
-    done = 0
-    while done < n:
-        k = min(chunk, n - done)
-        ev = JsonEvent(generate_demo_events(k, seed=done), "demodata").into_event(stream.metadata)
-        ev.process(stream, commit_schema=p.commit_schema)
-        done += k
-    p.local_sync(shutdown=True)
-    p.sync_all_streams()
-    print(f"# demo stream: {n} rows ingested in {time.perf_counter()-t0:.1f}s", file=sys.stderr)
-
-    filtered = "SELECT count(*) AS c FROM demodata WHERE host='192.168.1.7'"
-    repeats = int(os.environ.get("BENCH_REPEATS", "3"))
-    for engine in ("cpu", "tpu"):
-        r = timed_runs(p, "demodata", engine, filtered, repeats)
-        p50, scanned, rows = r["p50"], r["rows_scanned"], r["rows"]
-        print(
-            f"# config1 [{engine}]: count(*) WHERE host=... -> {rows[0][0]} in "
-            f"p50 {p50:.3f}s p95 {r['p95']:.3f}s ({scanned/p50:,.0f} rows/s scanned)",
-            file=sys.stderr,
-        )
-        emit(
-            f"config1_filtered_count_rows_per_sec_{engine}",
-            scanned / p50,
-            1.0,
-            {
-                "latency_p50_s": round(p50, 4),
-                "latency_p95_s": round(r["p95"], 4),
-                "repeats": repeats,
-                "matched": rows[0][0],
-            },
-        )
-
-    # unfiltered count: manifest fast path vs a forced full scan (the
-    # predicate defeats count_star_only without changing the answer)
-    from parseable_tpu.query.session import QuerySession
-
-    sess = QuerySession(p, engine="cpu")
-    t0 = time.perf_counter()
-    res_fast = sess.query("SELECT count(*) AS c FROM demodata")
-    fast_t = time.perf_counter() - t0
-    res_full = sess.query("SELECT count(*) AS c FROM demodata WHERE bytes >= 0")
-    fast_count = res_fast.to_json_rows()[0]["c"]
-    full_count = res_full.to_json_rows()[0]["c"]
-    ok = res_fast.stats.get("fast_path") == "manifest_count" and fast_count == full_count
-    if not ok:
-        print(
-            f"# WARNING config1 fast path mismatch: fast={fast_count} "
-            f"({res_fast.stats.get('fast_path')}) full={full_count}",
-            file=sys.stderr,
-        )
-    emit(
-        "config1_manifest_count_latency_ms",
-        fast_t * 1000,
-        1.0,
-        {
-            "unit": "ms",
-            "validated_vs_full_scan": ok,
-            "count": fast_count,
-            "note": "count(*) off manifest row counts, no scan",
-        },
-    )
-
-
-def bench_scale_inprocess() -> None:
-    """Config 4 at 100GB-logical scale over the persistent .benchwork
-    dataset (scripts/bench_scale.py; VERDICT r4 #2). Runs only when the
-    dataset has been built (scripts/build_benchwork.py). IN-PROCESS: this
-    process holds the chip, and a chip belongs to one process, so a child
-    could never initialize it. BENCH_SCALE=0 skips."""
-    here = os.path.dirname(os.path.abspath(__file__))
-    if os.environ.get("BENCH_SCALE", "1") == "0":
-        return
-    if not os.path.exists(os.path.join(here, ".benchwork", "meta.json")):
-        print("# scale bench: no .benchwork dataset (scripts/build_benchwork.py)", file=sys.stderr)
-        return
-    try:
-        sys.path.insert(0, os.path.join(here, "scripts"))
-        import bench_scale
-
-        bench_scale.main()
-    except Exception as e:  # noqa: BLE001
-        print(f"# scale bench failed: {e}", file=sys.stderr)
-
-
-def bench_json_ingest(p) -> None:
-    """End-to-end HTTP JSON ingest line with an honest absolute yardstick
-    (VERDICT r3 #7): vs_baseline is measured against the raw pyarrow C++
-    JSON-reader floor over the SAME payload bytes — the fastest any
-    Python-hosted server could conceivably decode it with a reader, with
-    zero event model, schema commit, or staging. The native columnar lane
-    (fastpath.cpp single-pass parse -> Arrow-layout buffers -> zero-copy
-    import) runs the whole pipeline and can legitimately EXCEED 1.0x: it
-    parses the bytes once into final columns while read_json tokenizes
-    into its own intermediate representation first."""
-    import io as _io
-
-    import numpy as np
-    import pyarrow.json as pj
-
-    from parseable_tpu.event.format import LogSource
-    from parseable_tpu.server.ingest_utils import flatten_and_push_logs
-
-    rng = np.random.default_rng(7)
-    n = 100_000
-    chunk = 10_000
-    rows = [
-        {
-            "host": f"h{i % 50}",
-            "status": int(rng.integers(200, 600)),
-            "method": "GET",
-            "path": f"/api/v{i % 5}/items",
-            "latency_ms": float(rng.random() * 500),
-            "meta": {"region": f"r{i % 4}", "zone": f"z{i % 3}"},
-        }
-        for i in range(n)
-    ]
-    bodies = [
-        json.dumps(rows[o : o + chunk]).encode() for o in range(0, n, chunk)
-    ]
-    # the floor parses the same records as NDJSON (read_json's wire
-    # format; feeding it the HTTP array body would error)
-    floor_bodies = [
-        ("\n".join(json.dumps(r) for r in rows[o : o + chunk]) + "\n").encode()
-        for o in range(0, n, chunk)
-    ]
-    p.create_stream_if_not_exists("ingbench")
-    # warm both paths (library load, stream schema commit, reader import)
-    flatten_and_push_logs(p, "ingbench", None, LogSource.JSON, {}, raw_body=bodies[0])
-    pj.read_json(_io.BytesIO(floor_bodies[0]))
-
-    # p50/p95 over reps for BOTH lines — the repo's bench policy (PR 2)
-    # bans best-of: a best-of hides the tail variance the latency north
-    # star exists to capture, and it biased this line's vs_baseline
-    reps = max(3, int(os.environ.get("BENCH_REPEATS", "3")))
-    cores = os.cpu_count() or 1
-    shards_n = min(cores, 4)
-    payload_gb = sum(len(b) for b in bodies) / 1e9
-
-    def run_ours(shards: int, telem: bool = True) -> list[float]:
-        # pin the shard count (and drop the byte threshold so every chunk
-        # actually shards) for the duration of the measured loop; telem=False
-        # A/Bs the native telemetry plane off (read per-call via telem_sync)
-        os.environ["P_INGEST_PARSE_SHARDS"] = str(shards)
-        os.environ["P_INGEST_SHARD_MIN_BYTES"] = "0"
-        if not telem:
-            os.environ["P_NATIVE_TELEM"] = "0"
-        try:
-            times: list[float] = []
-            for _ in range(reps):
-                t0 = time.perf_counter()
-                for b in bodies:
-                    flatten_and_push_logs(
-                        p, "ingbench", None, LogSource.JSON, {}, raw_body=b
-                    )
-                times.append(time.perf_counter() - t0)
-            return times
-        finally:
-            os.environ.pop("P_INGEST_PARSE_SHARDS", None)
-            os.environ.pop("P_INGEST_SHARD_MIN_BYTES", None)
-            os.environ.pop("P_NATIVE_TELEM", None)
-
-    def stage_sums() -> dict[str, float]:
-        # cumulative ingest_stage_seconds sums per stage (lanes folded in),
-        # read through the public collect() API — deltas around a measured
-        # run give the per-stage waterfall attribution for that run
-        from parseable_tpu.utils.metrics import INGEST_STAGE_TIME
-
-        out: dict[str, float] = {}
-        for metric in INGEST_STAGE_TIME.collect():
-            for s in metric.samples:
-                if s.name.endswith("_sum"):
-                    stage = s.labels["stage"]
-                    out[stage] = out.get(stage, 0.0) + s.value
-        return out
-
-    pre = stage_sums()
-    shard1_times = run_ours(1)
-    mid = stage_sums()
-    ours_times = run_ours(shards_n) if shards_n > 1 else shard1_times
-    post = stage_sums()
-    # attribute stages to the headline run (which is the shard1 run itself
-    # on a 1-core box, where no second measured loop happens)
-    lo, hi = (mid, post) if shards_n > 1 else (pre, mid)
-    stage_ms = {
-        k: (hi.get(k, 0.0) - lo.get(k, 0.0)) * 1e3 / reps
-        for k in sorted(set(lo) | set(hi))
-    }
-    teloff_times = run_ours(shards_n, telem=False)
-    ours = n / percentile(ours_times, 0.50)
-    shard1 = n / percentile(shard1_times, 0.50)
-    teloff = n / percentile(teloff_times, 0.50)
-    # telemetry cost = slowdown of the telemetry-ON run vs OFF (<1 means
-    # noise put the ON run ahead; the gate only cares about the upper side)
-    telem_overhead_pct = (teloff / ours - 1.0) * 100.0
-
-    floor_times: list[float] = []
-    for _ in range(reps):
-        t0 = time.perf_counter()
-        for b in floor_bodies:
-            pj.read_json(_io.BytesIO(b))
-        floor_times.append(time.perf_counter() - t0)
-    floor = n / percentile(floor_times, 0.50)
-    gb_per_sec = payload_gb / percentile(ours_times, 0.50)
-    print(
-        f"# json ingest: {ours:,.0f} rows/s end-to-end (p50; p95 "
-        f"{n / percentile(ours_times, 0.95):,.0f}) | pyarrow floor {floor:,.0f} rows/s | "
-        f"{ours / floor:.2f}x of floor | {gb_per_sec:.3f} GB/s",
-        file=sys.stderr,
-    )
-    print(
-        f"# json ingest sharding: shards=1 {shard1:,.0f} rows/s vs "
-        f"shards={shards_n} {ours:,.0f} rows/s ({ours / shard1:.2f}x on a "
-        f"{cores}-core box; {ours / shards_n:,.0f} rows/s/core)",
-        file=sys.stderr,
-    )
-    breakdown = " | ".join(f"{k} {v:.1f}ms" for k, v in stage_ms.items() if v > 0)
-    print(
-        f"# json ingest stages (per rep, {n:,} rows): {breakdown or 'n/a'} | "
-        f"telemetry off {teloff:,.0f} rows/s (on-cost "
-        f"{telem_overhead_pct:+.1f}%)",
-        file=sys.stderr,
-    )
-    emit(
-        "http_json_ingest_rows_per_sec",
-        round(ours, 1),
-        round(ours / floor, 4),
-        {
-            "note": (
-                "full pipeline (sharded single-pass C++ columnar build -> "
-                "ordered stitch -> zero-copy Arrow import -> schema/staging "
-                "with direct-to-IPC; NDJSON+read_json as the fallback tier) "
-                "vs raw pyarrow read_json floor on the same bytes; p50 over "
-                "reps, never best-of"
-            ),
-            "repeats": reps,
-            "latency_p50_s": round(percentile(ours_times, 0.50), 4),
-            "latency_p95_s": round(percentile(ours_times, 0.95), 4),
-            "pyarrow_floor_rows_per_sec": round(floor, 1),
-            "pyarrow_floor_p50_s": round(percentile(floor_times, 0.50), 4),
-            "pyarrow_floor_p95_s": round(percentile(floor_times, 0.95), 4),
-            "gb_per_sec": round(gb_per_sec, 4),
-            "rows_per_sec_per_core": round(ours / shards_n, 1),
-            "cores": cores,
-            "parse_shards": shards_n,
-            "shards1_rows_per_sec": round(shard1, 1),
-            "shard_scaling_x": round(ours / shard1, 4),
-            "stage_ms_per_rep": {k: round(v, 2) for k, v in stage_ms.items()},
-            "telem_off_rows_per_sec": round(teloff, 1),
-            "telem_overhead_pct": round(telem_overhead_pct, 2),
-        },
-    )
-
-
-def bench_edge() -> None:
-    """Native HTTP ingest edge (fastpath.cpp acceptor, PR "zero-Python
-    happy path") vs the aiohttp tier of the SAME server process, measured
-    wrk-style over loopback: persistent keep-alive connections, a fixed
-    offered load (rows/s; 0 = saturate), identical payload bytes on both
-    ports. Reports GB/s, rows/s-per-core and p50/p95/p99 ack latency next
-    to the in-process bench_json_ingest lines. vs_baseline = edge rows/s /
-    aiohttp rows/s (the PR's acceptance bar is >= 1.5x). Passes interleave
-    edge/aiohttp (A/B/A/B...) inside one server boot and the reported rate
-    is the p50 across passes — host-load drift on a shared box would
-    otherwise swing the ratio by +/-0.2x. Env knobs: BENCH_EDGE (0 skips),
-    BENCH_EDGE_CONNS (4; 1 on a single-core host, where the co-located
-    client's extra threads only time-slice the server's CPU and the run
-    measures scheduler fairness instead of the server), BENCH_EDGE_REQS
-    (300 per tier per pass), BENCH_EDGE_BATCH (200 rows per request),
-    BENCH_EDGE_OFFERED_ROWS (0 = unthrottled), BENCH_REPEATS (3 passes
-    per tier)."""
-    import pathlib
-    import socket as socketmod
-    import threading
-
-    if os.environ.get("BENCH_EDGE", "1") == "0":
-        return
-    here = os.path.dirname(os.path.abspath(__file__))
-    scripts_dir = os.path.join(here, "scripts")
-    if scripts_dir not in sys.path:
-        sys.path.insert(0, scripts_dir)
-    from blackbox import AUTH_HEADER, ClusterHarness, free_port
-
-    default_conns = 1 if (os.cpu_count() or 1) == 1 else 4
-    conns = int(os.environ.get("BENCH_EDGE_CONNS", str(default_conns)))
-    n_reqs = int(os.environ.get("BENCH_EDGE_REQS", "300"))
-    batch = int(os.environ.get("BENCH_EDGE_BATCH", "200"))
-    offered = float(os.environ.get("BENCH_EDGE_OFFERED_ROWS", "0"))
-    cores = os.cpu_count() or 1
-
-    rng = np.random.default_rng(17)
-    rows = [
-        {
-            "host": f"h{i % 50}",
-            "status": int(rng.integers(200, 600)),
-            "method": "GET",
-            "path": f"/api/v{i % 5}/items",
-            "latency_ms": float(rng.random() * 500),
-            "meta": {"region": f"r{i % 4}", "zone": f"z{i % 3}"},
-        }
-        for i in range(batch * 8)
-    ]
-    # a small pool of distinct bodies reused round-robin — prebuilt so the
-    # measured loop never json.dumps under the GIL the server also needs
-    bodies = [
-        json.dumps(rows[o : o + batch]).encode()
-        for o in range(0, len(rows), batch)
-    ]
-    bytes_per_req = sum(len(b) for b in bodies) / len(bodies)
-
-    def build_reqs(port: int, stream: str) -> list[bytes]:
-        out = []
-        for b in bodies:
-            head = (
-                f"POST /api/v1/ingest HTTP/1.1\r\n"
-                f"Host: 127.0.0.1:{port}\r\n"
-                f"Authorization: {AUTH_HEADER['Authorization']}\r\n"
-                f"Content-Type: application/json\r\n"
-                f"X-P-Stream: {stream}\r\n"
-                f"Content-Length: {len(b)}\r\n\r\n"
-            ).encode()
-            out.append(head + b)
-        return out
-
-    def read_ack(sock, buf: bytes) -> tuple[int, bytes]:
-        # both tiers answer this route Content-Length-framed
-        while b"\r\n\r\n" not in buf:
-            chunk = sock.recv(65536)
-            if not chunk:
-                raise RuntimeError("connection closed mid-response")
-            buf += chunk
-        head, _, rest = buf.partition(b"\r\n\r\n")
-        status = int(head.split(None, 2)[1])
-        cl = 0
-        for line in head.split(b"\r\n")[1:]:
-            k, _, v = line.partition(b":")
-            if k.strip().lower() == b"content-length":
-                cl = int(v.strip())
-        while len(rest) < cl:
-            chunk = sock.recv(65536)
-            if not chunk:
-                raise RuntimeError("connection closed mid-body")
-            rest += chunk
-        return status, rest[cl:]
-
-    def drive(port: int, reqs: list[bytes]) -> dict:
-        """One measured pass: `conns` persistent connections, requests
-        paced on a single global open-loop schedule (behind-schedule sends
-        go immediately, so overload shows up in the ack latencies)."""
-        interval = (batch / offered) if offered > 0 else 0.0
-        results: list[dict] = [dict() for _ in range(conns)]
-        barrier = threading.Barrier(conns + 1)
-
-        def sender(slot: int) -> None:
-            sock = socketmod.create_connection(("127.0.0.1", port), timeout=60)
-            sock.setsockopt(socketmod.IPPROTO_TCP, socketmod.TCP_NODELAY, 1)
-            lats: list[float] = []
-            acked = sent_bytes = 0
-            buf = b""
-            try:
-                barrier.wait()
-                t_base = t_start[0]
-                first = last = None
-                for i in range(slot, n_reqs, conns):
-                    if interval:
-                        tgt = t_base + i * interval
-                        now = time.perf_counter()
-                        if now < tgt:
-                            time.sleep(tgt - now)
-                    t0 = time.perf_counter()
-                    req = reqs[i % len(reqs)]
-                    sock.sendall(req)
-                    status, buf = read_ack(sock, buf)
-                    t1 = time.perf_counter()
-                    if status != 200:
-                        raise RuntimeError(f"ack status {status}")
-                    lats.append(t1 - t0)
-                    acked += batch
-                    sent_bytes += len(req)
-                    first = t0 if first is None else first
-                    last = t1
-                results[slot] = {
-                    "lats": lats,
-                    "acked": acked,
-                    "bytes": sent_bytes,
-                    "first": first,
-                    "last": last,
-                }
-            finally:
-                sock.close()
-
-        threads = [
-            threading.Thread(target=sender, args=(s,), daemon=True)
-            for s in range(conns)
-        ]
-        t_start = [0.0]
-        for t in threads:
-            t.start()
-        t_start[0] = time.perf_counter() + 0.05  # common schedule origin
-        barrier.wait()
-        for t in threads:
-            t.join(600)
-        done = [r for r in results if r.get("acked")]
-        if not done:
-            raise RuntimeError("no sender completed")
-        wall = max(r["last"] for r in done) - min(r["first"] for r in done)
-        acked = sum(r["acked"] for r in done)
-        return {
-            "rows_per_sec": acked / wall,
-            "gb_per_sec": sum(r["bytes"] for r in done) / wall / 1e9,
-            "lats_ms": [x * 1e3 for r in done for x in r["lats"]],
-            "acked_rows": acked,
-            "wall_s": wall,
-        }
-
-    workdir = tempfile.mkdtemp(prefix="ptpu-edgebench-")
-    try:
-        edge_port = free_port()
-        with ClusterHarness(pathlib.Path(workdir)) as cluster:
-            node = cluster.spawn(
-                "all",
-                "edgebench",
-                env_extra={
-                    "P_EDGE_PORT": str(edge_port),
-                    # keep the sync loop out of the measured window; the
-                    # ~120k rows staged here sit comfortably in the arena
-                    "P_LOCAL_SYNC_INTERVAL": "3600",
-                },
-            )
-            cluster.wait_live(node)
-            try:
-                probe = socketmod.create_connection(("127.0.0.1", edge_port), 5)
-                probe.close()
-            except OSError:
-                print(
-                    "# edge bench skipped: native edge acceptor not listening "
-                    "(library without ptpu_edge_* or start failure)",
-                    file=sys.stderr,
-                )
-                return
-
-            tiers = {
-                "edge": (edge_port, build_reqs(edge_port, "ebench")),
-                "aiohttp": (node.port, build_reqs(node.port, "ebench")),
-            }
-            # warm both tiers on the SAME stream first (stream creation +
-            # schema commit are one-time costs, not per-tier differences)
-            warm_sock = socketmod.create_connection(("127.0.0.1", edge_port), 30)
-            wbuf = b""
-            for _ in range(3):
-                warm_sock.sendall(tiers["edge"][1][0])
-                status, wbuf = read_ack(warm_sock, wbuf)
-                assert status == 200, f"edge warmup ack {status}"
-            warm_sock.close()
-            warm_sock = socketmod.create_connection(("127.0.0.1", node.port), 30)
-            wbuf = b""
-            for _ in range(3):
-                warm_sock.sendall(tiers["aiohttp"][1][0])
-                status, wbuf = read_ack(warm_sock, wbuf)
-                assert status == 200, f"aiohttp warmup ack {status}"
-            warm_sock.close()
-
-            reps = max(1, int(os.environ.get("BENCH_REPEATS", "3")))
-            passes: dict[str, list[dict]] = {name: [] for name in tiers}
-            for _ in range(reps):
-                for name, (port, reqs) in tiers.items():
-                    passes[name].append(drive(port, reqs))
-            stats = {}
-            for name, runs in passes.items():
-                lats_ms = sorted(
-                    x for r in runs for x in r["lats_ms"]
-                )
-                stats[name] = {
-                    "rows_per_sec": percentile(
-                        [r["rows_per_sec"] for r in runs], 0.50
-                    ),
-                    "gb_per_sec": percentile(
-                        [r["gb_per_sec"] for r in runs], 0.50
-                    ),
-                    "p50_ms": percentile(lats_ms, 0.50),
-                    "p95_ms": percentile(lats_ms, 0.95),
-                    "p99_ms": percentile(lats_ms, 0.99),
-                    "acked_rows": sum(r["acked_rows"] for r in runs),
-                    "wall_s": sum(r["wall_s"] for r in runs),
-                }
-
-            edge_counters = {}
-            try:
-                report = cluster.audit(node, scope="local", quiesce=False)
-                edge_counters = report.get("edge") or {}
-            except Exception as e:  # noqa: BLE001 - bench-only extra
-                print(f"# edge bench: audit probe failed: {e}", file=sys.stderr)
-
-        e, a = stats["edge"], stats["aiohttp"]
-        speedup = e["rows_per_sec"] / max(a["rows_per_sec"], 1e-9)
-        for name, s in stats.items():
-            print(
-                f"# edge bench [{name}]: {s['rows_per_sec']:,.0f} rows/s "
-                f"({s['gb_per_sec']:.3f} GB/s, {s['rows_per_sec']/cores:,.0f} "
-                f"rows/s/core) | ack p50 {s['p50_ms']:.1f}ms p95 "
-                f"{s['p95_ms']:.1f}ms p99 {s['p99_ms']:.1f}ms | "
-                f"{s['acked_rows']} rows over {conns} conns in {s['wall_s']:.2f}s",
-                file=sys.stderr,
-            )
-        print(
-            f"# edge bench: native edge {speedup:.2f}x aiohttp rows/s at equal "
-            f"payloads ({batch} rows/req, ~{bytes_per_req/1e3:.1f}KB bodies, "
-            f"{'unthrottled' if not offered else f'{offered:,.0f} rows/s offered'})",
-            file=sys.stderr,
-        )
-        emit(
-            "edge_native_ingest_rows_per_sec",
-            e["rows_per_sec"],
-            speedup,
-            {
-                "note": (
-                    "C++ epoll acceptor (socket->shard arena, zero Python "
-                    "objects on the happy path) vs the aiohttp tier of the "
-                    "same process; persistent keep-alive conns over "
-                    "loopback, identical payload bytes, open-loop schedule"
-                ),
-                "conns": conns,
-                "requests_per_tier": n_reqs,
-                "batch_rows": batch,
-                "body_bytes_avg": round(bytes_per_req, 1),
-                "offered_rows_per_sec": offered or "unthrottled",
-                "cores": cores,
-                "gb_per_sec": round(e["gb_per_sec"], 4),
-                "rows_per_sec_per_core": round(e["rows_per_sec"] / cores, 1),
-                "latency_p50_ms": round(e["p50_ms"], 2),
-                "latency_p95_ms": round(e["p95_ms"], 2),
-                "latency_p99_ms": round(e["p99_ms"], 2),
-                "aiohttp_rows_per_sec": round(a["rows_per_sec"], 1),
-                "aiohttp_gb_per_sec": round(a["gb_per_sec"], 4),
-                "aiohttp_rows_per_sec_per_core": round(a["rows_per_sec"] / cores, 1),
-                "aiohttp_latency_p50_ms": round(a["p50_ms"], 2),
-                "aiohttp_latency_p95_ms": round(a["p95_ms"], 2),
-                "aiohttp_latency_p99_ms": round(a["p99_ms"], 2),
-                "edge_counters": edge_counters,
-            },
-        )
-    except Exception as exc:  # noqa: BLE001
-        print(f"# edge bench failed: {exc}", file=sys.stderr)
-    finally:
-        shutil.rmtree(workdir, ignore_errors=True)
-
-
-def bench_ingest_pipeline() -> None:
-    """Write-path benchmark (parallel write path PR): N streams of backdated
-    minute buckets, measuring staging->queryable latency (flush -> compact ->
-    upload -> snapshot commit, per stream) and sync-path rows/s — serial
-    baseline (P_SYNC_WORKERS=1, two-phase local_sync + upload tick) vs the
-    pooled pipelined sync_cycle. Pure host work; runs with or without the
-    chip. Env knobs: BENCH_INGEST_STREAMS (6), BENCH_INGEST_ROWS (100000
-    rows per stream)."""
-    import pathlib
-
-    from parseable_tpu import DEFAULT_TIMESTAMP_KEY
-    from parseable_tpu.config import Options, StorageOptions
-    from parseable_tpu.core import Parseable
-    from parseable_tpu.event import Event
-
-    n_streams = int(os.environ.get("BENCH_INGEST_STREAMS", "8"))
-    rows_per_stream = int(os.environ.get("BENCH_INGEST_ROWS", "60000"))
-    # pooled workers: at least 4 even on small hosts — parquet encode
-    # releases the GIL and the uploads are I/O, so overlap pays regardless
-    pooled_workers = int(
-        os.environ.get("BENCH_INGEST_WORKERS", str(max(4, Options().sync_workers)))
-    )
-    # model the remote object store: each upload pays one simulated RTT so
-    # the serial-vs-pipelined difference reflects the deployment the write
-    # path actually targets (set 0 to measure raw local-fs copies)
-    upload_ms = float(os.environ.get("BENCH_INGEST_UPLOAD_MS", "25"))
-    minutes = 4
-    base = datetime(2024, 5, 1, 0, 0, tzinfo=UTC)
-
-    def run_mode(mode: str) -> dict:
-        rng = np.random.default_rng(11)
-        workdir = tempfile.mkdtemp(prefix=f"ptpu-ingbench-{mode}-")
-        opts = Options()
-        opts.local_staging_path = pathlib.Path(workdir) / "staging"
-        opts.sync_workers = 1 if mode == "serial" else pooled_workers
-        storage = StorageOptions(
-            backend="local-store", root=pathlib.Path(workdir) / "data"
-        )
-        p = Parseable(opts, storage)
-        if upload_ms > 0:
-            real_upload = p.storage.upload_file
-
-            def slow_upload(key, path):
-                time.sleep(upload_ms / 1000.0)
-                return real_upload(key, path)
-
-            p.storage.upload_file = slow_upload
-        try:
-            per_minute = max(1, rows_per_stream // minutes)
-            for si in range(n_streams):
-                name = f"ing{si}"
-                stream = p.create_stream_if_not_exists(name)
-                for minute in range(minutes):
-                    ts = [
-                        base + timedelta(minutes=minute, milliseconds=int(o))
-                        for o in np.sort(rng.integers(0, 60_000, per_minute))
-                    ]
-                    tbl = pa.table(
-                        {
-                            DEFAULT_TIMESTAMP_KEY: pa.array(
-                                [t.replace(tzinfo=None) for t in ts], pa.timestamp("ms")
-                            ),
-                            "host": pa.array([f"h{i % 32}" for i in range(per_minute)]),
-                            "status": pa.array(rng.choice([200.0, 404.0, 500.0], per_minute)),
-                            "bytes": pa.array(rng.random(per_minute) * 1000),
-                        }
-                    ).combine_chunks()
-                    for batch in tbl.to_batches():
-                        Event(
-                            stream_name=name,
-                            rb=batch,
-                            origin_size=batch.num_rows * 100,
-                            is_first_event=minute == 0,
-                            parsed_timestamp=base + timedelta(minutes=minute),
-                        ).process(stream, commit_schema=p.commit_schema)
-            # per-stream visibility instant = its snapshot commit landing
-            commit_times: dict[str, float] = {}
-            orig_update = p.update_snapshot
-
-            def timed_update(stream, entries):
-                orig_update(stream, entries)
-                commit_times[stream.name] = time.perf_counter()
-
-            p.update_snapshot = timed_update
-            t0 = time.perf_counter()
-            if mode == "serial":
-                p.local_sync(shutdown=True)
-                p.sync_all_streams()
-            else:
-                p.sync_cycle(shutdown=True)
-            total = time.perf_counter() - t0
-            p.update_snapshot = orig_update
-            lats = sorted(
-                commit_times.get(f"ing{si}", t0 + total) - t0 for si in range(n_streams)
-            )
-            p.shutdown()
-            return {
-                "total_s": total,
-                "lat_p50_s": percentile(lats, 0.50),
-                "lat_p95_s": percentile(lats, 0.95),
-                "rows_per_sec": n_streams * per_minute * minutes / total,
-            }
-        finally:
-            shutil.rmtree(workdir, ignore_errors=True)
-
-    serial = run_mode("serial")
-    pooled = run_mode("pooled")
-    speedup = serial["total_s"] / max(pooled["total_s"], 1e-9)
-    print(
-        f"# ingest sync: serial {serial['total_s']:.3f}s "
-        f"(lat p50 {serial['lat_p50_s']:.3f}s p95 {serial['lat_p95_s']:.3f}s) | "
-        f"pooled {pooled['total_s']:.3f}s "
-        f"(lat p50 {pooled['lat_p50_s']:.3f}s p95 {pooled['lat_p95_s']:.3f}s) | "
-        f"{speedup:.2f}x",
-        file=sys.stderr,
-    )
-    emit(
-        "ingest_sync_rows_per_sec",
-        pooled["rows_per_sec"],
-        speedup,
-        {
-            "streams": n_streams,
-            "rows_per_stream": rows_per_stream,
-            "sync_workers": pooled_workers,
-            "upload_rtt_ms": upload_ms,
-            "serial_total_s": round(serial["total_s"], 4),
-            "pooled_total_s": round(pooled["total_s"], 4),
-            "serial_lat_p50_s": round(serial["lat_p50_s"], 4),
-            "serial_lat_p95_s": round(serial["lat_p95_s"], 4),
-            "pooled_lat_p50_s": round(pooled["lat_p50_s"], 4),
-            "pooled_lat_p95_s": round(pooled["lat_p95_s"], 4),
-            "note": (
-                "staging->queryable (flush+compact+upload+commit) across N "
-                "streams; serial = P_SYNC_WORKERS=1 two-phase ticks, pooled "
-                "= pipelined sync_cycle on the shared sync pool"
-            ),
-        },
-    )
-
-
-def bench_query_concurrency() -> None:
-    """Closed-loop concurrent query serving bench (the BASELINE.md latency
-    north star no bench emitted before this): N concurrent clients — one
-    heavy full-range aggregate, the rest light dashboard-style narrow-range
-    aggregates — against one node with background ingest running, under a
-    simulated object-store GET RTT so scan tasks have real service time.
-
-    Phase 1/2 A/B the shared scan scheduler's dispatch policy (fifo vs
-    fair) with the result cache OFF and report the light-query p50/p95/p99
-    per policy: fair round-robin must beat global FIFO at the tail, because
-    the heavy scan's backlog no longer sits in front of every dashboard
-    query. Phase 3 turns the partial-aggregate result cache on and measures
-    the same heavy aggregate cold vs warm (warm must skip the scan).
-
-    Env knobs: BENCH_QC_CLIENTS (8), BENCH_QC_SECS (6 per policy phase),
-    BENCH_QC_FILES (24 manifest files), BENCH_QC_ROWS (4000 rows/file),
-    BENCH_QC_GET_MS (10 ms simulated GET RTT), BENCH_QC_SCAN_WORKERS (2).
-    """
-    import pathlib
-    import threading
-
-    from parseable_tpu import DEFAULT_TIMESTAMP_KEY
-    from parseable_tpu.config import Options, StorageOptions
-    from parseable_tpu.core import Parseable
-    from parseable_tpu.event import Event
-    from parseable_tpu.query.provider import get_scan_scheduler
-    from parseable_tpu.query.session import QuerySession
-
-    n_clients = int(os.environ.get("BENCH_QC_CLIENTS", "8"))
-    phase_secs = float(os.environ.get("BENCH_QC_SECS", "6"))
-    n_files = int(os.environ.get("BENCH_QC_FILES", "24"))
-    rows_per_file = int(os.environ.get("BENCH_QC_ROWS", "4000"))
-    get_ms = float(os.environ.get("BENCH_QC_GET_MS", "10"))
-    base = datetime(2024, 5, 1, 0, 0, tzinfo=UTC)
-    hist = ("2024-05-01T00:00:00Z", "2024-05-02T00:00:00Z")
-    # 3 of the N files: the dashboard query a heavy scan must not starve
-    light_range = ("2024-05-01T00:01:00Z", "2024-05-01T00:04:00Z")
-
-    workdir = tempfile.mkdtemp(prefix="ptpu-qcbench-")
-    try:
-        opts = Options()
-        opts.local_staging_path = pathlib.Path(workdir) / "staging"
-        opts.scan_workers = int(os.environ.get("BENCH_QC_SCAN_WORKERS", "2"))
-        opts.query_result_cache_bytes = 0  # phases 1-2 measure scheduling
-        storage = StorageOptions(
-            backend="local-store", root=pathlib.Path(workdir) / "data"
-        )
-        p = Parseable(opts, storage)
-        rng = np.random.default_rng(17)
-        stream = p.create_stream_if_not_exists("qc")
-        for minute in range(n_files):
-            n = rows_per_file
-            ts = [
-                base + timedelta(minutes=minute, milliseconds=int(o))
-                for o in np.sort(rng.integers(0, 60_000, n))
-            ]
-            tbl = pa.table(
-                {
-                    DEFAULT_TIMESTAMP_KEY: pa.array(
-                        [t.replace(tzinfo=None) for t in ts], pa.timestamp("ms")
-                    ),
-                    "host": pa.array([f"h{i % 16}" for i in range(n)]),
-                    "status": pa.array(
-                        rng.choice([200.0, 404.0, 500.0], n).astype(np.float64)
-                    ),
-                    "bytes": pa.array(rng.random(n) * 1000),
-                }
-            ).combine_chunks()
-            for batch in tbl.to_batches():
-                Event(
-                    stream_name="qc",
-                    rb=batch,
-                    origin_size=batch.num_rows * 100,
-                    is_first_event=minute == 0,
-                    parsed_timestamp=base + timedelta(minutes=minute),
-                ).process(stream, commit_schema=p.commit_schema)
-        p.local_sync(shutdown=True)
-        p.sync_all_streams()
-
-        # simulated object-store RTT: without it, local-fs reads finish so
-        # fast the dispatch policy can't matter
-        real_get = p.storage.get_object
-
-        def slow_get(key):
-            time.sleep(get_ms / 1000.0)
-            return real_get(key)
-
-        p.storage.get_object = slow_get
-
-        heavy_sql = (
-            "SELECT host, status, count(*) c, sum(bytes) s FROM qc "
-            "GROUP BY host, status"
-        )
-        light_sql = "SELECT host, count(*) c FROM qc GROUP BY host"
-
-        def one(sql, rng_pair):
-            return QuerySession(p, engine="cpu").query(sql, *rng_pair)
-
-        # warm the plan cache + code paths so neither phase pays first-run
-        one(heavy_sql, hist)
-        one(light_sql, light_range)
-
-        def run_phase(policy: str) -> dict:
-            opts.scan_sched = policy
-            get_scan_scheduler(opts)  # re-root onto the policy under test
-            lats: list[float] = []
-            llock = threading.Lock()
-            stop = threading.Event()
-            errors: list[str] = []
-            heavy_done = [0]
-
-            def heavy_client():
-                while not stop.is_set():
-                    try:
-                        one(heavy_sql, hist)
-                        heavy_done[0] += 1
-                    except Exception as e:  # noqa: BLE001 - recorded
-                        errors.append(repr(e))
-                        return
-
-            def light_client():
-                while not stop.is_set():
-                    t0 = time.perf_counter()
-                    try:
-                        one(light_sql, light_range)
-                    except Exception as e:  # noqa: BLE001 - recorded
-                        errors.append(repr(e))
-                        return
-                    with llock:
-                        lats.append(time.perf_counter() - t0)
-
-            def ingest_client():
-                # background ingest: staging writes racing the queries
-                i = 0
-                while not stop.is_set():
-                    n = 500
-                    tbl = pa.table(
-                        {
-                            DEFAULT_TIMESTAMP_KEY: pa.array(
-                                [
-                                    (base + timedelta(hours=2, seconds=i * 60 + k)).replace(
-                                        tzinfo=None
-                                    )
-                                    for k in range(n)
-                                ],
-                                pa.timestamp("ms"),
-                            ),
-                            "host": pa.array(["ing"] * n),
-                            "status": pa.array([200.0] * n),
-                            "bytes": pa.array([1.0] * n),
-                        }
-                    )
-                    for batch in tbl.to_batches():
-                        Event(
-                            stream_name="qc", rb=batch, origin_size=n * 100,
-                            is_first_event=False,
-                            parsed_timestamp=base + timedelta(hours=2),
-                        ).process(stream, commit_schema=p.commit_schema)
-                    i += 1
-                    time.sleep(0.05)
-
-            threads = [threading.Thread(target=heavy_client)]
-            threads += [
-                threading.Thread(target=light_client) for _ in range(n_clients - 1)
-            ]
-            threads += [threading.Thread(target=ingest_client)]
-            for t in threads:
-                t.start()
-            time.sleep(phase_secs)
-            stop.set()
-            for t in threads:
-                t.join()
-            if errors:
-                print(f"# qc bench [{policy}] errors: {errors[:3]}", file=sys.stderr)
-            return {
-                "n": len(lats),
-                "p50": percentile(lats, 0.50),
-                "p95": percentile(lats, 0.95),
-                "p99": percentile(lats, 0.99),
-                "heavy_done": heavy_done[0],
-            }
-
-        fifo = run_phase("fifo")
-        fair = run_phase("fair")
-
-        # phase 3: partial-aggregate result cache, cold vs warm repeat
-        opts.query_result_cache_bytes = 64 * 1024 * 1024
-        t0 = time.perf_counter()
-        cold_res = one(heavy_sql, hist)
-        cold_s = time.perf_counter() - t0
-        warm_s = 1e9
-        warm_hit = False
-        for _ in range(3):
-            t0 = time.perf_counter()
-            warm_res = one(heavy_sql, hist)
-            warm_s = min(warm_s, time.perf_counter() - t0)
-            warm_hit = warm_hit or (
-                warm_res.stats["stages"].get("result_cache") == "hit"
-            )
-        ratio = warm_s / max(cold_s, 1e-9)
-        assert cold_res.table.num_rows == warm_res.table.num_rows
-
-        speedup_p95 = fifo["p95"] / max(fair["p95"], 1e-9)
-        print(
-            f"# query concurrency ({n_clients} clients + ingest, {n_files} files, "
-            f"{get_ms:.0f}ms GET): light fifo p50 {fifo['p50']*1e3:.0f}ms "
-            f"p95 {fifo['p95']*1e3:.0f}ms p99 {fifo['p99']*1e3:.0f}ms | "
-            f"fair p50 {fair['p50']*1e3:.0f}ms p95 {fair['p95']*1e3:.0f}ms "
-            f"p99 {fair['p99']*1e3:.0f}ms ({speedup_p95:.2f}x p95) | "
-            f"agg cache cold {cold_s*1e3:.0f}ms warm {warm_s*1e3:.0f}ms "
-            f"({ratio:.3f}x, hit={warm_hit})",
-            file=sys.stderr,
-        )
-        emit(
-            "bench_query_concurrency",
-            fair["n"] / max(phase_secs, 1e-9),
-            speedup_p95,
-            {
-                "unit": "queries/s",
-                "clients": n_clients,
-                "phase_secs": phase_secs,
-                "files": n_files,
-                "sim_get_ms": get_ms,
-                "scan_workers": opts.scan_workers,
-                "background_ingest": True,
-                "light_p50_s_fair": round(fair["p50"], 4),
-                "light_p95_s_fair": round(fair["p95"], 4),
-                "light_p99_s_fair": round(fair["p99"], 4),
-                "light_p50_s_fifo": round(fifo["p50"], 4),
-                "light_p95_s_fifo": round(fifo["p95"], 4),
-                "light_p99_s_fifo": round(fifo["p99"], 4),
-                "light_queries_fair": fair["n"],
-                "light_queries_fifo": fifo["n"],
-                "heavy_queries_fair": fair["heavy_done"],
-                "heavy_queries_fifo": fifo["heavy_done"],
-                "fair_vs_fifo_p95": round(speedup_p95, 3),
-                "agg_cache_cold_s": round(cold_s, 4),
-                "agg_cache_warm_s": round(warm_s, 4),
-                "agg_cache_warm_over_cold": round(ratio, 4),
-                "agg_cache_hit": warm_hit,
-                "note": (
-                    "closed-loop light-query latency under one heavy scan + "
-                    "background ingest; fair = per-query weighted RR on the "
-                    "shared scan pool, fifo = global arrival order; cache = "
-                    "partial-aggregate result cache cold vs warm repeat"
-                ),
-            },
-        )
-        p.shutdown()
-    except Exception as e:  # noqa: BLE001
-        print(f"# query concurrency bench failed: {e}", file=sys.stderr)
-    finally:
-        shutil.rmtree(workdir, ignore_errors=True)
-
-
-def bench_memory_pressure(emit_line: bool = True) -> dict | None:
-    """Tiering under real memory pressure (ROADMAP "make the tiering story
-    true"): a high-entropy dataset split across many parquet files, queried
-    warm with P_TPU_HOT_BYTES capped WELL below the encoded working set, so
-    every repetition pays eviction + re-ship for the part that doesn't fit.
-    A/Bs the eviction policy (P_TPU_HOT_POLICY=cost vs lru) over >=10 warm
-    reps and reports p50/p95 per policy plus the tiering counters — done
-    means hotset_evictions > 0 while the cost-policy warm ratio still beats
-    the CPU engine.
-
-    Under pressure LRU is pathological for a cyclic warm scan (each rep
-    flushes exactly the blocks the next rep needs first); the cost policy's
-    frequency x ship-cost scoring + probationary segment converges on a
-    stable resident subset, and the query-aware prefetcher overlaps the
-    re-ship of the rest with device compute.
-
-    Like bench_query_concurrency / bench_ingest_pipeline, the deployment's
-    I/O costs are simulated so a local-fs dev box measures the path the
-    design targets: every storage GET pays BENCH_MP_GET_MS (the CPU engine
-    re-fetches parquet from the object store every rep) and every enccache
-    block load pays BENCH_MP_SHIP_MS (the tier's local re-ship: NVMe read +
-    PCIe put — cheaper than a remote GET, which is exactly why the tier
-    exists). Prefetch overlaps the re-ship with compute; protected hot-set
-    hits skip it entirely.
-
-    Env knobs: BENCH_MP_FILES (12), BENCH_MP_FILE_ROWS (100000),
-    BENCH_MP_REPEATS (10), BENCH_MP_BUDGET_FRAC (0.35 of the measured
-    working set), BENCH_MP_GET_MS (25), BENCH_MP_SHIP_MS (10). Pure
-    in-process work; runs with or without the real chip (tier-1 smokes it
-    with tiny knobs so the eviction path can never rot into dead code
-    again)."""
-    import pathlib
-
-    from parseable_tpu import DEFAULT_TIMESTAMP_KEY
-    from parseable_tpu.config import Options, StorageOptions
-    from parseable_tpu.core import Parseable
-    from parseable_tpu.event import Event
-    from parseable_tpu.ops.enccache import get_enccache
-    from parseable_tpu.ops.hotset import get_hotset
-    from parseable_tpu.query.session import QuerySession
-
-    n_files = int(os.environ.get("BENCH_MP_FILES", "12"))
-    rows_per_file = int(os.environ.get("BENCH_MP_FILE_ROWS", "100000"))
-    repeats = int(os.environ.get("BENCH_MP_REPEATS", "10"))
-    budget_frac = float(os.environ.get("BENCH_MP_BUDGET_FRAC", "0.35"))
-    get_ms = float(os.environ.get("BENCH_MP_GET_MS", "25"))
-    ship_ms = float(os.environ.get("BENCH_MP_SHIP_MS", "10"))
-    rows_total = n_files * rows_per_file
-    base = datetime(2024, 5, 1, 0, 0, tzinfo=UTC)
-    sql = (
-        "SELECT path, host, count(*) c, sum(bytes) s FROM mp "
-        "GROUP BY path, host"
-    )
-
-    saved_env = {
-        k: os.environ.get(k) for k in ("P_TPU_HOT_BYTES", "P_TPU_HOT_POLICY")
-    }
-    workdir = tempfile.mkdtemp(prefix="ptpu-mpbench-")
-    summary: dict | None = None
-    unpatch: list = []  # (obj, attr, original) — the enccache is process-global
-    try:
-        opts = Options()
-        opts.local_staging_path = pathlib.Path(workdir) / "staging"
-        storage = StorageOptions(
-            backend="local-store", root=pathlib.Path(workdir) / "data"
-        )
-        p = Parseable(opts, storage)
-        rng = np.random.default_rng(23)
-        stream = p.create_stream_if_not_exists("mp")
-        n_hosts = int(os.environ.get("BENCH_MP_HOSTS", "32"))
-        hosts = [f"10.0.{i // 16}.{i % 16}" for i in range(n_hosts)]
-        paths = [f"/api/v1/resource{i}" for i in range(64)]
-        for minute in range(n_files):
-            n = rows_per_file
-            ts = [
-                base + timedelta(minutes=minute, milliseconds=int(o))
-                for o in np.sort(rng.integers(0, 60_000, n))
-            ]
-            tbl = pa.table(
-                {
-                    DEFAULT_TIMESTAMP_KEY: pa.array(
-                        [t.replace(tzinfo=None) for t in ts], pa.timestamp("ms")
-                    ),
-                    "host": pa.array(np.array(hosts)[rng.integers(0, len(hosts), n)]),
-                    "path": pa.array(np.array(paths)[rng.integers(0, len(paths), n)]),
-                    # high-entropy payload: full-mantissa uniform floats and
-                    # per-row-unique messages — parquet compression buys
-                    # ~nothing, disk size ~= logical size
-                    "bytes": pa.array((rng.random(n) * 50_000).astype(np.float64)),
-                    "message": pa.array(
-                        [f"request {minute * n + i} completed" for i in range(n)]
-                    ),
-                }
-            ).combine_chunks()
-            for batch in tbl.to_batches():
-                Event(
-                    stream_name="mp",
-                    rb=batch,
-                    origin_size=batch.num_rows * 100,
-                    is_first_event=minute == 0,
-                    parsed_timestamp=base + timedelta(minutes=minute),
-                ).process(stream, commit_schema=p.commit_schema)
-        p.local_sync(shutdown=True)
-        p.sync_all_streams()
-
-        # simulated deployment I/O: object-store GET RTT on the storage
-        # client (paid by anything re-reading parquet) and a local re-ship
-        # latency on enccache block loads (the tier's miss cost)
-        if get_ms > 0:
-            real_get_object = p.storage.get_object
-            real_get_range = p.storage.get_range
-
-            def slow_get_object(key):
-                time.sleep(get_ms / 1000.0)
-                return real_get_object(key)
-
-            def slow_get_range(key, start, end):
-                time.sleep(get_ms / 1000.0)
-                return real_get_range(key, start, end)
-
-            p.storage.get_object = slow_get_object
-            p.storage.get_range = slow_get_range
-
-        cpu = timed_runs(p, "mp", "cpu", sql, max(2, min(repeats, 3)))
-
-        def run_tpu() -> tuple[float, dict]:
-            t0 = time.perf_counter()
-            res = QuerySession(p, engine="tpu").query(sql)
-            return time.perf_counter() - t0, res.stats
-
-        # phase 0: all-resident pass under the default (huge) budget to
-        # measure the encoded working set and seed the enccache
-        os.environ.pop("P_TPU_HOT_BYTES", None)
-        os.environ["P_TPU_HOT_POLICY"] = "cost"
-        hs = get_hotset()
-        hs.clear()
-        run_tpu()
-        working_set = hs.resident_bytes
-        ec = get_enccache(p.options)
-        if ec is not None:
-            ec.wait_idle()
-            if ship_ms > 0:
-                real_ec_get = ec.get
-
-                def slow_ec_get(source_id, needed, dict_cols):
-                    time.sleep(ship_ms / 1000.0)
-                    return real_ec_get(source_id, needed, dict_cols)
-
-                ec.get = slow_ec_get
-                unpatch.append((ec, "get", real_ec_get))
-        budget = max(1, int(working_set * budget_frac))
-        os.environ["P_TPU_HOT_BYTES"] = str(budget)
-
-        phases: dict[str, dict] = {}
-        for policy in ("lru", "cost"):
-            os.environ["P_TPU_HOT_POLICY"] = policy
-            hs = get_hotset()  # re-roots onto the capped budget + policy
-            hs.clear()
-            run_tpu()  # populate up to the capped budget
-            ev0, times, last_stats = hs.evictions, [], {}
-            for _ in range(max(1, repeats)):
-                dt, last_stats = run_tpu()
-                times.append(dt)
-            stages = (last_stats.get("stages") or {}).get("hotset") or {}
-            phases[policy] = {
-                "p50": percentile(times, 0.50),
-                "p95": percentile(times, 0.95),
-                "evictions": hs.evictions - ev0,
-                "resident_bytes": hs.resident_bytes,
-                "prefetch_issued": stages.get("prefetch_issued", 0),
-                "prefetch_hits": stages.get("prefetch_hits", 0),
-                "prefetch_wasted": stages.get("prefetch_wasted", 0),
-            }
-
-        import jax
-
-        cost, lru = phases["cost"], phases["lru"]
-        cpus = (
-            len(os.sched_getaffinity(0))
-            if hasattr(os, "sched_getaffinity")
-            else (os.cpu_count() or 1)
-        )
-        summary = {
-            "files": n_files,
-            "rows": rows_total,
-            "repeats": repeats,
-            "profile": "highentropy",
-            "sim_get_ms": get_ms,
-            "sim_ship_ms": ship_ms,
-            "platform": jax.devices()[0].platform,
-            "cpus": cpus,
-            "working_set_bytes": working_set,
-            "hot_budget_bytes": budget,
-            "hotset_evictions": cost["evictions"],
-            "hotset_evictions_lru": lru["evictions"],
-            "warm_p50_s_cost": round(cost["p50"], 4),
-            "warm_p95_s_cost": round(cost["p95"], 4),
-            "warm_p50_s_lru": round(lru["p50"], 4),
-            "warm_p95_s_lru": round(lru["p95"], 4),
-            "cost_vs_lru_p95": round(lru["p95"] / max(cost["p95"], 1e-9), 3),
-            "cpu_p50_s": round(cpu["p50"], 4),
-            "warm_vs_cpu": round(cpu["p50"] / max(cost["p50"], 1e-9), 3),
-            "prefetch_issued": cost["prefetch_issued"],
-            "prefetch_hits": cost["prefetch_hits"],
-            "prefetch_wasted": cost["prefetch_wasted"],
-            "enccache_dropped": getattr(ec, "dropped", 0) if ec else 0,
-            "note": (
-                "warm reps with P_TPU_HOT_BYTES capped below the encoded "
-                "working set over a high-entropy profile; cost = freq x "
-                "recency x re-ship-cost eviction + probation + prefetch, "
-                "lru = plain LRU A/B"
-            ),
-        }
-        print(
-            f"# memory pressure ({n_files} files, ws {working_set/1e6:.1f}MB, "
-            f"budget {budget/1e6:.1f}MB): cost p50 {cost['p50']*1e3:.0f}ms "
-            f"p95 {cost['p95']*1e3:.0f}ms ({cost['evictions']} evictions, "
-            f"{cost['prefetch_hits']}/{cost['prefetch_issued']} prefetch hits) | "
-            f"lru p50 {lru['p50']*1e3:.0f}ms p95 {lru['p95']*1e3:.0f}ms "
-            f"({lru['evictions']} evictions) | cpu p50 {cpu['p50']*1e3:.0f}ms",
-            file=sys.stderr,
-        )
-        if emit_line:
-            emit(
-                "bench_memory_pressure",
-                rows_total / max(cost["p50"], 1e-9),
-                cpu["p50"] / max(cost["p50"], 1e-9),
-                summary,
-            )
-        p.shutdown()
-    except Exception as e:  # noqa: BLE001
-        print(f"# memory pressure bench failed: {e}", file=sys.stderr)
-    finally:
-        for obj, attr, orig in unpatch:
-            setattr(obj, attr, orig)
-        for k, v in saved_env.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        get_hotset().clear()  # drop capped-budget state for later phases
-        shutil.rmtree(workdir, ignore_errors=True)
-    return summary
-
-
-def _flight_fanin_ab(workdir, reps: int, stream: str) -> dict | None:
-    """Interleaved Flight-vs-HTTP fan-in A/B over the live ingestor
-    processes: one in-process QUERY-mode client against the harness's
-    shared store pulls `stream`'s staging window over each transport rung
-    back-to-back, alternating the order per pair. The caller loads the
-    window once into quiescent (sync-paused) ingestors, so every pull
-    sees the byte-identical, cache-hot window — the A/B measures the
-    wire, not the server-side window build. Returns per-transport GB/s +
-    per-pull wire bytes, or None if the A/B could not run at all."""
-    from parseable_tpu.config import Mode, Options, StorageOptions
-    from parseable_tpu.core import Parseable
-    from parseable_tpu.server import cluster as C
-
-    opts = Options()
-    opts.mode = Mode.QUERY
-    opts.local_staging_path = workdir / "staging-ab"
-    q = Parseable(
-        opts, StorageOptions(backend="local-store", root=workdir / "shared-store")
-    )
-    sides: dict = {
-        t: {"secs": [], "bytes": [], "fallbacks": 0} for t in ("flight", "http")
-    }
-
-    def pull(transport: str) -> None:
-        q.options.flight_client = transport == "flight"
-        st: dict = {}
-        t0 = time.perf_counter()
-        C.fetch_staging_batches(q, stream, stats=st)
-        side = sides[transport]
-        side["secs"].append(time.perf_counter() - t0)
-        side["bytes"].append(st.get("bytes", 0))
-        side["fallbacks"] += st.get("flight_fallbacks", 0)
-
-    try:
-        # warm both rungs: channel dial / keep-alive socket, and the
-        # server-side cold window build lands here instead of in a sample
-        for t in ("flight", "http", "flight", "http"):
-            pull(t)
-        for side in sides.values():
-            side["secs"].clear()
-            side["bytes"].clear()
-            side["fallbacks"] = 0
-        for i in range(reps):
-            order = ("flight", "http") if i % 2 == 0 else ("http", "flight")
-            for t in order:
-                pull(t)
-    except Exception as e:  # noqa: BLE001 - bench-only
-        print(f"# flight fan-in A/B failed: {e}", file=sys.stderr)
-        return None
-    finally:
-        q.shutdown()
-        C.shutdown_flight_pool()
-        C.shutdown_conn_pool()
-        C.shutdown_cluster_pool()
-
-    out: dict = {}
-    for t, side in sides.items():
-        total_b, total_s = sum(side["bytes"]), sum(side["secs"])
-        out[t] = {
-            "gbs": total_b / max(total_s, 1e-9) / 1e9,
-            "p50_s": percentile(side["secs"], 0.50),
-            "wire_bytes_per_pull": total_b / max(1, len(side["bytes"])),
-            "flight_fallbacks": side["fallbacks"],
-        }
-    return out
-
-
-def bench_distributed_fanout() -> None:
-    """Distributed fan-out bench with a REAL multi-process baseline
-    (ROADMAP: "give the distributed mesh bench a real baseline ... not
-    vs_baseline: 1.0"): scripts/blackbox.py boots 1 querier per data plane
-    + N ingestor processes over a shared LocalFS store, sustains background
-    ingest, and replays a dashboard-style GROUP BY aggregate over the last
-    minutes against both planes:
-
-    - central pull (P_QUERY_PUSHDOWN=0): the querier pulls every peer's
-      staging window over Arrow IPC and scans all parquet itself;
-    - pushdown (default): peers execute scan + partial aggregation on
-      node-local data and ship one partial table each.
-
-    Reports p50/p95 client-side latency and BYTES OVER THE WIRE (the
-    querier<->ingestor data plane: raw staging IPC vs partial tables) per
-    query, p50/p95 over BENCH_DF_QUERIES reps. vs_baseline = central p95 /
-    pushdown p95. A second record, bench_flight_fanin, comes from an
-    interleaved Flight-vs-HTTP staging fan-in A/B against the same live
-    ingestors (GB/s + per-pull wire bytes per transport). Env knobs:
-    BENCH_DF (0 skips), BENCH_DF_INGESTORS (2), BENCH_DF_QUERIES (12),
-    BENCH_DF_PRELOAD_ROWS (24000 per ingestor), BENCH_DF_INGEST_ROWS
-    (400 per background tick), BENCH_DF_AB_ROWS (960000 once per A/B
-    ingestor — ~20MB windows, big enough that the wire dominates the
-    per-pull fixed costs)."""
-    import pathlib
-    import threading
-
-    if os.environ.get("BENCH_DF", "1") == "0":
-        return
-    here = os.path.dirname(os.path.abspath(__file__))
-    sys.path.insert(0, os.path.join(here, "scripts"))
-    from blackbox import ClusterHarness
-
-    n_ing = int(os.environ.get("BENCH_DF_INGESTORS", "2"))
-    n_queries = int(os.environ.get("BENCH_DF_QUERIES", "12"))
-    preload = int(os.environ.get("BENCH_DF_PRELOAD_ROWS", "200000"))
-    bg_rows = int(os.environ.get("BENCH_DF_INGEST_ROWS", "1000"))
-    workdir = tempfile.mkdtemp(prefix="ptpu-dfbench-")
-    sql = "SELECT host, count(*) c, sum(v) s, avg(v) a FROM dfb GROUP BY host"
-    rng = np.random.default_rng(31)
-
-    def batch(n: int) -> list[dict]:
-        return [
-            {"host": f"h{int(i) % 16}", "v": float(v)}
-            for i, v in zip(rng.integers(0, 1 << 30, n), rng.random(n) * 100)
-        ]
-
-    try:
-        with ClusterHarness(pathlib.Path(workdir)) as cluster:
-            # sync fast so preloaded rows reach manifests while background
-            # ingest keeps a live staging window on every peer
-            ing_env = {"P_LOCAL_SYNC_INTERVAL": "3", "P_STORAGE_UPLOAD_INTERVAL": "2"}
-            # flight=True: ingestors serve both data-plane tiers, so the
-            # queriers ride the Arrow Flight hot tier by default and the
-            # A/B below can pin P_FLIGHT_CLIENT per pull
-            ingestors = [
-                cluster.spawn("ingest", f"ing{i}", env_extra=ing_env, flight=True)
-                for i in range(n_ing)
-            ]
-            q_central = cluster.spawn(
-                "query", "q-central", env_extra={"P_QUERY_PUSHDOWN": "0"}
-            )
-            q_push = cluster.spawn(
-                "query", "q-push", env_extra={"P_QUERY_PUSHDOWN": "1"}
-            )
-            for node in [*ingestors, q_central, q_push]:
-                cluster.wait_live(node)
-
-            t0 = time.perf_counter()
-            for node in ingestors:
-                done = 0
-                while done < preload:
-                    k = min(4000, preload - done)
-                    cluster.ingest(node, "dfb", batch(k))
-                    done += k
-            print(
-                f"# fanout bench: {n_ing}x{preload} rows preloaded in "
-                f"{time.perf_counter() - t0:.1f}s",
-                file=sys.stderr,
-            )
-            time.sleep(6)  # one sync tick: most of the preload reaches manifests
-
-            stop = threading.Event()
-
-            def background_ingest():
-                while not stop.is_set():
-                    for node in ingestors:
-                        try:
-                            cluster.ingest(node, "dfb", batch(bg_rows))
-                        except Exception as e:  # noqa: BLE001 - bench-only
-                            print(f"# bg ingest failed: {e}", file=sys.stderr)
-                            return
-                    stop.wait(0.25)
-
-            bg = threading.Thread(target=background_ingest, daemon=True)
-            bg.start()
-
-            def phase(node) -> dict:
-                cluster.query(node, sql, "5m", "now")  # warm plan/stream load
-                lats, wire, push_ok, fallbacks, flight_n = [], [], 0, 0, 0
-                for _ in range(n_queries):
-                    t0 = time.perf_counter()
-                    records, stats = cluster.query(node, sql, "5m", "now")
-                    lats.append(time.perf_counter() - t0)
-                    fan = (stats.get("stages") or {}).get("fanout") or {}
-                    wire.append(
-                        fan.get("bytes", 0) + fan.get("fanin_bytes", 0)
-                    )
-                    push_ok += fan.get("ok", 0)
-                    fallbacks += fan.get("fallback", 0)
-                    # pushdown scatter reports {"flight": n}; the central
-                    # plane's staging fan-in reports {"flight_peers": n}
-                    t = fan.get("transport", {})
-                    flight_n += t.get("flight", 0) + t.get("flight_peers", 0)
-                    assert records, "dashboard aggregate returned no groups"
-                return {
-                    "p50": percentile(lats, 0.50),
-                    "p95": percentile(lats, 0.95),
-                    "wire_bytes_per_query": sum(wire) / max(1, len(wire)),
-                    "pushdown_ok": push_ok,
-                    "fallbacks": fallbacks,
-                    "flight_peers": flight_n,
-                }
-
-            central = phase(q_central)
-            push = phase(q_push)
-            stop.set()
-            bg.join(10)
-
-            # Flight-vs-HTTP fan-in A/B: one in-process QUERY-mode client
-            # alternating the transport pull-by-pull, measuring raw
-            # data-plane GB/s. Dedicated ingestors with sync paused hold a
-            # frozen window, so every pull ships the byte-identical,
-            # cache-hot payload — the A/B measures the wire, not the
-            # server-side window build (the main-phase ingestors answer
-            # this stream with an empty window on both rungs alike).
-            ab_rows = int(os.environ.get("BENCH_DF_AB_ROWS", "960000"))
-            ab_env = {
-                "P_LOCAL_SYNC_INTERVAL": "3600",
-                "P_STORAGE_UPLOAD_INTERVAL": "3600",
-            }
-            ab_ing = [
-                cluster.spawn("ingest", f"ab{i}", env_extra=ab_env, flight=True)
-                for i in range(n_ing)
-            ]
-            for node in ab_ing:
-                cluster.wait_live(node)
-            for node in ab_ing:
-                done = 0
-                while done < ab_rows:
-                    k = min(4000, ab_rows - done)
-                    cluster.ingest(node, "dfab", batch(k))
-                    done += k
-            ab = _flight_fanin_ab(pathlib.Path(workdir), n_queries, "dfab")
-
-        byte_reduction = central["wire_bytes_per_query"] / max(
-            1.0, push["wire_bytes_per_query"]
-        )
-        p95_speedup = central["p95"] / max(push["p95"], 1e-9)
-        print(
-            f"# distributed fanout ({n_ing} ingestors + 2 queriers, background "
-            f"ingest): central p50 {central['p50']*1e3:.0f}ms p95 "
-            f"{central['p95']*1e3:.0f}ms {central['wire_bytes_per_query']/1e3:.1f}KB/q | "
-            f"pushdown p50 {push['p50']*1e3:.0f}ms p95 {push['p95']*1e3:.0f}ms "
-            f"{push['wire_bytes_per_query']/1e3:.1f}KB/q | {p95_speedup:.2f}x p95, "
-            f"{byte_reduction:.1f}x fewer bytes",
-            file=sys.stderr,
-        )
-        emit(
-            "bench_distributed_fanout",
-            1.0 / max(push["p50"], 1e-9),
-            p95_speedup,
-            {
-                "unit": "queries/s",
-                "processes": n_ing + 2,
-                "ingestors": n_ing,
-                "queries_per_phase": n_queries,
-                "background_ingest": True,
-                "central_p50_s": round(central["p50"], 4),
-                "central_p95_s": round(central["p95"], 4),
-                "pushdown_p50_s": round(push["p50"], 4),
-                "pushdown_p95_s": round(push["p95"], 4),
-                "central_wire_bytes_per_query": round(central["wire_bytes_per_query"], 1),
-                "pushdown_wire_bytes_per_query": round(push["wire_bytes_per_query"], 1),
-                "wire_byte_reduction": round(byte_reduction, 2),
-                "pushdown_ok_total": push["pushdown_ok"],
-                "pushdown_fallbacks": push["fallbacks"],
-                "pushdown_flight_peers": push["flight_peers"],
-                "central_flight_peers": central["flight_peers"],
-                "note": (
-                    "1 querier per data plane + N ingestor PROCESSES over "
-                    "LocalFS (scripts/blackbox.py) under sustained ingest; "
-                    "dashboard GROUP BY over the last 5 minutes; central = "
-                    "raw staging pull + full local scan, pushdown = per-peer "
-                    "partial aggregation; wire bytes = querier<->ingestor "
-                    "data plane only; both queriers ride the Arrow Flight "
-                    "hot tier (flight_peers counts per-peer Flight wins)"
-                ),
-            },
-        )
-        if ab and ab["flight"]["wire_bytes_per_pull"] > 0 and ab["http"]["gbs"] > 0:
-            fanin_speedup = ab["flight"]["gbs"] / max(ab["http"]["gbs"], 1e-9)
-            print(
-                f"# flight fan-in A/B: flight {ab['flight']['gbs']:.3f} GB/s "
-                f"({ab['flight']['wire_bytes_per_pull'] / 1e6:.2f} MB/pull) vs "
-                f"http {ab['http']['gbs']:.3f} GB/s "
-                f"({ab['http']['wire_bytes_per_pull'] / 1e6:.2f} MB/pull) -> "
-                f"{fanin_speedup:.2f}x fan-in throughput",
-                file=sys.stderr,
-            )
-            emit(
-                "bench_flight_fanin",
-                ab["flight"]["gbs"],
-                fanin_speedup,
-                {
-                    "unit": "GB/s",
-                    "ingestors": n_ing,
-                    "ab_pairs": n_queries,
-                    "ab_rows_per_ingestor": ab_rows,
-                    "flight_gbs": round(ab["flight"]["gbs"], 4),
-                    "http_gbs": round(ab["http"]["gbs"], 4),
-                    "flight_p50_s": round(ab["flight"]["p50_s"], 4),
-                    "http_p50_s": round(ab["http"]["p50_s"], 4),
-                    "flight_wire_bytes_per_pull": round(
-                        ab["flight"]["wire_bytes_per_pull"], 1
-                    ),
-                    "http_wire_bytes_per_pull": round(
-                        ab["http"]["wire_bytes_per_pull"], 1
-                    ),
-                    "flight_fallbacks": ab["flight"]["flight_fallbacks"],
-                    "note": (
-                        "interleaved A/B, one in-process QUERY client vs the "
-                        "live ingestor processes: staging-window fan-in over "
-                        "Arrow Flight vs keep-alive HTTP+IPC, every peer's "
-                        "window refilled before each pair so payloads match "
-                        "and the pull order alternates; GB/s = wire bytes / "
-                        "wall time per transport"
-                    ),
-                },
-            )
-    except Exception as e:  # noqa: BLE001
-        print(f"# distributed fanout bench failed: {e}", file=sys.stderr)
-    finally:
-        shutil.rmtree(workdir, ignore_errors=True)
-
-
-def bench_otel_ingest(p) -> None:
-    """OTel-logs ingest line: the native C++ lane (fastpath.cpp walk ->
-    NDJSON -> pyarrow reader -> staging) vs the Python flattener pipeline
-    over the same bytes, both end-to-end through flatten_and_push_logs
-    (VERDICT r4 #3: >=200k rows/s). Pure host work — runs whether or not
-    the chip is reachable."""
-
-    n_groups, n_recs = 10, 2000
-    rls = []
-    for g in range(n_groups):
-        recs = []
-        for i in range(n_recs):
-            recs.append(
-                {
-                    "timeUnixNano": str(1714521600000000000 + i * 1_000_000),
-                    "observedTimeUnixNano": str(1714521600500000000 + i * 1_000_000),
-                    "severityNumber": 9 + (i % 4),
-                    "body": {"stringValue": f"request {i} completed"},
-                    "attributes": [
-                        {"key": "http.status_code", "value": {"intValue": str(200 + i % 4)}},
-                        {"key": "http.method", "value": {"stringValue": "GET"}},
-                    ],
-                    "traceId": f"{i:032x}",
-                    "spanId": f"{i:016x}",
-                }
-            )
-        rls.append(
-            {
-                "resource": {
-                    "attributes": [
-                        {"key": "service.name", "value": {"stringValue": f"svc{g}"}}
-                    ]
-                },
-                "scopeLogs": [{"scope": {"name": "app"}, "logRecords": recs}],
-            }
-        )
-    payload = {"resourceLogs": rls}
-    body = json.dumps(payload).encode()
-    total = n_groups * n_recs
-
-    p.create_stream_if_not_exists("otelbench")
-
-    from parseable_tpu.event.format import LogSource
-    from parseable_tpu.server.ingest_utils import flatten_and_push_logs
-
-    def ingest_native(shards: int) -> float:
-        os.environ["P_INGEST_PARSE_SHARDS"] = str(shards)
-        os.environ["P_INGEST_SHARD_MIN_BYTES"] = "0"
-        try:
-            t0 = time.perf_counter()
-            n = flatten_and_push_logs(
-                p, "otelbench", None, LogSource.OTEL_LOGS, {}, raw_body=body
-            )
-            assert n == total
-            return time.perf_counter() - t0
-        finally:
-            os.environ.pop("P_INGEST_PARSE_SHARDS", None)
-            os.environ.pop("P_INGEST_SHARD_MIN_BYTES", None)
-
-    def ingest_python() -> float:
-        # the exact-semantics fallback pipeline over the same bytes
-        t0 = time.perf_counter()
-        n = flatten_and_push_logs(
-            p, "otelbench", json.loads(body), LogSource.OTEL_LOGS, {}
-        )
-        assert n == total
-        return time.perf_counter() - t0
-
-    cores = os.cpu_count() or 1
-    shards_n = min(cores, 4)
-    ingest_native(1)  # warm (library load, stream schema, reader import)
-    fast_times = [ingest_native(shards_n) for _ in range(3)]
-    t_fast = percentile(fast_times, 0.50)
-    t_fast_p95 = percentile(fast_times, 0.95)
-    t_1 = percentile([ingest_native(1) for _ in range(3)], 0.50) if shards_n > 1 else t_fast
-    t_py = min(ingest_python() for _ in range(2))
-    gb_per_sec = len(body) / 1e9 / t_fast
-    print(
-        f"# otel ingest: native {t_fast:.3f}s ({total/t_fast:,.0f} r/s, "
-        f"{gb_per_sec:.3f} GB/s) | python {t_py:.3f}s ({total/t_py:,.0f} r/s) | "
-        f"{t_py/t_fast:.1f}x",
-        file=sys.stderr,
-    )
-    print(
-        f"# otel ingest sharding: shards=1 {total/t_1:,.0f} r/s vs "
-        f"shards={shards_n} {total/t_fast:,.0f} r/s ({t_1/t_fast:.2f}x on a "
-        f"{cores}-core box; {total/t_fast/shards_n:,.0f} r/s/core)",
-        file=sys.stderr,
-    )
-    emit(
-        "otel_logs_ingest_rows_per_sec",
-        total / t_fast,
-        t_py / t_fast,
-        {
-            "note": "native C++ columnar OTel lane (sharded single-pass -> Arrow buffers -> ordered stitch) vs Python flattener pipeline, end-to-end incl. staging",
-            "latency_p50_s": round(t_fast, 4),
-            "latency_p95_s": round(t_fast_p95, 4),
-            "gb_per_sec": round(gb_per_sec, 4),
-            "rows_per_sec_per_core": round(total / t_fast / shards_n, 1),
-            "cores": cores,
-            "parse_shards": shards_n,
-            "shards1_rows_per_sec": round(total / t_1, 1),
-            "shard_scaling_x": round(t_1 / t_fast, 4),
-        },
-    )
-
-
-def require_accelerator() -> None:
-    """The bench measures the device path: without an accelerator it exits
-    non-zero before emitting anything (a CPU-JAX number under a device
-    metric's name is worse than no number)."""
-    import jax
-
-    devs = jax.devices()
-    if devs[0].platform == "cpu":
-        sys.exit(f"bench.py: JAX found no accelerator (devices: {devs}); nothing emitted")
-    print(f"# devices: {devs}", file=sys.stderr)
-
-
-def main() -> None:
-    from parseable_tpu.utils.compile_cache import configure_compile_cache
-
-    total_rows = int(os.environ.get("BENCH_ROWS", "32000000"))
-    repeats = int(os.environ.get("BENCH_REPEATS", "3"))
-    configure_compile_cache()
-    require_accelerator()
-
-    workdir = tempfile.mkdtemp(prefix="ptpu-bench-")
-    try:
-        from parseable_tpu.config import Options, StorageOptions
-        from parseable_tpu.core import Parseable
-
-        opts = Options()
-        opts.local_staging_path = __import__("pathlib").Path(workdir) / "staging"
-        storage = StorageOptions(backend="local-store", root=__import__("pathlib").Path(workdir) / "data")
-        p = Parseable(opts, storage)
-
-        t0 = time.perf_counter()
-        build_dataset(p, "bench", total_rows)
-        print(f"# dataset: {total_rows} rows built+cataloged in {time.perf_counter()-t0:.1f}s", file=sys.stderr)
-
-        # characterize the link once so cold numbers are interpretable
-        try:
-            import jax
-            import numpy as _np
-
-            x = _np.random.rand(16 << 18).astype(_np.float32)  # 16 MB
-            jax.device_put(x[:1024]).block_until_ready()
-            t1 = time.perf_counter()
-            dev = jax.device_put(x)
-            dev.block_until_ready()
-            h2d = x.nbytes / (time.perf_counter() - t1)
-            small = jax.device_put(_np.ones(64_000, _np.float32))
-            small.block_until_ready()
-            t1 = time.perf_counter()
-            _np.asarray(small)
-            d2h_lat = time.perf_counter() - t1
-            print(
-                f"# link: h2d {h2d/1e6:.0f} MB/s (16MB put), d2h 256KB in {d2h_lat*1e3:.0f}ms",
-                file=sys.stderr,
-            )
-            emit(
-                "link_h2d_bytes_per_sec",
-                h2d,
-                1.0,
-                {"d2h_256k_secs": round(d2h_lat, 3), "note": "link characterization"},
-            )
-        except Exception as e:  # noqa: BLE001
-            print(f"# link characterization failed: {e}", file=sys.stderr)
-
-        # measure + EMIT each config as it completes (a killed run still
-        # records whatever finished); the north-star config runs last so
-        # its line stays the final one when everything completes
-        def measure_and_emit(name: str, sql: str, stream: str = "bench") -> None:
-            from parseable_tpu.ops.enccache import get_enccache
-            from parseable_tpu.query import executor_tpu as ET
-
-            cpu = timed_runs(p, stream, "cpu", sql, max(1, repeats - 1))
-            cpu_t, rows, cpu_rows = cpu["p50"], cpu["rows_scanned"], cpu["rows"]
-            # compile first (one-time XLA cost), THEN measure cold: the cold
-            # number is the data path (parquet fetch + decode + transfer +
-            # compute, overlapped by the parallel scan pool), not compilation
-            run_query(p, stream, "tpu", sql)
-            # let write-behind land: cold must measure the disk-cache path,
-            # not a race with the enccache writer
-            ec = get_enccache(p.options)
-            if ec is not None:
-                ec.wait_idle()
-            # cold = the disk-cache/data path with no device-resident blocks,
-            # re-cleared before every repeat so it too gets p50/p95
-            adaptive_before = ET.ADAPTIVE_CPU_BLOCKS[0]
-            cold_times: list[float] = []
-            cold_stats: dict = {}
-            for _ in range(max(1, repeats - 1)):
-                clear_hot_state()
-                dt, _, _, cold_stats = run_query(p, stream, "tpu", sql)
-                cold_times.append(dt)
-            cold_t = percentile(cold_times, 0.50)
-            cold_p95 = percentile(cold_times, 0.95)
-            cold_adaptive = ET.ADAPTIVE_CPU_BLOCKS[0] - adaptive_before
-            warm = timed_runs(p, stream, "tpu", sql, repeats)
-            warm_t, tpu_rows = warm["p50"], warm["rows"]
-            if not rows_match(cpu_rows, tpu_rows):
-                print(f"# WARNING: {name} results differ!", file=sys.stderr)
-                print(f"#   cpu: {cpu_rows[:2]} tpu: {tpu_rows[:2]}", file=sys.stderr)
-            print(
-                f"# {name}: cpu p50 {cpu_t:.3f}s | tpu cold p50 {cold_t:.3f}s "
-                f"p95 {cold_p95:.3f}s ({rows/cold_t:,.0f} r/s, {cpu_t/cold_t:.1f}x, "
-                f"{cold_stats.get('bytes_scanned', 0)/1e6:.1f} MB fetched) | "
-                f"tpu warm p50 {warm_t:.3f}s p95 {warm['p95']:.3f}s "
-                f"({rows/warm_t:,.0f} r/s, {cpu_t/warm_t:.1f}x)",
-                file=sys.stderr,
-            )
-            metric = (
-                "topk_multicol_groupby_rows_per_sec_tpu"
-                if name == "topk_multicol"
-                else f"{name}_scan_rows_per_sec_tpu"
-            )
-            extra = {
-                "repeats": repeats,
-                "warm_p50_s": round(warm_t, 4),
-                "warm_p95_s": round(warm["p95"], 4),
-                "cpu_p50_s": round(cpu_t, 4),
-                "cpu_p95_s": round(cpu["p95"], 4),
-                "cold_rows_per_sec": round(rows / cold_t, 1),
-                "cold_vs_baseline": round(cpu_t / cold_t, 3),
-                "cold_p50_s": round(cold_t, 4),
-                "cold_p95_s": round(cold_p95, 4),
-                # cold-scan fetch accounting: the projected range reads'
-                # win shows up here as fetched bytes < dataset bytes
-                "cold_bytes_scanned": cold_stats.get("bytes_scanned", 0),
-                "cold_bytes_saved_by_projection": cold_stats.get(
-                    "bytes_saved_by_projection", 0
-                ),
-            }
-            if cold_adaptive:
-                # the measured link made shipping a losing trade for some
-                # cold blocks: they aggregated host-side while the device
-                # warmed in the background (ops/link.py)
-                extra["cold_adaptive_cpu_blocks"] = cold_adaptive
-            emit(metric, rows / warm_t, cpu_t / warm_t, extra)
-
-        for name, sql in CONFIGS.items():
-            if name != "topk_multicol":
-                measure_and_emit(name, sql)
-        bench_distributed_subprocess(total_rows)
-        bench_otel_ingest(p)
-        bench_json_ingest(p)
-        bench_edge()
-        bench_ingest_pipeline()
-        bench_query_concurrency()
-        bench_distributed_fanout()
-        bench_memory_pressure()
-        bench_config1(p)
-        bench_scale_inprocess()
-
-        # high-cardinality profile (VERDICT r2 "de-rig"): same configs 3-4
-        # over ~10k hosts / ~100k paths / ~50k-unique-per-block messages —
-        # the regressions this exposes are honest work, not hidden
-        hc_rows = int(os.environ.get("BENCH_HC_ROWS", str(max(total_rows // 4, 1_000_000))))
-        t0 = time.perf_counter()
-        build_dataset(p, "bench_hc", hc_rows, profile="highcard")
-        print(
-            f"# highcard dataset: {hc_rows} rows built in {time.perf_counter()-t0:.1f}s",
-            file=sys.stderr,
-        )
-        measure_and_emit("regex_filter_highcard", CONFIGS["regex_filter"], stream="bench_hc")
-        measure_and_emit("topk_multicol_highcard", CONFIGS["topk_multicol"], stream="bench_hc")
-
-        # north star LAST (config 4)
-        measure_and_emit("topk_multicol", CONFIGS["topk_multicol"])
-    finally:
-        shutil.rmtree(workdir, ignore_errors=True)
-
-
-if __name__ == "__main__":
-    main()

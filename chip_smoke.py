@@ -62,7 +62,7 @@ DEFAULT_ROWS = 32_000_000
 BASE_MS = 1_714_521_600_000  # 2024-05-01T00:00:00Z, bench.py's base
 REL_TOL = 1e-4  # float sums/averages vs the f64 reference (executor_tpu docstring)
 
-# bench.py:42-167, default profile: 32 hosts, 64 paths, 27 message templates,
+# bench.py build_dataset, default profile: 32 hosts, 64 paths, 27 message templates,
 # 8 statuses (5 distinct), 6 methods (4 distinct)
 HOSTS = [f"10.0.{i}.{j}" for i in range(4) for j in range(8)]
 PATHS = [f"/api/v1/resource{i}" for i in range(64)]
@@ -727,7 +727,7 @@ def main() -> int:
         check(args.cpu_rehearsal or (args.rows >= DEFAULT_ROWS and args.batch_rows == BATCH_ROWS),
               f"--rows {args.rows} / --batch-rows {args.batch_rows}: a chip run loads at least {DEFAULT_ROWS} rows "
               f"in {BATCH_ROWS}-row buckets (smaller sizes are for --cpu-rehearsal)")
-        # scratch: never ./staging, never .benchwork; the name may be temporary
+        # scratch: never ./staging; the name may be temporary
         workdir = Path(tempfile.mkdtemp(prefix="ptpu-chip-smoke-"))
         smoke(args, workdir, result)
         result["ok"] = True

@@ -19,13 +19,11 @@ Rules (each is one discipline):
 - traced-control-flow   Python if/while/assert on traced values in jit'd
                         bodies, resolved from jit sites through local defs
 - transfer-discipline   device_put/device_get must be priced into
-                        LinkProfile/route_stats accounting
+                        route_stats byte accounting
                         (``# link-priced: <where>`` points elsewhere)
 - dtype-promotion       float64 inside traced bodies; jax_enable_x64 flips
 - donation-hazard       use-after-donate errors; undocumented missed
                         donation as advisory
-- bench-sync            (advisory) timed device regions must
-                        block_until_ready before the clock stops
 
 The dynamic companion is the ``P_DLINT=1`` pytest tripwire
 (``parseable_tpu.analysis.device.tripwire``): it hooks ``jax.jit``,
@@ -62,7 +60,6 @@ from parseable_tpu.analysis.device.rules_jit import (
     TracedControlFlowRule,
 )
 from parseable_tpu.analysis.device.rules_sync import (
-    BenchSyncRule,
     HostSyncRule,
     TransferDisciplineRule,
 )
@@ -76,21 +73,20 @@ DEVICE_RULES: list[type[Rule]] = [
     TransferDisciplineRule,
     DtypePromotionRule,
     DonationHazardRule,
-    BenchSyncRule,
 ]
 
 # tests/ deliberately touch device arrays (that is what device tests do);
-# the discipline applies to shipped code and the bench harnesses.
-DEFAULT_PATHS = ["parseable_tpu", "scripts", "bench.py"]
+# the discipline applies to shipped code.
+DEFAULT_PATHS = ["parseable_tpu", "scripts"]
 
 _SUPPRESS_RE = re.compile(r"dlint:\s*disable(?:=([A-Za-z0-9_,-]+))?")
 
 
 @dataclass
 class DeviceReport(AnalysisReport):
-    """plint's report shape plus non-gating advisories (bench-sync and
-    missed-donation notes): printed as notes, serialized under their own
-    key, never part of the exit code."""
+    """plint's report shape plus non-gating advisories (missed-donation
+    notes): printed as notes, serialized under their own key, never part
+    of the exit code."""
 
     advisories: list[Finding] = field(default_factory=list)
 

@@ -5,8 +5,8 @@ error — plint/wlint's contract exactly, so check_green.sh treats the
 gates identically. `--json` emits a machine-diffable report (stable
 ordering, content fingerprints); `--json-out FILE` writes the same report
 as a gate artifact while keeping human-readable output on stdout.
-Advisories (bench-sync, missed-donation) print as notes and never affect
-the exit code.
+Advisories (missed-donation) print as notes and never affect the exit
+code.
 
 No --changed / result cache here: host-sync is a whole-graph reachability
 rule (the sync and the hot loop that reaches it are rarely in the same

@@ -13,9 +13,9 @@ hierarchies and wlint uses for wire headers:
 
 ``# sync-boundary[: reason]``
     Marks a line (or a whole function, via its def line) as a *declared*
-    device->host synchronization point — a priced readback, a sampled link
-    probe.  The host-sync rule exempts declared boundaries; everything else
-    reachable from a hot loop is a finding.
+    device->host synchronization point — a priced readback.  The host-sync
+    rule exempts declared boundaries; everything else reachable from a hot
+    loop is a finding.
 
 ``# device-hot``
     Marks a loop/function as a device hot path.  These are the roots the
@@ -23,7 +23,7 @@ hierarchies and wlint uses for wire headers:
 
 ``# link-priced[: reason]``
     Marks a ``device_put``/``device_get`` (or the function owning it) as
-    accounted for in LinkProfile/route_stats byte accounting even though
+    accounted for in route_stats byte accounting even though
     the pricing calls live elsewhere in the function.
 
 Annotations are read from ``SourceFile.comments`` (tokenize-derived, so

@@ -11,16 +11,11 @@
   ``# sync-boundary: <why>`` (line or whole function) is exempt — the point
   is not "never sync" but "every sync is declared and priced".
 * ``transfer-discipline`` — every ``jax.device_put``/``device_get`` in the
-  query path must be priced into LinkProfile/route_stats byte accounting
-  (``record_h2d``/``record_d2h``/``DEVICE_BYTES_TO_DEVICE``/ the
-  ``h2d_bytes``/``d2h_bytes`` route counters) within its enclosing named
-  function, or carry a ``# link-priced: <where>`` annotation pointing at
-  the accounting.  Lambdas are opaque: a ship inside a lambda needs the
-  line annotation.
-* ``bench-sync`` (advisory) — a timed region (``t = perf_counter()`` …
-  ``… - t``) that launches device work must call ``block_until_ready``
-  after the last launch and before the clock stops, or the benchmark
-  measures dispatch latency, not execution.
+  query path must be priced into the byte accounting
+  (``DEVICE_BYTES_TO_DEVICE`` / the ``h2d_bytes``/``d2h_bytes`` route
+  counters) within its enclosing named function, or carry a
+  ``# link-priced: <where>`` annotation pointing at the accounting.
+  Lambdas are opaque: a ship inside a lambda needs the line annotation.
 """
 
 from __future__ import annotations
@@ -44,8 +39,7 @@ _DEVICE_ROOTS = ("jnp",)
 #: Cache variables whose ``.get()`` yields a compiled device program.
 _PROGRAM_HINTS = ("program", "cache", "prog")
 
-_PRICING_CALL_TAILS = frozenset({"record_h2d", "record_d2h"})
-_PRICING_NAMES = frozenset({"DEVICE_BYTES_TO_DEVICE", "get_link"})
+_PRICING_NAMES = frozenset({"DEVICE_BYTES_TO_DEVICE"})
 _PRICING_KEYS = frozenset({"h2d_bytes", "d2h_bytes"})
 
 
@@ -172,17 +166,17 @@ class HostSyncRule(Rule):
 
     Every sync on the hot path must either go away or become a declared,
     priced boundary (``# sync-boundary: <why>``): the executor's
-    ``_timed_readback`` feeds the link profile that adaptive routing and
-    transfer budgeting read, so an undeclared ``np.asarray`` is both a
-    stall *and* invisible to the cost model.
+    ``_timed_readback`` ticks ``d2h_bytes`` and the phase clock, so an
+    undeclared ``np.asarray`` is both a stall *and* invisible to the byte
+    accounting.
     """
 
     name = "host-sync"
     description = "undeclared device->host sync reachable from a # device-hot root"
     rationale = (
         "an implicit sync serializes dispatch against device completion "
-        "and bypasses LinkProfile accounting; declared boundaries "
-        "(_timed_readback, sampled link probes) are the only allowed syncs"
+        "and bypasses the byte accounting; declared boundaries "
+        "(_timed_readback) are the only allowed syncs"
     )
 
     def applies(self, rel: str) -> bool:
@@ -295,18 +289,18 @@ class HostSyncRule(Rule):
 class TransferDisciplineRule(Rule):
     """Unpriced device_put/device_get in the query path.
 
-    Transfers are the resource the link profile exists to model — the
-    adaptive router's device-vs-CPU decision is only as good as the byte
-    accounting feeding it.  A ship that bypasses ``record_h2d``/route
-    counters skews every routing decision after it.
+    Per-query host<->device traffic is the budget the device path is
+    designed around, and ``h2d_bytes``/``d2h_bytes`` are how a response
+    (and the benchmark) sees it.  A ship that bypasses the counters is
+    traffic nobody can read.
     """
 
     name = "transfer-discipline"
-    description = "device_put/device_get must be priced into link accounting"
+    description = "device_put/device_get must be priced into byte accounting"
     rationale = (
-        "unpriced transfers starve the EWMA the adaptive router trusts; "
-        "a data-sized ship inside a loop is the expensive variant of the "
-        "same bug"
+        "unpriced transfers are missing from the h2d/d2h bytes a response "
+        "reports; a data-sized ship inside a loop is the expensive variant "
+        "of the same bug"
     )
 
     def applies(self, rel: str) -> bool:
@@ -351,8 +345,8 @@ class TransferDisciplineRule(Rule):
                 path=sf.rel,
                 line=call.lineno,
                 message=(
-                    f"jax.{kind}{where}{loop} is not priced into LinkProfile/"
-                    "route_stats accounting — tick record_h2d/record_d2h or "
+                    f"jax.{kind}{where}{loop} is not priced into route_stats "
+                    "byte accounting — tick DEVICE_BYTES_TO_DEVICE or "
                     "the h2d_bytes/d2h_bytes route counters, or annotate "
                     "`# link-priced: <where the bytes are tallied>`"
                 ),
@@ -364,116 +358,10 @@ class TransferDisciplineRule(Rule):
         for n in ast.walk(fn):
             if isinstance(n, ast.Call):
                 ch = attr_chain(n.func)
-                if ch and (
-                    ch[-1] in _PRICING_CALL_TAILS or ch[-1] in _PRICING_NAMES
-                ):
+                if ch and ch[-1] in _PRICING_NAMES:
                     return True
             elif isinstance(n, ast.Name) and n.id in _PRICING_NAMES:
                 return True
             elif isinstance(n, ast.Constant) and n.value in _PRICING_KEYS:
                 return True
         return False
-
-
-class BenchSyncRule(Rule):
-    """Advisory: timed device regions must block before the clock stops.
-
-    JAX dispatch is asynchronous — ``fn(x)`` returns before the device
-    finishes.  A ``perf_counter()`` pair around device work without a
-    ``block_until_ready`` between the last launch and the stop measures
-    dispatch latency (microseconds) instead of execution (milliseconds),
-    which is exactly the error that makes a bench table lie.
-    """
-
-    name = "bench-sync"
-    description = "timed device region stops the clock before block_until_ready"
-    rationale = (
-        "async dispatch makes an unblocked timer read measure launch "
-        "overhead, not device execution — the bench number becomes fiction"
-    )
-
-    _BENCH_FILES = ("bench.py",)
-    _BENCH_PREFIX = "scripts/bench_"
-
-    def applies(self, rel: str) -> bool:
-        return False  # advisory-only; work happens in advisories()
-
-    def _bench_file(self, rel: str) -> bool:
-        return rel in self._BENCH_FILES or (
-            rel.startswith(self._BENCH_PREFIX) and rel.endswith(".py")
-        )
-
-    def advisories(self, project: Project):
-        for sf in project.files:
-            if not self._bench_file(sf.rel) or sf.tree is None:
-                continue
-            scopes: list[ast.AST] = [sf.tree]
-            scopes.extend(
-                n
-                for n in ast.walk(sf.tree)
-                if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef))
-            )
-            for scope in scopes:
-                yield from self._scan_scope(sf, scope)
-
-    def _scan_scope(self, sf: SourceFile, scope: ast.AST):
-        starts: list[tuple[str, int]] = []
-        for node in _own_nodes(scope):
-            if (
-                isinstance(node, ast.Assign)
-                and len(node.targets) == 1
-                and isinstance(node.targets[0], ast.Name)
-                and any(
-                    isinstance(c, ast.Call)
-                    and attr_chain(c.func)[-1:] in (["perf_counter"], ["monotonic"])
-                    for c in ast.walk(node.value)
-                )
-            ):
-                starts.append((node.targets[0].id, node.lineno))
-
-        for t_name, start_line in starts:
-            stop_line = None
-            for node in _own_nodes(scope):
-                if (
-                    isinstance(node, ast.BinOp)
-                    and isinstance(node.op, ast.Sub)
-                    and isinstance(node.right, ast.Name)
-                    and node.right.id == t_name
-                    and node.lineno > start_line
-                ):
-                    if stop_line is None or node.lineno < stop_line:
-                        stop_line = node.lineno
-            if stop_line is None:
-                continue
-            device_lines = []
-            block_lines = []
-            for node in _own_nodes(scope):
-                if not isinstance(node, ast.Call):
-                    continue
-                ch = attr_chain(node.func)
-                tail = ch[-1] if ch else (
-                    node.func.attr if isinstance(node.func, ast.Attribute) else ""
-                )
-                if tail == "block_until_ready" and start_line < node.lineno <= stop_line:
-                    block_lines.append(node.lineno)
-                elif ch and (
-                    ch[0] in ("jnp",) or ch[:1] == ["jax"] or tail == "device_put"
-                ) and start_line < node.lineno < stop_line:
-                    device_lines.append(node.lineno)
-            if not device_lines:
-                continue
-            if block_lines and max(block_lines) >= max(device_lines):
-                continue
-            yield Finding(
-                rule=self.name,
-                path=sf.rel,
-                line=stop_line,
-                message=(
-                    f"timed region (clock starts line {start_line}) launches "
-                    "device work but stops the clock without a trailing "
-                    "block_until_ready — this measures dispatch, not "
-                    "execution"
-                ),
-                context=enclosing_context(sf.tree, scope)
-                or getattr(scope, "name", ""),
-            )

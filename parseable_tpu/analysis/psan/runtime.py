@@ -69,9 +69,8 @@ logger = logging.getLogger(__name__)
 _RAW_LOCK = _thread.allocate_lock  # always the uninstrumented factory
 
 # default allowlist: process-wide daemons that legitimately outlive a test
-# (singleton schedulers, device warmers, monitors). Extend via P_PSAN_ALLOW.
+# (singleton schedulers, monitors). Extend via P_PSAN_ALLOW.
 DEFAULT_THREAD_ALLOW = (
-    "device-warmer",
     "device-probe",
     "resource-monitor",
     "profiler-sampler",

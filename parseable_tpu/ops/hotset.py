@@ -11,14 +11,15 @@ attached chip).
 Entries are keyed by a source id (file path + mtime + size, or a staging
 batch fingerprint) plus the column-set signature.
 
-Eviction (P_TPU_HOT_POLICY, default "cost") is cost-aware, not plain LRU.
-Each entry carries a GDSF-style score
+Eviction is cost-aware, not plain LRU. Each entry carries a GDSF-style score
 
     score = clock + frequency * ship_cost(nbytes) / nbytes
 
-("seconds of re-ship saved per resident byte", ship_cost from the measured
-link profile, ops/link.py), so a cheap-to-refetch block is evicted before
-an expensive one of equal heat. The set is segmented SLRU-style:
+("seconds of re-ship saved per resident byte"), so a cheap-to-refetch block
+is evicted before an expensive one of equal heat. `ship_cost` is
+`_SHIP_PUT_SECONDS + nbytes / _SHIP_BYTES_PER_SECOND`: two placeholders (2 ms
+a put, 8 GB/s) that no eviction has ever tested, since no benchmark cell
+evicts (ROADMAP D16). The set is segmented SLRU-style:
 
 - a first touch lands in a *probationary* segment; a re-touch promotes to
   *protected*, capped at 80% of the budget (the weakest protected entry is
@@ -36,14 +37,11 @@ an expensive one of equal heat. The set is segmented SLRU-style:
   shift in the working set displaces stale protected entries — one scan
   does not.
 
-`P_TPU_HOT_POLICY=lru` keeps the old byte-budgeted LRU for A/B
-(bench_memory_pressure compares the two under a capped budget).
-
 Entries larger than the whole budget are rejected — counted and logged
 once per key, never silently dropped. The budget is P_TPU_HOT_BYTES
 (default 8 GiB — leaves headroom on a 16 GiB v5e); `get_hotset()` re-roots
-the singleton when P_TPU_HOT_BYTES / P_TPU_HOT_POLICY change, so tests and
-long-lived servers can resize without stale state.
+the singleton when P_TPU_HOT_BYTES changes, so tests and long-lived servers
+can resize without stale state.
 
 Cache contents are the *canonical* encodings (ops/device.py): batch-local
 dictionary codes, epoch-2020 int32-second timestamps, f32 numerics. Query-
@@ -68,7 +66,9 @@ from parseable_tpu.utils.metrics import (
 
 logger = logging.getLogger(__name__)
 
-_POLICIES = ("cost", "lru")
+# the default ship-cost estimate's two terms (module docstring): placeholders
+_SHIP_PUT_SECONDS = 0.002
+_SHIP_BYTES_PER_SECOND = 8e9
 # protected segment cap as a fraction of the budget: probation always keeps
 # at least the rest, so churn (and with it, measurable eviction pressure)
 # can never be starved out by promotions
@@ -85,7 +85,7 @@ class HotEntry:
 
 
 class _Slot:
-    """Per-entry policy state (cost mode): GDSF score + segment."""
+    """Per-entry policy state: GDSF score + segment."""
 
     __slots__ = ("entry", "freq", "pri", "probation", "seq")
 
@@ -98,33 +98,25 @@ class _Slot:
 
 
 def _default_ship_cost(nbytes: int) -> float:
-    from parseable_tpu.ops.link import get_link
-
-    # seconds to re-ship this block, from the measured link profile — the
-    # per-byte normalization happens in _priority
-    return get_link().ship_cost_per_byte(nbytes) * max(1, nbytes)
+    # seconds to re-ship this block — the per-byte normalization happens in
+    # _priority, so a small block, which pays the whole put, scores higher
+    return _SHIP_PUT_SECONDS + nbytes / _SHIP_BYTES_PER_SECOND
 
 
 class DeviceHotSet:
-    """Byte-budgeted cache of encoded device blocks.
-
-    Policy "cost": frequency x recency x re-ship-cost scoring with a
-    probationary segment, admission control, and ghost frequencies (see
-    module docstring). Policy "lru": plain LRU.
-    """
+    """Byte-budgeted cache of encoded device blocks: frequency x recency x
+    re-ship-cost scoring with a probationary segment, admission control,
+    and ghost frequencies (see module docstring)."""
 
     def __init__(
         self,
         budget_bytes: int | None = None,
-        policy: str | None = None,
         ship_cost: Callable[[int], float] | None = None,
     ):
-        from parseable_tpu.config import env_int, env_str
+        from parseable_tpu.config import env_int
 
         self.budget = budget_bytes or env_int("P_TPU_HOT_BYTES", 8 << 30)
-        policy = policy or env_str("P_TPU_HOT_POLICY", "cost") or "cost"
-        self.policy = policy if policy in _POLICIES else "cost"
-        # ship-cost estimator: measured link profile unless injected (tests)
+        # ship-cost estimator: the two constants unless injected (tests)
         self._ship_cost = ship_cost or _default_ship_cost
         self._entries: OrderedDict[tuple, _Slot] = OrderedDict()  # guarded-by: self._lock
         self._bytes = 0  # guarded-by: self._lock
@@ -150,7 +142,7 @@ class DeviceHotSet:
         try:
             cost = self._ship_cost(nb)
         except Exception:  # estimator must never break the cache
-            cost = nb / 8e9
+            cost = nb / _SHIP_BYTES_PER_SECOND
         return clock + slot.freq * (cost / nb)
 
     # ------------------------------------------------------------------- get
@@ -192,7 +184,7 @@ class DeviceHotSet:
             self._entries.move_to_end(key)
             slot.freq += 1
             slot.pri = self._priority(slot, self._clock)
-            if slot.probation and self.policy != "lru":
+            if slot.probation:
                 # re-touch: proven reuse -> promote into protected, capped
                 # at _PROTECTED_FRAC of the budget. Over the cap, the
                 # weakest protected entry is demoted iff this one is hotter
@@ -250,57 +242,52 @@ class DeviceHotSet:
                 slot.probation = old.probation
             slot.pri = self._priority(slot, self._clock)
             while self._bytes + entry.nbytes > self.budget and self._entries:
-                # evict one entry under the active policy
-                if self.policy == "lru":
-                    vkey = next(iter(self._entries))
-                    victim = self._entries.pop(vkey)
+                probation = [
+                    (k, s) for k, s in self._entries.items() if s.probation
+                ]
+                if probation:
+                    # scan resistance: probation drains first, so
+                    # one-shot blocks churn among themselves. Lowest
+                    # score goes (cheap-to-re-ship before expensive);
+                    # score ties break NEWEST-first — a sequential
+                    # over-budget scan then churns a single slot
+                    # instead of rolling the whole segment, which is
+                    # LRU's cyclic worst case (every warm rep flushes
+                    # exactly what the next rep needs first). Linear
+                    # scan: entry counts are O(manifest files).
+                    vkey, victim = min(
+                        probation, key=lambda kv: (kv[1].pri, -kv[1].seq)
+                    )
+                    self._entries.pop(vkey)
+                    # NO clock inflation here: intra-probation churn
+                    # must keep score ties exact or the MRU tie-break
+                    # degenerates back to rolling LRU
                 else:
-                    probation = [
-                        (k, s) for k, s in self._entries.items() if s.probation
-                    ]
-                    if probation:
-                        # scan resistance: probation drains first, so
-                        # one-shot blocks churn among themselves. Lowest
-                        # score goes (cheap-to-re-ship before expensive);
-                        # score ties break NEWEST-first — a sequential
-                        # over-budget scan then churns a single slot
-                        # instead of rolling the whole segment, which is
-                        # LRU's cyclic worst case (every warm rep flushes
-                        # exactly what the next rep needs first). Linear
-                        # scan: entry counts are O(manifest files).
-                        vkey, victim = min(
-                            probation, key=lambda kv: (kv[1].pri, -kv[1].seq)
-                        )
-                        self._entries.pop(vkey)
-                        # NO clock inflation here: intra-probation churn
-                        # must keep score ties exact or the MRU tie-break
-                        # degenerates back to rolling LRU
-                    else:
-                        # every resident has proven reuse. Admission
-                        # control: a first-touch candidate must BEAT the
-                        # weakest protected score to displace it, so a
-                        # one-shot full scan cannot flush the dashboard
-                        # working set. The rejected key's ghost frequency
-                        # still grows, so a genuine sustained shift in heat
-                        # wins after a few recurrences.
-                        vkey, victim = min(
-                            self._entries.items(), key=lambda kv: kv[1].pri
-                        )
-                        if slot.probation and slot.pri <= victim.pri:
-                            self.rejected_admission += 1
-                            self._ghost[key] = slot.freq
-                            self._ghost.move_to_end(key)
-                            if len(self._ghost) > _GHOST_CAP:
-                                self._ghost.popitem(last=False)
-                            HOTSET_RESIDENT_BYTES.set(self._bytes)
-                            return
-                        self._entries.pop(vkey)
-                        self._protected_bytes -= victim.entry.nbytes
-                        # aging: future scores start from the evicted
-                        # protected score, so long-resident-but-idle
-                        # entries decay relative to new heat
-                        if victim.pri > self._clock:
-                            self._clock = victim.pri
+                    # every resident has proven reuse. Admission
+                    # control: a first-touch candidate must BEAT the
+                    # weakest protected score to displace it, so a
+                    # one-shot full scan cannot flush the dashboard
+                    # working set. The rejected key's ghost frequency
+                    # still grows, so a genuine sustained shift in heat
+                    # wins after a few recurrences.
+                    vkey, victim = min(
+                        self._entries.items(), key=lambda kv: kv[1].pri
+                    )
+                    if slot.probation and slot.pri <= victim.pri:
+                        self.rejected_admission += 1
+                        self._ghost[key] = slot.freq
+                        self._ghost.move_to_end(key)
+                        if len(self._ghost) > _GHOST_CAP:
+                            self._ghost.popitem(last=False)
+                        HOTSET_RESIDENT_BYTES.set(self._bytes)
+                        return
+                    self._entries.pop(vkey)
+                    self._protected_bytes -= victim.entry.nbytes
+                    # aging: future scores start from the evicted
+                    # protected score, so long-resident-but-idle
+                    # entries decay relative to new heat
+                    if victim.pri > self._clock:
+                        self._clock = victim.pri
                 self._bytes -= victim.entry.nbytes
                 self.evictions += 1
                 HOTSET_EVICTIONS.inc()
@@ -320,7 +307,7 @@ class DeviceHotSet:
 
     def contains(self, key: tuple) -> bool:
         """Peek without touching recency/frequency or hit/miss counters
-        (the adaptive dispatcher asks before deciding where a block runs)."""
+        (the prefetcher asks before and after it ships a block)."""
         with self._lock:
             return key in self._entries
 
@@ -346,7 +333,6 @@ class DeviceHotSet:
         """One consistent read of the cache's state (stats.stages.hotset)."""
         with self._lock:
             return {
-                "policy": self.policy,
                 "budget_bytes": self.budget,
                 "resident_bytes": self._bytes,
                 "protected_bytes": self._protected_bytes,
@@ -365,18 +351,15 @@ _HOTSET_LOCK = threading.Lock()
 
 def get_hotset() -> DeviceHotSet:
     """Process-wide hot set; re-roots (drops the old instance, device
-    arrays freed by GC) when P_TPU_HOT_BYTES or P_TPU_HOT_POLICY change —
-    same pattern as get_scan_scheduler, so tests and long-lived servers
-    can resize the budget without stale singletons."""
-    from parseable_tpu.config import env_int, env_str
+    arrays freed by GC) when P_TPU_HOT_BYTES changes — same pattern as
+    get_scan_scheduler, so tests and long-lived servers can resize the
+    budget without stale singletons."""
+    from parseable_tpu.config import env_int
 
     global _GLOBAL_HOTSET
     budget = env_int("P_TPU_HOT_BYTES", 8 << 30)
-    policy = env_str("P_TPU_HOT_POLICY", "cost") or "cost"
-    if policy not in _POLICIES:
-        policy = "cost"
     with _HOTSET_LOCK:
         hs = _GLOBAL_HOTSET
-        if hs is None or hs.budget != budget or hs.policy != policy:
-            _GLOBAL_HOTSET = DeviceHotSet(budget_bytes=budget, policy=policy)
+        if hs is None or hs.budget != budget:
+            _GLOBAL_HOTSET = DeviceHotSet(budget_bytes=budget)
         return _GLOBAL_HOTSET

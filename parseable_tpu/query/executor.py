@@ -1190,24 +1190,16 @@ class QueryExecutor:
             # two-phase: per-block pyarrow partials + ONE vectorized merge —
             # no per-group Python, so 1M-group queries don't cliff
             # (DataFusion partial/final split parity)
-            import time as _time
-
-            from parseable_tpu.ops.link import get_link
-
-            link = get_link(getattr(self, "options", None))
             parts: list[pa.Table] = []
             for table in tables:
                 self._check_deadline()
-                t0 = _time.perf_counter()
                 table = self._bounds_filter(table)
-                rows_scanned = table.num_rows  # pre-filter: the adaptive
-                mask = self._where_mask(table)  # cost model prices raw rows
+                mask = self._where_mask(table)
                 if mask is not None:
                     table = table.filter(mask)
                 pt = PT.partial_from_block(table, sel.group_by, agg.specs)
                 if pt is not None:
                     parts.append(pt)
-                link.record_cpu_agg(rows_scanned, _time.perf_counter() - t0)
             if self.partials_source is not None:
                 # distributed pushdown: peers' combined partials join the
                 # local blocks in ONE merge (same funnel, exact avg/stddev)

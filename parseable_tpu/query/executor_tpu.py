@@ -1296,8 +1296,6 @@ _PROGRAM_CACHE: dict[tuple, Callable] = {}  # jit-cache: executor
 _PROGRAM_KEYS_BUILT: set = set()
 PROGRAM_BUILDS = [0]
 
-_TRANSFER_COUNT = [0]
-
 
 def _note_program_build(program: str, key: tuple, stats: dict | None = None) -> None:
     """Account one call-time program build for `program` under cache `key`:
@@ -1354,7 +1352,10 @@ class RouteStats(dict):
         super().__init__(
             device_warm=0,  # hot-set resident: zero bytes shipped
             device_cold=0,  # encoded + shipped this query
-            cpu_adaptive=0,  # link cost model routed to host
+            # constant 0 since the link-adaptive routing went (PR 32): kept
+            # because the benchmark's warm_block_share sums the four routes
+            # and drops a response that lacks one (ROADMAP M8)
+            cpu_adaptive=0,
             cpu_fallback=0,  # declared UnsupportedOnDevice (incl. budgets)
             h2d_bytes=0,
             d2h_bytes=0,
@@ -1415,49 +1416,34 @@ class RouteStats(dict):
         self.last_readback_ns = self._since
 
 
-# the ONE declared d2h readback — waits out pending compute, times pure
-# transfer, prices wire bytes into route_stats and the link-profile EWMA
+# the ONE declared d2h readback — waits out pending compute, then prices
+# the copy's wire bytes into route_stats
 # sync-boundary: every hot-path device->host read must flow through here
-def _timed_readback(
-    x, stats: dict | None = None, dtype=np.float64, feed_link: bool = True
-) -> np.ndarray:
-    """Device->host readback with link-profile recording. Pending compute
-    is waited out BEFORE the timer starts so the d2h sample measures pure
-    transfer — compute/compile waits folded in would poison the adaptive
-    cost model's latency EWMA. `stats` (a route_stats dict) gets the wire
-    bytes added for EXPLAIN ANALYZE observability; a `RouteStats` also gets
-    the wait under `device_wait` and the copy under `readback`.
+def _timed_readback(x, stats: dict | None = None, dtype=np.float64) -> np.ndarray:
+    """Device->host readback with byte accounting. Pending compute is
+    waited out BEFORE the copy, so a `RouteStats` clocks the wait under
+    `device_wait` and the copy alone under `readback`. `stats` (a
+    route_stats dict) gets the wire bytes added for EXPLAIN ANALYZE
+    observability.
 
     `dtype` is the HOST-side representation (np.float64 for f32
     accumulators headed into host arithmetic; None keeps the device
     dtype — int32 indices, bool masks). Wire bytes are priced at the
     DEVICE dtype's width capped at 4: the device layer is f32/int32/bool
-    end to end, so a float64 host target still crossed the link as f32.
-
-    `feed_link=False` is for the reads no dispatch decision was priced on
-    (a stripped key column, a histogram's occupancy probe): counted and
-    clocked like any other, but no sample for the EWMA that steers
-    `_adaptive_gate`."""
+    end to end, so a float64 host target still crossed the link as f32."""
     if isinstance(x, np.ndarray):
         return np.asarray(x) if dtype is None else np.asarray(x, dtype)
     clock = stats if isinstance(stats, RouteStats) else None
     prev = clock.enter("device_wait") if clock is not None else None
-    # wait for pending compute FIRST so the timing below is pure transfer
-    # — folding compile/compute waits into the d2h latency EWMA would
-    # poison the adaptive cost model. An async device failure surfaces
-    # here, at the declared readback, and fails the query.
+    # wait for pending compute FIRST so the `readback` phase is the copy
+    # alone. An async device failure surfaces here, at the declared
+    # readback, and fails the query.
     x.block_until_ready()
     if clock is not None:
         clock.enter("readback")
-    t0 = _time.perf_counter()
     arr = np.asarray(x) if dtype is None else np.asarray(x, dtype)
-    wire = arr.size * min(x.dtype.itemsize, 4)
     if stats is not None:
-        stats["d2h_bytes"] += wire
-    if feed_link:
-        from parseable_tpu.ops.link import get_link
-
-        get_link().record_d2h(wire, _time.perf_counter() - t0)
+        stats["d2h_bytes"] += arr.size * min(x.dtype.itemsize, 4)
     if clock is not None:
         clock.read_back(prev)
     return arr
@@ -1678,10 +1664,6 @@ class _KeptPartials:
         return self.reason is None
 
 
-# blocks the adaptive dispatcher routed to the CPU because the measured
-# link made shipping a losing trade (observable in tests/metrics)
-ADAPTIVE_CPU_BLOCKS = [0]
-
 # how many programs were built with a mesh (shard_map psum path) — the
 # stable signal tests/bench use to assert distributed execution happened
 # (cache-key positions are an implementation detail); the second counter
@@ -1722,14 +1704,10 @@ def resolve_mesh(options: Options | None = None):
         return _MESH_CACHE[shape]
     import jax
 
-    from parseable_tpu.ops.link import set_link_device
-
     # first device contact of the engine: from here on this process owns
-    # its chip(s), /metrics may report their memory gauges, and the link
-    # profile knows which device its measurements belong to
+    # its chip(s) and /metrics may report their memory gauges
     devices = jax.local_devices()
     note_engine_devices(devices)
-    set_link_device(devices[0].platform, devices[0].device_kind)
     mesh = None
     if shape != "off":
         from parseable_tpu.parallel.mesh import make_mesh, make_mesh_2d
@@ -1900,43 +1878,13 @@ class TpuQueryExecutor(QueryExecutor):
 
         def filtered() -> Iterator[pa.Table]:
             # bounds filtering happens once, in the inner executor's loop
-            from parseable_tpu.config import env_str
-            from parseable_tpu.ops.link import get_link
             from parseable_tpu.query.executor import _arr, evaluate
 
-            adaptive = env_str("P_TPU_ADAPTIVE", "1") != "0"
-            link = get_link(self.options)
-            hotset_obj = get_hotset()
             compiler = PredicateCompiler()
             for table in tables:  # device-hot: per-block filter dispatch
                 if sel.where is None:
                     yield table
                     continue
-                if adaptive:
-                    # readback here is a 1-byte-per-row filter mask
-                    route, k0, rows0 = self._adaptive_gate(
-                        table,
-                        mask_needed,
-                        set(),
-                        link,
-                        hotset_obj,
-                        lambda r: r,
-                        filter_workload=True,
-                    )
-                    if route:
-                        ADAPTIVE_CPU_BLOCKS[0] += 1
-                        self.route_stats["cpu_adaptive"] += 1
-                        t0 = _time.perf_counter()
-                        t = self._materialize(table)
-                        mask = _arr(evaluate(sel.where, t), t)
-                        out = t.filter(mask)
-                        # feed the measurement back so select-heavy loads
-                        # can correct a wrong routing estimate
-                        link.record_cpu_filter(rows0, _time.perf_counter() - t0)
-                        if k0 is not None:
-                            self._warm_block(k0, table, mask_needed, set())
-                        yield out
-                        continue
                 try:
                     enc, dev = self._encoded_block(table, mask_needed, set())
                     import jax.numpy as jnp
@@ -2032,58 +1980,6 @@ class TpuQueryExecutor(QueryExecutor):
             return
         counters = pf.close()
         self.route_stats.update(counters)
-
-    def _adaptive_gate(
-        self,
-        table: pa.Table,
-        needed: set[str] | None,
-        dict_cols: set[str],
-        link,
-        hotset_obj,
-        read_bytes: Callable[[int], int],
-        filter_workload: bool = False,
-    ) -> tuple[bool, tuple | None, int]:
-        """Shared routing decision: (route_to_cpu, hot_key|None, rows).
-        Resident blocks and small blocks always take the device path;
-        otherwise estimated ship+readback cost is priced against the
-        measured host rate (ops/link.py) — the filter rate for predicate
-        workloads, the aggregation rate otherwise."""
-        meta = table.schema.metadata or {}
-        src = meta.get(SOURCE_ID_META)
-        rows0 = int(meta[STUB_META]) if STUB_META in meta else table.num_rows
-        if rows0 < (1 << 16):
-            return False, None, rows0
-        key = hot_key(src, needed, dict_cols) if src is not None else None
-        if key is not None and hotset_obj.contains(key):
-            return False, key, rows0
-        ncols = len(needed) if needed is not None else 6
-        ship = link.ship_cost(rows0 * 4 * max(ncols, 1))
-        rb = read_bytes(rows0)
-        if rb:  # a zero-byte readback pays no d2h latency either
-            ship += link.read_cost(rb)
-        cpu = (
-            link.cpu_filter_cost(rows0) if filter_workload else link.cpu_cost(rows0)
-        )
-        return ship > cpu * 1.15, key, rows0
-
-    def _warm_block(
-        self, key: tuple, table: pa.Table, needed: set[str] | None, dict_cols: set[str]
-    ) -> None:
-        """Ship a CPU-routed block into the hot set off the query path.
-
-        The ship runs on a detached copy of the executor: this query
-        already counted the block as `cpu_adaptive`, so the background
-        transfer must not count it again as `device_cold` (every block has
-        exactly one route), nor drive the query's prefetcher from the
-        warmer thread."""
-        import copy
-
-        from parseable_tpu.ops.link import warm_async
-
-        warmer = copy.copy(self)
-        warmer.route_stats = RouteStats()
-        warmer._prefetcher, warmer._prefetch_tried = None, True
-        warm_async(key, lambda t=table: warmer._encoded_block(t, needed, dict_cols))
 
     def _materialize(self, table: pa.Table) -> pa.Table:
         """Real rows for a table (loads the source when it's a hot stub)."""
@@ -2340,8 +2236,7 @@ class TpuQueryExecutor(QueryExecutor):
 
             def put_rep(a: np.ndarray):
                 # priced: these ride outside _transfer's packed payload, so
-                # the link accounting must see them here (no latency sample:
-                # the puts are async and a probe would serialize the loop)
+                # the byte accounting must see them here
                 rs["h2d_bytes"] += a.nbytes
                 DEVICE_BYTES_TO_DEVICE.labels("lut").inc(a.nbytes)
                 return jax.device_put(a, rep_s)
@@ -2431,26 +2326,11 @@ class TpuQueryExecutor(QueryExecutor):
             exprs=exprs,
         )
 
-        # adaptive dispatch: per non-resident block, estimated ship (+
-        # local-mode readback) cost vs measured CPU aggregation cost
-        # (ops/link.py) — a degraded link must not make cold scans 10x
-        # slower than the host. Routed blocks still warm the device hot
-        # set in the background so the NEXT query runs warm.
-        import os
-
-        from parseable_tpu.ops.link import get_link
         from parseable_tpu.query.partials import (
             partial_from_block,
             specs_partializable,
         )
 
-        from parseable_tpu.config import env_str
-
-        adaptive = env_str("P_TPU_ADAPTIVE", "1") != "0"
-        link = get_link(self.options)
-        needed = self.plan.needed_columns
-        n_acc_rows = lay.n_rows
-        hotset_obj = get_hotset()
         partializable = bool(sel.group_by) and specs_partializable(specs)
 
         def cpu_block(table: pa.Table) -> None:
@@ -2460,15 +2340,6 @@ class TpuQueryExecutor(QueryExecutor):
             if keep is not None:
                 # a partial made on the host: the device cannot rank it
                 self._spill_kept(keep, partials, specs, lay, "host_partials")
-            t0 = _time.perf_counter()
-            # same row basis the gate prices on (raw block rows / stub
-            # meta, BEFORE the bounds filter) — recording post-bounds rows
-            # while pricing pre-bounds rows skews the EWMA under heavy
-            # time pruning and misroutes blocks (ADVICE r3 #2)
-            meta = table.schema.metadata or {}
-            rows_scanned = (
-                int(meta[STUB_META]) if STUB_META in meta else table.num_rows
-            )
             t = self._bounds_filter(self._materialize(table))
             mask = self._where_mask(t)
             if partializable:
@@ -2479,7 +2350,6 @@ class TpuQueryExecutor(QueryExecutor):
                     partials.append(pt)
             else:
                 agg.update(t, mask)
-            link.record_cpu_agg(rows_scanned, _time.perf_counter() - t0)
 
         t_start = _t.monotonic()
         # set when the scan discovers device percentiles/distincts can't fit
@@ -2494,31 +2364,6 @@ class TpuQueryExecutor(QueryExecutor):
                     self.route_stats["cpu_fallback"] += 1
                     cpu_block(table)
                     continue
-                # adaptive routing decides OUTSIDE the device-fallback try: the
-                # fallback handler re-aggregates the block, and a block that
-                # cpu_block already (even partially) folded must never reach it
-                if adaptive and not dkeys:
-                    # two-phase (local) blocks read back a dense G-sized
-                    # partial; the dense path reads back nothing per block
-                    route, k0, _ = self._adaptive_gate(
-                        table,
-                        needed,
-                        dict_cols,
-                        link,
-                        hotset_obj,
-                        (
-                            (lambda r: min(r, LOCAL_G_MAX) * n_acc_rows * 4)
-                            if local_mode
-                            else (lambda r: 0)
-                        ),
-                    )
-                    if route:
-                        ADAPTIVE_CPU_BLOCKS[0] += 1
-                        self.route_stats["cpu_adaptive"] += 1
-                        cpu_block(table)
-                        if k0 is not None:
-                            self._warm_block(k0, table, needed, dict_cols)
-                        continue
                 try:
                     enc, dev = self._encoded_block(table, self.plan.needed_columns, dict_cols)
                     rs.enter("prepare")
@@ -2696,7 +2541,7 @@ class TpuQueryExecutor(QueryExecutor):
         if expr_idx:
             # where the expressions were evaluated: in the device program for
             # the blocks it folded, by the CPU engine for the blocks that did
-            on_cpu = rs["cpu_fallback"] + rs["cpu_adaptive"]
+            on_cpu = rs["cpu_fallback"]
             if rs.blocks > on_cpu:
                 rs["expr_aggs_device"] = len(expr_idx)
                 rs.expr_nodes = AggExprCompiler.nodes(exprs)
@@ -3393,14 +3238,13 @@ class TpuQueryExecutor(QueryExecutor):
         """A column's encoded codes on host: the encode-time array when it
         still exists, else a readback (hot-set entries strip host copies,
         so for a warm block this is every call). Its bytes and time are
-        `stats`'s; the link EWMA gets no sample, since no routing decision
-        priced this read."""
+        `stats`'s."""
         col = enc.columns.get(column)
         if col is None:
             raise UnsupportedOnDevice(f"group key column {column} missing")
         if col.values is not None and len(col.values):
             return col.values
-        return _timed_readback(dev[column], stats, dtype=None, feed_link=False)
+        return _timed_readback(dev[column], stats, dtype=None)
 
     def _get_local_program(
         self,
@@ -3681,11 +3525,8 @@ class TpuQueryExecutor(QueryExecutor):
             return _timed_readback(h, self.route_stats).reshape(num_groups, DEVICE_NB)
         mat = h.reshape(num_groups, DEVICE_NB)
         # NB-sized (~8 KB) occupancy probe gating a readback 10-50x larger:
-        # when sparse the probe pays for itself. Counted and clocked, but no
-        # sample for the link EWMA (the gate priced the histogram, not this)
-        colsum = _timed_readback(
-            jnp.sum(mat, axis=0), self.route_stats, dtype=None, feed_link=False
-        )
+        # when sparse the probe pays for itself
+        colsum = _timed_readback(jnp.sum(mat, axis=0), self.route_stats, dtype=None)
         active = np.nonzero(colsum > 0)[0]
         if len(active) * 2 >= DEVICE_NB:
             return _timed_readback(h, self.route_stats).reshape(num_groups, DEVICE_NB)
@@ -4366,20 +4207,7 @@ def _transfer(enc: EncodedBatch, mesh=None) -> tuple[dict, int]:
         # when the block enters the hot set)
         pack("__rowmask", enc.row_mask)
     payload = np.concatenate(bufs) if bufs else np.empty(0, np.uint8)
-    _TRANSFER_COUNT[0] += 1
-    sample = payload.nbytes >= (1 << 20) and (
-        _TRANSFER_COUNT[0] == 1 or _TRANSFER_COUNT[0] % 8 == 0
-    )
-    t0 = _time.perf_counter() if sample else 0.0
-    dev_payload = jnp.asarray(payload)
-    if sample:
-        # block on 1-in-8 puts to keep the link profile honest without
-        # serializing the pipeline (puts are otherwise async)
-        # sync-boundary: sampled link-profile probe
-        dev_payload.block_until_ready()
-        from parseable_tpu.ops.link import get_link
-
-        get_link().record_h2d(payload.nbytes, _time.perf_counter() - t0)
+    dev_payload = jnp.asarray(payload)  # asynchronous: nothing here waits for it
     nbytes = payload.nbytes
     for key, dtype, count, o in parts:
         dev[key] = _bitcast_from_u8(

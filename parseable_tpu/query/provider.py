@@ -146,7 +146,7 @@ class ScanScheduler:
       3-file dashboard query alternate dispatches instead of the big scan
       occupying every worker until its backlog drains.
     - "fifo": strict global arrival order — the pre-scheduler behavior,
-      kept for A/B measurement (bench.py compares the two).
+      kept for A/B measurement.
 
     A lane's task is only dispatched when its own inflight-byte budget has
     room, so a slow consumer parks its *lane*, never a worker thread.

@@ -633,7 +633,7 @@ class QuerySession:
                         phys.append(
                             "aggregate: device fused one-hot fold (dense pow2 "
                             "group space; block-local two-phase past "
-                            "DENSE_G_MAX; link-adaptive CPU routing)"
+                            "DENSE_G_MAX)"
                         )
                     elif specs_partializable(agg.specs):
                         phys.append(
@@ -737,20 +737,11 @@ class QuerySession:
                 plans.append("\n".join(lines))
             routes = st.get("device_routes")
             if routes is not None:
-                # adaptive dispatch, observable without a profiler
-                # (VERDICT r3 #10): where each block ran and what the
-                # link actually carried, plus the measured link profile
-                # the routing decisions priced against
+                # observable without a profiler (VERDICT r3 #10): where
+                # each block ran and what the link actually carried
                 plan_types.append("device_routes")
                 plans.append(
                     " ".join(f"{k}={v}" for k, v in sorted(routes.items()))
-                )
-                from parseable_tpu.ops.link import get_link
-
-                snap = get_link(self.p.options).snapshot()
-                plan_types.append("link_profile")
-                plans.append(
-                    " ".join(f"{k}={v:.4g}" for k, v in sorted(snap.items()))
                 )
 
         table = pa.table({"plan_type": plan_types, "plan": plans})
@@ -1326,8 +1317,8 @@ class QuerySession:
         stats = {}
         routes = getattr(executor, "route_stats", None)
         if routes is not None:
-            # adaptive-dispatch observability (EXPLAIN ANALYZE surfaces
-            # this): per-block route decisions + actual transfer bytes
+            # route observability (EXPLAIN ANALYZE surfaces this): where
+            # each block ran + actual transfer bytes
             stats["device_routes"] = dict(routes)
             # the phase clock beside the counters (stages.execute reads it)
             self._execute_clock = routes

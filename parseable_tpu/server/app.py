@@ -325,10 +325,6 @@ class ServerState:
         shutdown_cluster_pool(wait=False)
         shutdown_conn_pool()
         shutdown_flight_pool()
-        # device-warmer singleton (background hot-set warming)
-        from parseable_tpu.ops.link import shutdown_warmer
-
-        shutdown_warmer()
         # native sharded-parse worker pool (pool-lifecycle: the C++ side's
         # lock-id ppool::g_mu state drains queued shard jobs before joining;
         # the pool restarts lazily if anything parses after stop)

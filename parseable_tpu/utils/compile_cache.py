@@ -1,7 +1,7 @@
 """Where this process keeps JAX's persistent compilation cache.
 
-Each entry point that compiles (server main, bench.py, scripts/
-bench_scale.py, chip_smoke.py's children) calls `configure_compile_cache()`
+Each entry point that compiles (server main, chip_smoke.py's children)
+calls `configure_compile_cache()`
 once before its first jit — never at package import, so library users and
 the test suite are untouched.
 

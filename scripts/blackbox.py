@@ -4,8 +4,7 @@ black-box cluster harness + failure-scenario suite").
 Boots REAL `parseable_tpu.server` processes — query / ingest modes over a
 shared LocalFS object store — and drives them purely over HTTP, the way the
 reference tests against running containers (docker-compose-distributed-test).
-Used by `bench.py bench_distributed_fanout` (1 querier + N ingestors with
-sustained background ingest) and importable from tests / future failure
+Used by `tests/test_blackbox.py` and importable from future failure
 scenarios: kill a node mid-sync, rolling restarts, querier LB with a dead
 peer.
 

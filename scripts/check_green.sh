@@ -75,8 +75,8 @@ fi
 # device-path gate: dlint (parseable_tpu/analysis/device/) — jit sites on
 # query paths must ride a declared program cache, host syncs reachable from
 # `# device-hot` loops must be `# sync-boundary` annotated, device_put/get
-# must be priced into link accounting, plus traced-control-flow, dtype
-# promotion, donation hazards and bench timing discipline. Full-tree run
+# must be priced into byte accounting, plus traced-control-flow, dtype
+# promotion and donation hazards. Full-tree run
 # (the host-sync rule walks the cross-file call graph; sub-second). Opt out
 # with DLINT=0 — which also disarms the P_DLINT tripwire on the tier-1 run
 # above; the JSON report lands at /tmp/dlint.json either way it runs.

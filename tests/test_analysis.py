@@ -233,8 +233,8 @@ def test_pool_lifecycle_custody_transfer_clean():
 
 
 def test_pool_lifecycle_global_with_module_stop_clean():
-    """The ops/link.py device-warmer idiom after the fix: a module-global
-    thread whose stop path joins it through a tuple-unload alias."""
+    """A module-global thread whose stop path joins it through a
+    tuple-unload alias."""
     code = """
         import threading
 
@@ -251,7 +251,7 @@ def test_pool_lifecycle_global_with_module_stop_clean():
             if w is not None:
                 w.join(5)
     """
-    assert check(PoolLifecycleRule(), code, "parseable_tpu/ops/link.py") == []
+    assert check(PoolLifecycleRule(), code, "parseable_tpu/ops/prefetch.py") == []
 
 
 def test_pool_lifecycle_global_without_stop_flagged():
@@ -265,7 +265,7 @@ def test_pool_lifecycle_global_without_stop_flagged():
             _WORKER = threading.Thread(target=fn, daemon=True)
             _WORKER.start()
     """
-    out = check(PoolLifecycleRule(), code, "parseable_tpu/ops/link.py")
+    out = check(PoolLifecycleRule(), code, "parseable_tpu/ops/prefetch.py")
     assert len(out) == 1
     assert "_WORKER" in out[0].message
 

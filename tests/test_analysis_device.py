@@ -31,7 +31,6 @@ from parseable_tpu.analysis.device.rules_jit import (
     TracedControlFlowRule,
 )
 from parseable_tpu.analysis.device.rules_sync import (
-    BenchSyncRule,
     HostSyncRule,
     TransferDisciplineRule,
 )
@@ -448,37 +447,6 @@ def test_transfer_lambda_is_opaque_to_function_pricing(tmp_path):
     assert "inside a lambda" in report.findings[0].message
 
 
-# --------------------------------------------------------------- bench-sync
-
-
-def test_bench_sync_advisory_tp_and_tn(tmp_path):
-    tp = """\
-    import time
-
-    import jax.numpy as jnp
-
-
-    def bench(x):
-        t = time.perf_counter()
-        y = jnp.sum(x)
-        dt = time.perf_counter() - t
-        return y, dt
-    """
-    root = _tree(tmp_path, {"bench.py": tp})
-    report = run_device_analysis(root, rules=[BenchSyncRule()])
-    assert report.findings == []  # advisory only: never gates
-    assert len(report.advisories) == 1
-    assert "measures dispatch, not" in report.advisories[0].message
-
-    tn = tp.replace(
-        "        dt = time.perf_counter() - t",
-        "        y.block_until_ready()\n        dt = time.perf_counter() - t",
-    )
-    root2 = _tree(tmp_path / "b", {"bench.py": tn})
-    report = run_device_analysis(root2, rules=[BenchSyncRule()])
-    assert report.advisories == []
-
-
 # ------------------------------------------------------ fingerprint stability
 
 
@@ -561,9 +529,9 @@ def test_cli_rule_selection_and_catalog(tmp_path):
         "transfer-discipline",
         "dtype-promotion",
         "donation-hazard",
-        "bench-sync",
     ):
         assert name in r.stdout
+    assert "bench-sync" not in r.stdout
 
     r = _dlint_cli(root, "--explain", "transfer-discipline")
     assert r.returncode == 0

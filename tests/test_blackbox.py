@@ -1,8 +1,7 @@
 """Black-box multi-process cluster smoke (scripts/blackbox.py in test reach).
 
 ROADMAP: the cross-process unlock ("multi-process black-box cluster
-harness") was exercised only by scripts/bench_fanout.py until now — zero
-test coverage. This smoke boots REAL `python -m parseable_tpu.server`
+harness"). This smoke boots REAL `python -m parseable_tpu.server`
 processes (1 querier + 1 ingestor over one LocalFS store), ingests over
 HTTP, waits for the sync tick to land parquet in the shared store, and
 queries over HTTP — counts, grouped aggregates, and post-sync visibility

@@ -31,7 +31,6 @@ MERGE_KEYS = ("merge_device", "merge_host", "merge_entries", "merge_survivors")
 def _small_dense_budget(monkeypatch):
     # a few thousand groups leave the dense path, as the cell's 2^31 do
     monkeypatch.setattr(ET, "DENSE_G_MAX", 1 << 10)
-    monkeypatch.setenv("P_TPU_ADAPTIVE", "0")
     monkeypatch.setenv("P_TPU_BLOCK_ROWS", str(BLOCK_ROWS))
 
 

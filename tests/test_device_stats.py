@@ -45,13 +45,6 @@ def run_device_strict(sql: str, tables: list[pa.Table], caplog):
     return out
 
 
-@pytest.fixture(autouse=True)
-def _no_adaptive(monkeypatch):
-    # deterministic device routing: the adaptive gate must not shunt test
-    # blocks to the host path these tests exist to avoid
-    monkeypatch.setenv("P_TPU_ADAPTIVE", "0")
-
-
 def latency_table(n=20_000, seed=0, groups=8):
     rng = np.random.default_rng(seed)
     v = np.exp(rng.normal(3.0, 1.0, n))  # lognormal latencies

@@ -4,8 +4,8 @@ fixed — each test names the rule it pins down.
 The static gate proves the *shape* of the discipline (annotated jit sites
 riding a declared cache, syncs routed through declared boundaries, priced
 transfers); these tests prove the *behavior*: warm queries build zero new
-XLA programs, every readback and LUT ship lands in the byte accounting the
-adaptive router trusts, and the program-cache traffic is consumed from
+XLA programs, every readback and LUT ship lands in the byte accounting a
+response reports, and the program-cache traffic is consumed from
 stats.stages.programs.
 """
 
@@ -19,13 +19,6 @@ from parseable_tpu.query import executor_tpu as ET
 from parseable_tpu.query.planner import plan as build_plan
 from parseable_tpu.query.sql import parse_sql
 from parseable_tpu.utils import metrics
-
-
-@pytest.fixture(autouse=True)
-def _no_adaptive(monkeypatch):
-    # deterministic device routing: the adaptive gate must not shunt test
-    # blocks to the host path these regressions exist to exercise
-    monkeypatch.setenv("P_TPU_ADAPTIVE", "0")
 
 
 def table(n=6_000, seed=0, groups=8):
@@ -138,7 +131,7 @@ def test_timed_readback_prices_wire_bytes_at_device_width():
 def test_group_lut_and_accumulator_ships_are_priced_h2d():
     """transfer-discipline: the group-LUT and accumulator device_put sites
     tick h2d route bytes and the tpu_bytes_to_device{op} counter —
-    un-priced ships would starve the link EWMA the adaptive router reads."""
+    un-priced ships would be missing from the bytes a response reports."""
 
     def op_total(op):
         return (

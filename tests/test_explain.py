@@ -113,9 +113,9 @@ def test_column_named_explain_still_works():
 
 def test_explain_analyze_surfaces_device_routes(loaded):
     """VERDICT r4 #10: EXPLAIN ANALYZE on the TPU engine reports per-block
-    route decisions (device warm/cold, adaptive/fallback CPU) and actual
-    transfer bytes, plus the link-profile snapshot the routing priced
-    against — adaptive dispatch is observable without a profiler."""
+    routes (device warm/cold, fallback CPU; `cpu_adaptive` is a constant 0
+    the benchmark's readers still sum) and actual transfer bytes, observable
+    without a profiler. No routing prices a link, so no `link_profile` row."""
     sess = QuerySession(loaded, engine="tpu")
     r = sess.query(
         "EXPLAIN ANALYZE SELECT host, count(*) c, sum(bytes) s FROM logs GROUP BY host",
@@ -145,9 +145,8 @@ def test_explain_analyze_surfaces_device_routes(loaded):
         for k in ("device_warm", "device_cold", "cpu_adaptive", "cpu_fallback")
     )
     assert total_blocks >= 1  # the scan dispatched at least one block
-    assert "link_profile" in rows
-    assert "h2d_bw=" in rows["link_profile"]
-    assert "cpu_rows_per_sec=" in rows["link_profile"]
+    assert int(routes["cpu_adaptive"]) == 0
+    assert "link_profile" not in rows
 
 
 def test_explain_analyze_cpu_engine_has_no_device_routes(loaded):

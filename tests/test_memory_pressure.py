@@ -1,14 +1,11 @@
 """Tiering under memory pressure: cost-aware hot-set eviction + admission
-control, query-aware prefetch, enccache write-behind backpressure, and the
-bench_memory_pressure tier-1 smoke (eviction path can never regress to
-dead code again)."""
+control, query-aware prefetch under a capped budget (the eviction path can
+never regress to dead code), and enccache write-behind backpressure."""
 
 from __future__ import annotations
 
-import importlib.util
 import threading
 import time
-from pathlib import Path
 
 import numpy as np
 import pytest
@@ -28,7 +25,7 @@ def test_cost_policy_evicts_cheap_before_expensive():
     """Equal heat, different re-ship cost: the cheap-to-refetch block goes
     first (GDSF score = freq * ship_cost/byte)."""
     costs = {100: 0.001, 101: 1.0}  # keyed by size: cheap vs expensive
-    hs = DeviceHotSet(budget_bytes=250, policy="cost", ship_cost=costs.get)
+    hs = DeviceHotSet(budget_bytes=250, ship_cost=costs.get)
     hs.put(("cheap",), _entry(100))
     hs.put(("exp",), _entry(101))
     hs.put(("new", 101), _entry(101))  # needs room: one of the two must go
@@ -39,36 +36,25 @@ def test_cost_policy_evicts_cheap_before_expensive():
 
 def test_scan_resistance_one_shot_scan_does_not_flush_dashboard():
     """A hot dashboard working set (touched repeatedly -> protected) must
-    survive one full over-budget scan under the cost policy; under LRU the
-    same sequence flushes everything."""
-
-    def run(policy: str) -> DeviceHotSet:
-        hs = DeviceHotSet(budget_bytes=1000, policy=policy, ship_cost=lambda n: 0.01)
-        for d in range(4):  # dashboard: 800 bytes, re-touched => protected
-            hs.put(("dash", d), _entry(200))
-        for _ in range(2):
-            for d in range(4):
-                assert hs.get(("dash", d)) is not None
-        for s in range(20):  # one-shot full scan, 5000 bytes through a 1000 cache
-            hs.put(("scan", s), _entry(250))
-        return hs
-
-    cost = run("cost")
+    survive one full over-budget scan."""
+    hs = DeviceHotSet(budget_bytes=1000, ship_cost=lambda n: 0.01)
+    for d in range(4):  # dashboard: 800 bytes, re-touched => protected
+        hs.put(("dash", d), _entry(200))
+    for _ in range(2):
+        for d in range(4):
+            assert hs.get(("dash", d)) is not None
+    for s in range(20):  # one-shot full scan, 5000 bytes through a 1000 cache
+        hs.put(("scan", s), _entry(250))
     for d in range(4):
-        assert cost.contains(("dash", d)), f"cost policy flushed dash{d}"
+        assert hs.contains(("dash", d)), f"the scan flushed dash{d}"
     # the scan hit the admission gate: first-touch blocks lost to protected
-    assert cost.rejected_admission > 0
-
-    lru = run("lru")
-    assert not any(lru.contains(("dash", d)) for d in range(4)), (
-        "LRU kept the dashboard through a full scan?! (A/B premise broken)"
-    )
+    assert hs.rejected_admission > 0
 
 
 def test_scan_churns_probation_with_evictions():
     """With free probation room, an over-budget scan churns among its own
     blocks (evictions > 0) while the protected set survives."""
-    hs = DeviceHotSet(budget_bytes=1000, policy="cost", ship_cost=lambda n: 0.01)
+    hs = DeviceHotSet(budget_bytes=1000, ship_cost=lambda n: 0.01)
     for d in range(3):  # 600 bytes protected, 400 free for probation
         hs.put(("dash", d), _entry(200))
     for _ in range(2):
@@ -86,7 +72,7 @@ def test_ghost_frequency_displaces_stale_protected():
     """Sustained new heat (not a one-shot scan) must eventually displace a
     stale protected set: rejected keys re-enter with their earned ghost
     frequency and out-score entries nobody touches anymore."""
-    hs = DeviceHotSet(budget_bytes=400, policy="cost", ship_cost=lambda n: 0.01)
+    hs = DeviceHotSet(budget_bytes=400, ship_cost=lambda n: 0.01)
     for d in range(2):
         hs.put(("old", d), _entry(200))
     for _ in range(2):
@@ -102,19 +88,10 @@ def test_ghost_frequency_displaces_stale_protected():
     )
 
 
-def test_lru_policy_is_plain_lru():
-    hs = DeviceHotSet(budget_bytes=100, policy="lru")
-    hs.put(("a",), _entry(60))
-    hs.put(("b",), _entry(60))
-    assert hs.get(("a",)) is None
-    assert hs.get(("b",)) is not None
-    assert hs.evictions == 1
-
-
 def test_oversize_rejected_counted_and_logged_once(caplog):
     """An entry larger than the whole budget was silently dropped before:
     now it ticks rejected_oversize and logs once per key."""
-    hs = DeviceHotSet(budget_bytes=100, policy="cost", ship_cost=lambda n: 0.01)
+    hs = DeviceHotSet(budget_bytes=100, ship_cost=lambda n: 0.01)
     with caplog.at_level("WARNING", logger="parseable_tpu.ops.hotset"):
         hs.put(("big",), _entry(1000))
         hs.put(("big",), _entry(1000))
@@ -126,7 +103,7 @@ def test_oversize_rejected_counted_and_logged_once(caplog):
 
 
 def test_get_hotset_reroots_on_env_change(monkeypatch):
-    """Budget/policy env changes rebuild the singleton (mirrors the
+    """A budget env change rebuilds the singleton (mirrors the
     get_scan_scheduler re-root pattern) — no stale instances in tests or
     long-lived servers."""
     base = get_hotset()
@@ -134,17 +111,14 @@ def test_get_hotset_reroots_on_env_change(monkeypatch):
     monkeypatch.setenv("P_TPU_HOT_BYTES", "12345")
     resized = get_hotset()
     assert resized is not base and resized.budget == 12345
-    monkeypatch.setenv("P_TPU_HOT_POLICY", "lru")
-    repoliced = get_hotset()
-    assert repoliced is not resized and repoliced.policy == "lru"
-    assert get_hotset() is repoliced
+    assert get_hotset() is resized
 
 
 def test_concurrent_get_put_evict_race():
     """Hammer get/put/clear from threads: the budget is never exceeded,
     byte accounting never goes negative, and the final ledger matches the
     resident entries exactly."""
-    hs = DeviceHotSet(budget_bytes=10_000, policy="cost", ship_cost=lambda n: 0.01)
+    hs = DeviceHotSet(budget_bytes=10_000, ship_cost=lambda n: 0.01)
     rng = np.random.default_rng(7)
     sizes = rng.integers(100, 1500, 64).tolist()
     errors: list = []
@@ -287,7 +261,6 @@ def test_prefetch_query_leaves_no_thread_or_inflight_ship(parseable, monkeypatch
     res = sess.query(sql)
     assert res.to_json_rows() == expected
     st = res.stats["stages"]["hotset"]
-    assert st["policy"] == "cost"
     assert st["evictions"] > 0, "capped budget produced no eviction pressure"
     assert st.get("prefetch_issued", 0) > 0
     assert hs.resident_bytes <= hs.budget, "leaked device bytes past the budget"
@@ -353,29 +326,3 @@ def test_enccache_no_drops_when_writer_keeps_up(tmp_path, monkeypatch):
     cache.wait_idle()
     cache.shutdown()
     assert cache.dropped == 0
-
-
-# ------------------------------------------------------------- bench smoke
-
-
-def test_bench_memory_pressure_smoke(monkeypatch):
-    """Fast deterministic smoke of the bench phase: a capped budget MUST
-    produce hotset_evictions > 0 (the eviction path can never silently
-    regress to dead code again) and both policies report warm latencies."""
-    spec = importlib.util.spec_from_file_location(
-        "bench", Path(__file__).resolve().parent.parent / "bench.py"
-    )
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
-    monkeypatch.setenv("BENCH_MP_FILES", "6")
-    monkeypatch.setenv("BENCH_MP_FILE_ROWS", "4000")
-    monkeypatch.setenv("BENCH_MP_REPEATS", "2")
-    monkeypatch.setenv("BENCH_MP_GET_MS", "0")
-    monkeypatch.setenv("BENCH_MP_SHIP_MS", "0")
-    summary = bench.bench_memory_pressure(emit_line=False)
-    assert summary is not None, "bench_memory_pressure failed"
-    assert summary["hotset_evictions"] > 0
-    assert summary["hotset_evictions_lru"] > 0
-    assert summary["warm_p95_s_cost"] > 0 and summary["warm_p95_s_lru"] > 0
-    assert summary["hot_budget_bytes"] < summary["working_set_bytes"]

@@ -25,11 +25,6 @@ BASE = datetime(2024, 6, 1, 0, 0, tzinfo=UTC)
 ROWS = 2_048  # a block; divides over the eight virtual devices
 
 
-@pytest.fixture(autouse=True)
-def _every_block_to_the_device(monkeypatch):
-    monkeypatch.setenv("P_TPU_ADAPTIVE", "0")
-
-
 def stream(tag: str, n_blocks: int, grow_at: int | None = None, seed: int = 31) -> list[pa.Table]:
     """`n_blocks` blocks of one minute each, kept apart by a source id as scanned parquet files are.
     From block `grow_at` on the hosts are drawn from 40 and not 8: the key's capacity grows there."""

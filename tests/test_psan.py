@@ -8,8 +8,6 @@ surfaced and this PR fixed:
 
 - the per-flush fire-and-forget `otlp-export` thread in utils/telemetry.py
   (now tracked, at most one in flight, joined by Tracer.drain());
-- the module-global `device-warmer` thread in ops/link.py with no stop
-  path (now drained by shutdown_warmer());
 - scrypt password verification on the event loop in the auth middleware
   (psan-loop-block: rbac/__init__.py hash_password blocked the loop 58ms;
   cache misses — including every wrong-password attempt — now verify on
@@ -351,7 +349,7 @@ LEAK_SRC = """
         return t
 
     def allowlisted_worker():
-        t = threading.Thread(target=STOP.wait, name="device-warmer", daemon=True)
+        t = threading.Thread(target=STOP.wait, name="enccache-writer", daemon=True)
         t.start()
         return t
 """
@@ -495,24 +493,6 @@ def test_psan_leak_detector_catches_undrained_export(monkeypatch):
             )
 
 
-def test_warmer_shutdown_joins_and_restarts():
-    """Regression (pool-lifecycle: ops/link.py device-warmer had no stop
-    path): shutdown_warmer() drains + joins; warming works again after."""
-    from parseable_tpu.ops import link as L
-
-    ran = threading.Event()
-    assert L.warm_async(("psan-k1",), ran.set)
-    assert ran.wait(5)
-    L.shutdown_warmer()
-    assert all(t.name != "device-warmer" for t in threading.enumerate()), (
-        "shutdown_warmer left the warmer running"
-    )
-    ran2 = threading.Event()
-    assert L.warm_async(("psan-k2",), ran2.set)  # fresh warmer spins up
-    assert ran2.wait(5)
-    L.shutdown_warmer()
-
-
 def test_prefetch_consumption_never_promotes():
     """Regression (psan seed: hotset/prefetch claim() interleaving): the
     consumer now fetches with touch=False unconditionally and applies
@@ -522,7 +502,7 @@ def test_prefetch_consumption_never_promotes():
     from parseable_tpu.ops.hotset import DeviceHotSet, HotEntry
     from parseable_tpu.ops.prefetch import ScanPrefetcher
 
-    hs = DeviceHotSet(budget_bytes=10_000, policy="cost", ship_cost=lambda n: 1.0)
+    hs = DeviceHotSet(budget_bytes=10_000, ship_cost=lambda n: 1.0)
     key = ("blk", "cols")
     shipped = threading.Event()
 
@@ -557,8 +537,8 @@ def test_prefetch_consumption_never_promotes():
 def test_hotset_touch_matches_get_touch_semantics():
     from parseable_tpu.ops.hotset import DeviceHotSet, HotEntry
 
-    a = DeviceHotSet(budget_bytes=10_000, policy="cost", ship_cost=lambda n: 1.0)
-    b = DeviceHotSet(budget_bytes=10_000, policy="cost", ship_cost=lambda n: 1.0)
+    a = DeviceHotSet(budget_bytes=10_000, ship_cost=lambda n: 1.0)
+    b = DeviceHotSet(budget_bytes=10_000, ship_cost=lambda n: 1.0)
     for hs in (a, b):
         hs.put(("k",), HotEntry(dev={}, meta=None, nbytes=64))
     a.get(("k",), touch=True)
