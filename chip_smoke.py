@@ -289,7 +289,8 @@ def role_kernels(rehearsal: bool) -> None:
     it — N = 2^20, G in {128, 512}, R = 1 + n_all + n_sum as configs 2-4
     produce (5 for two summed columns, 3 for one) — compiled with Mosaic
     (the interpreter only under --cpu-rehearsal, at a small N) and compared
-    with the XLA path on sparse groups, plus both against f64."""
+    with the XLA path on sparse groups, plus both against f64; then the XLA
+    path alone at G = 8,192, the factored one-hot product's shape."""
     import numpy as np
 
     from parseable_tpu.utils.compile_cache import configure_compile_cache
@@ -331,6 +332,44 @@ def role_kernels(rehearsal: bool) -> None:
             check(xla[0].sum() == mask.sum(), f"XLA counts wrong at G={g}")
             check(max(err_xla, err_pal) <= REL_TOL, f"sparse sums off at G={g}: xla {err_xla:.2e} pallas {err_pal:.2e}")
             out.append({"n": n, "g": g, "r": 1 + 2 * n_sum, "max_rel_err_xla": err_xla, "max_rel_err_pallas": err_pal})
+    # the same check past the one-hot's element budget, where a chip takes the
+    # factored one-hot product (K.fold_route; the rehearsal's backend the
+    # scatter): a ten-row group beside a 100k-row group in one high row
+    g, big, small = 8192, 4097, 4098
+    big_rows = 8_000 if rehearsal else 100_000
+    ids = np.concatenate([np.full(big_rows, big), np.full(10, small), rng.integers(0, g, n - big_rows - 10)]).astype(np.int32)
+    rng.shuffle(ids)
+    mask = np.ones(n, bool)
+    vals = rng.integers(100, 50_000, (1, n)).astype(np.float32)
+    empty = jnp.zeros((0, n), jnp.float32)
+    K.fused_groupby_block.clear_cache()
+    got = [
+        np.asarray(x, np.float64)
+        for x in jax.block_until_ready(
+            K.fused_groupby_block(jnp.asarray(ids), jnp.asarray(mask), jnp.asarray(vals), empty, empty, jnp.asarray(mask[None, :]), g, 1, 0, 0)
+        )
+    ]
+    want = np.bincount(ids, weights=vals[0].astype(np.float64), minlength=g)
+    err = float(np.max(np.abs(got[2][0] - want) / np.maximum(1.0, np.abs(want))))
+    route = K.fold_route(n, g)
+    check(rehearsal or route == "factored", f"a chip's fold at G={g} took the route {route}")
+    check(np.array_equal(got[0], np.bincount(ids, minlength=g)), f"{route} counts wrong at G={g}")
+    check(err <= REL_TOL, f"sparse sums off at G={g} ({route}): {err:.2e}")
+    out.append({"n": n, "g": g, "r": 3, "route": route, "max_rel_err_xla": err, "small_group_rel_err": float(abs(got[2][0][small] - want[small]) / want[small])})
+    # a valid inf is its own group's sum and no other's: in a one-hot product
+    # it would be NaN in every group of its column (the ten-row group's too)
+    at = int(np.flatnonzero(ids == big)[0])
+    vals[0, at] = np.inf
+    got_inf = np.asarray(
+        jax.block_until_ready(
+            K.fused_groupby_block(jnp.asarray(ids), jnp.asarray(mask), jnp.asarray(vals), empty, empty, jnp.asarray(mask[None, :]), g, 1, 0, 0)
+        )[2][0],
+        np.float64,
+    )
+    rest = np.arange(g) != big
+    check(got_inf[big] == np.inf, f"{route}: a valid inf summed to {got_inf[big]} at G={g}")
+    err_inf = float(np.max(np.abs(got_inf[rest] - want[rest]) / np.maximum(1.0, np.abs(want[rest]))))  # NaN fails
+    check(err_inf <= REL_TOL, f"{route}: a valid inf in one group moved another's sum at G={g}: {err_inf:.2e}")
     dev = jax.devices()[0]
     print(json.dumps({"platform": dev.platform, "mosaic": not rehearsal, "precision": str(K.SUM_DOT_PRECISION), "shapes": out}))
 

@@ -1363,6 +1363,17 @@ class RouteStats(dict):
             # scalars, remaps) the dense block loop made: one a dtype a
             # dispatch group
             operand_puts=0,
+            # the additive reduction's form in each block a device program
+            # folded (kernels.fold_route of the rows a device holds, the
+            # group count and the backend): the plain one-hot dot, the
+            # factored one-hot product, the scatter-add. Ticked on the host
+            # from shapes derived there, not read off the device: what ties
+            # it to the traced program is tests/test_fold_routes.py (the
+            # kernel asks the same function the same thing). A block the
+            # Pallas twin folds (P_TPU_USE_PALLAS, off) counts as one-hot
+            fold_onehot_blocks=0,
+            fold_factored_blocks=0,
+            fold_scatter_blocks=0,
             # program-cache traffic (stages.programs reads these): builds
             # this query, cache hits this query, rebuilds of a key that
             # was already built once (0 in steady state)
@@ -1764,9 +1775,13 @@ def device_summary(options: Options | None = None) -> dict:
     }
 
 
-def _mesh_group_shards(mesh) -> int:
-    """Size of the `groups` axis (1 on 1D meshes)."""
-    return mesh.shape.get("groups", 1) if mesh is not None else 1
+def _group_shards_of(mesh, num_groups: int) -> int:
+    """Over how many shards of the mesh's `groups` axis (absent on 1D
+    meshes) a dense accumulator of `num_groups` splits: the axis' size
+    where the group space divides by it, else 1 (the axis idles: inputs
+    replicated over it, the fold identical in each shard)."""
+    n = mesh.shape.get("groups", 1) if mesh is not None else 1
+    return n if n > 1 and num_groups % n == 0 and num_groups >= n else 1
 
 
 def _mesh_shardings(mesh):
@@ -2297,6 +2312,9 @@ class TpuQueryExecutor(QueryExecutor):
                 )
                 rs.dispatched(prev)
                 rs["operand_puts"] += len(packed)  # of a group that went
+                self._note_fold_route(
+                    enc0.block_rows, acc_groups // _group_shards_of(self.mesh, acc_groups), len(pending)
+                )
                 dacc = list(dacc_out)
                 pacc = list(pacc_out)
                 pending.clear()
@@ -2880,6 +2898,14 @@ class TpuQueryExecutor(QueryExecutor):
             _timed_readback(idx, self.route_stats, dtype=None),
         )
 
+    def _note_fold_route(self, block_rows: int, kernel_groups: int, blocks: int) -> None:
+        """`blocks` blocks went through a device program: count the route
+        their additive reduction took, by the function the kernel itself
+        branches on and with what it sees (under a mesh a device holds
+        its share of the block's rows)."""
+        rows = block_rows // (self.mesh.shape["data"] if self.mesh is not None else 1)
+        self.route_stats[f"fold_{kernels.fold_route(rows, kernel_groups)}_blocks"] += blocks
+
     # ----------------------------------------------- high-card (block-local)
 
     def _local_block(
@@ -3002,6 +3028,7 @@ class TpuQueryExecutor(QueryExecutor):
             num_groups,
         )
         out_dev = program(dev, dev_luts, row_mask)
+        self._note_fold_route(enc.block_rows, num_groups, 1)
         if keep.active:
             # the fold runs while the host makes the lanes; nothing waits on it
             rs.dispatched("prepare")
@@ -3622,14 +3649,7 @@ class TpuQueryExecutor(QueryExecutor):
         """
         mesh = self.mesh
         # 2D layout: the accumulator itself shards over the `groups` axis
-        # when the group space divides; otherwise that axis idles (inputs
-        # replicated over it, fold identical per shard)
-        n_group_shards = _mesh_group_shards(mesh)
-        shard_groups = (
-            n_group_shards
-            if n_group_shards > 1 and num_groups % n_group_shards == 0 and num_groups >= n_group_shards
-            else 1
-        )
+        shard_groups = _group_shards_of(mesh, num_groups)
         # distinct presence bitmaps shard over `groups` too: the flat
         # groups-major layout (group * Vcap + code) makes each shard's
         # window contiguous, so P("groups") on the flat dim is exact

@@ -138,6 +138,8 @@ def test_explain_analyze_surfaces_device_routes(loaded):
         "expr_aggs_device", "expr_aggs_host", "encode_declined",
         # transfers of small operands the dense block loop made (ISSUE 31)
         "operand_puts",
+        # the additive reduction's route in each folded block (ISSUE 33)
+        "fold_onehot_blocks", "fold_factored_blocks", "fold_scatter_blocks",
     }
     assert int(routes["recompiles"]) == 0
     total_blocks = sum(
