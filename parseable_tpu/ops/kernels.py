@@ -391,16 +391,20 @@ def fused_groupby_block(
     def seg_max(vals, vm):
         return jax.ops.segment_max(jnp.where(vm, vals, -F32_MAX), group_ids, num_segments=num_groups)
 
-    mins = (
-        jax.vmap(seg_min)(min_values, vmask[n_sum : n_sum + n_min])
-        if n_min
-        else jnp.zeros((0, num_groups), jnp.float32)
-    )
-    maxs = (
-        jax.vmap(seg_max)(max_values, vmask[n_sum + n_min : n_sum + n_min + n_max])
-        if n_max
-        else jnp.zeros((0, num_groups), jnp.float32)
-    )
+    # a scope of its own, so that a device trace says what the one
+    # reduction no one-hot product can carry costs (the executor counts the
+    # blocks: `device_routes.fold_minmax_scatter_blocks`)
+    with jax.named_scope("segment_minmax"):
+        mins = (
+            jax.vmap(seg_min)(min_values, vmask[n_sum : n_sum + n_min])
+            if n_min
+            else jnp.zeros((0, num_groups), jnp.float32)
+        )
+        maxs = (
+            jax.vmap(seg_max)(max_values, vmask[n_sum + n_min : n_sum + n_min + n_max])
+            if n_max
+            else jnp.zeros((0, num_groups), jnp.float32)
+        )
     return count, per_agg_count, sums, mins, maxs
 
 

@@ -42,9 +42,11 @@ complete and exact: a column ops/device.py declines (a nested type, a
 timestamp with sub-millisecond residue, a timestamp column no whole unit
 holds in int32), an integer division (it truncates on the CPU engine), a
 numeric aggregate over a string or timestamp column, `date_bin` with a
-custom origin or sub-millisecond bins or over a timestamp column that is off
-the block's origin, `p_timestamp` itself off that origin under time bounds,
-exact distinct or percentile state beyond its budget. That is the ONLY way
+custom origin or sub-millisecond bins, a time bin over a timestamp column
+that is off the block's origin in a unit that does not divide the bin (an
+hour bin over ship dates held in whole days), `p_timestamp` itself off that
+origin under the request's time bounds, exact distinct or percentile state
+beyond its budget. That is the ONLY way
 work leaves the device: every table or block so handed is counted in
 `route_stats["cpu_fallback"]` (a plan-time rejection with its reason under
 `cpu_fallback_reason`), every declined encoding in `encode_declined` and
@@ -59,6 +61,23 @@ the same programs: `AggExprCompiler` traces each tree in f32 under the scope
 `fold/expr` into one more value row, a shared subtree once, validity the
 AND of the operands'; `expr_aggs_device` / `expr_aggs_host` say where a
 request's expressions were evaluated.
+
+Time bins over event time (`date_bin` / `date_trunc` over a column that is
+not the partition timestamp: rows backfilled, replayed or bulk-loaded carry a
+time years from the minute they were ingested in). Such a column keeps a
+day-aligned `origin_ms` and a `unit_ms` of its own (ops/device.py), and
+wherever `bin_ms` is a whole multiple of that unit the bin of a row is
+`(origin_ms // unit_ms + rel) // (bin_ms // unit_ms)`, exact in whole numbers,
+computed in the column's own steps under the scope `keys`. Shift, offset and
+divisor are the block's runtime scalars among the packed operands
+(`_time_args`), so a text compiles once, not once a block origin or unit;
+its group window is sized once from the text's own bounds on the column
+(`_where_window_ms`), as `scan_time_hint` sizes the partition timestamp's.
+A time-bin key over any column but `p_timestamp` keeps the last slot of its
+capacity for the NULL time's group, as a dict key does (`KeySpec.null_slot`).
+`timebin_offorigin_device_blocks` / `timebin_offorigin_host_blocks` say where
+the bins of such a column were computed, `fold_minmax_scatter_blocks` how
+many blocks folded a min or max (by scatter: `fold/segment_minmax`).
 
 Precision: per-block reductions run in f32 (blocks <= 2^22 rows keep counts
 exact; sums carry ~1e-5 relative error vs the CPU engine's f64); cross-block
@@ -115,6 +134,7 @@ from parseable_tpu.utils.metrics import (
     DEVICE_MERGES,
     DEVICE_PHASE_SECONDS,
     DEVICE_RECOMPILES,
+    DEVICE_TIMEBIN_OFFORIGIN,
 )
 from parseable_tpu.utils.telemetry import TRACER
 from parseable_tpu.utils.timeutil import parse_duration, parse_rfc3339
@@ -361,6 +381,10 @@ class KeySpec:
     gdict: GlobalDict | None = None  # dict only
     capacity: int = 1  # current stride capacity (pow2)
     origin_rel: int | None = None  # timebin only: origin *bin index*
+    # timebin only: code `capacity - 1` is the NULL time's group, as a dict
+    # key's is. The partition timestamp is never NULL and keeps every slot
+    # for a bin; an event-time column (`ev`, backfilled) may hold one
+    null_slot: bool = False
 
     def epoch_values(self) -> list[Any]:
         """dict only: the values this capacity epoch's codes can name. Code
@@ -411,6 +435,12 @@ _TRUNC_MS = {
 }
 
 
+def _may_be_null(time_column: str) -> bool:
+    from parseable_tpu import DEFAULT_TIMESTAMP_KEY
+
+    return time_column != DEFAULT_TIMESTAMP_KEY
+
+
 def classify_group_expr(e: S.Expr) -> KeySpec:
     """Map a GROUP BY expression onto a device key kind, or raise."""
     if isinstance(e, S.Column):
@@ -424,14 +454,14 @@ def classify_group_expr(e: S.Expr) -> KeySpec:
         # any >=1ms bin maps exactly; the upper bound keeps the device-side
         # shift (origin % bin_ms + rel) inside int32
         if ms and ms <= (1 << 30) and isinstance(col, S.Column):
-            return KeySpec("timebin", col.name, e, bin_ms=ms)
+            return KeySpec("timebin", col.name, e, bin_ms=ms, null_slot=_may_be_null(col.name))
         raise UnsupportedOnDevice("sub-millisecond or >12-day date_bin")
     if isinstance(e, S.FunctionCall) and e.name == "date_trunc" and len(e.args) == 2:
         unit = e.args[0].value if isinstance(e.args[0], S.Literal) else None
         col = e.args[1]
         ms = _TRUNC_MS.get(str(unit).lower()) if unit else None
         if ms and isinstance(col, S.Column):
-            return KeySpec("timebin", col.name, e, bin_ms=ms)
+            return KeySpec("timebin", col.name, e, bin_ms=ms, null_slot=_may_be_null(col.name))
     if isinstance(e, S.Cast):
         return classify_group_expr(e.expr)
     raise UnsupportedOnDevice(f"group expression not device-mappable: {S.expr_name(e)}")
@@ -770,11 +800,52 @@ def _dt_to_us(dt: datetime) -> int:
 
 
 def _require_on_origin(col: EncodedColumn | None) -> None:
-    """Time bins and the request's time bounds are arithmetic in ms from the
-    block's origin: a column that keeps an origin and a unit of its own
-    (ops/device.py) takes neither on the device."""
+    """The request's time bounds are arithmetic in ms from the block's
+    origin: a column that keeps an origin and a unit of its own
+    (ops/device.py) does not take them on the device."""
     if col is not None and col.origin_ms is not None:
         raise UnsupportedOnDevice(f"time column {col.name} is off the block's origin")
+
+
+def _time_base(enc: EncodedBatch, col: EncodedColumn) -> tuple[int, int]:
+    """(origin_ms, unit_ms) of a time column: its int32 values are whole
+    `unit_ms` steps from `origin_ms`, the block's origin in ms unless the
+    column keeps a day-aligned one of its own (ops/device.py)."""
+    return (enc.time_origin_ms, 1) if col.origin_ms is None else (col.origin_ms, col.unit_ms)
+
+
+def _bin_steps(ks: KeySpec, col: EncodedColumn) -> int:
+    """A time bin in the column's own steps. An origin of its own is
+    day-aligned, so a whole multiple of every unit ops/device.py takes: the
+    bin of a row is then `(origin_ms // unit_ms + rel) // steps`, exact in
+    whole numbers. A bin the unit does not divide has no such form."""
+    steps, rest = divmod(ks.bin_ms, col.unit_ms)
+    if rest:
+        raise UnsupportedOnDevice(
+            f"date_bin of {ks.bin_ms} ms over {col.name}, held in steps of {col.unit_ms} ms"
+        )
+    return steps
+
+
+def _bin_range(ks: KeySpec, enc: EncodedBatch, col: EncodedColumn) -> tuple[int, int]:
+    """The absolute bins (epoch ms // bin_ms) of the column's least and
+    greatest live value in this block."""
+    origin_ms, unit_ms = _time_base(enc, col)
+    return (
+        (origin_ms + col.vmin * unit_ms) // ks.bin_ms,
+        (origin_ms + col.vmax * unit_ms) // ks.bin_ms,
+    )
+
+
+def _off_origin_keys(enc: EncodedBatch, key_specs: list[KeySpec]) -> tuple[bool, ...]:
+    """Per group key: a time bin over a column that is off the block's
+    origin. Its divisor ships as a third runtime scalar (`_time_args`), so it
+    is part of what a program is traced for and of its cache key."""
+    return tuple(
+        ks.kind == "timebin"
+        and getattr(enc.columns.get(ks.column), "origin_ms", None) is not None
+        for ks in key_specs
+    )
 
 
 def _num_cmp(values, op: str, threshold):
@@ -1164,6 +1235,9 @@ class PlanLayout:
     # lists above hold the names (the tree's canonical text), so the
     # program keys that hold those lists hold the expressions too
     exprs: tuple = ()
+    # per group key: a time bin over a column off the block's origin
+    # (`_off_origin_keys`); empty where the layout has no keys to fold
+    off_origin: tuple[bool, ...] = ()
 
 
 def _kernel_stacks(dev: dict, layout: "PlanLayout", local_rows: int):
@@ -1374,6 +1448,20 @@ class RouteStats(dict):
             fold_onehot_blocks=0,
             fold_factored_blocks=0,
             fold_scatter_blocks=0,
+            # blocks whose device program folded at least one min or max
+            # (a percentile's exact bounds too), by the route that fold took:
+            # `segment_min` / `segment_max`, a scatter, on every backend and
+            # at every group count today (kernels.fused_groupby_block, scope
+            # `fold/segment_minmax`); another route gets a key of its own
+            fold_minmax_scatter_blocks=0,
+            # blocks whose text bins (date_bin / date_trunc) a time column
+            # that is off the block's origin (ops/device.py `origin_ms`):
+            # binned inside the device program, in the column's own steps, or
+            # by host code (the CPU engine for a block that was declared, a
+            # bin its unit does not divide among them; numpy for a block-local
+            # fold on host-compacted pair codes)
+            timebin_offorigin_device_blocks=0,
+            timebin_offorigin_host_blocks=0,
             # program-cache traffic (stages.programs reads these): builds
             # this query, cache hits this query, rebuilds of a key that
             # was already built once (0 in steady state)
@@ -2262,6 +2350,8 @@ class TpuQueryExecutor(QueryExecutor):
             """The plan layout was declared UnsupportedOnDevice at program
             build: aggregate the buffered blocks' source tables on the CPU."""
             self.route_stats["cpu_fallback"] += len(pending)
+            if any(_off_origin_keys(pending[0][1], key_specs)):
+                self._note_offorigin_bins("host", len(pending))
             for x in pending:
                 t = self._bounds_filter(self._materialize(x[0]))
                 agg.update(t, self._where_mask(t))
@@ -2287,6 +2377,7 @@ class TpuQueryExecutor(QueryExecutor):
                 pct_cols=[arg_name(i) for i in pct_idx],
                 cnt_cols=[arg_name(i) for i in countcol_idx],
                 exprs=exprs,
+                off_origin=_off_origin_keys(enc0, key_specs),
             )
             prev = rs.enter("dispatch")
             try:
@@ -2313,8 +2404,10 @@ class TpuQueryExecutor(QueryExecutor):
                 rs.dispatched(prev)
                 rs["operand_puts"] += len(packed)  # of a group that went
                 self._note_fold_route(
-                    enc0.block_rows, acc_groups // _group_shards_of(self.mesh, acc_groups), len(pending)
+                    enc0.block_rows, acc_groups // _group_shards_of(self.mesh, acc_groups), len(pending), lay
                 )
+                if any(layout.off_origin):
+                    self._note_offorigin_bins("device", len(pending))
                 dacc = list(dacc_out)
                 pacc = list(pacc_out)
                 pending.clear()
@@ -2382,9 +2475,12 @@ class TpuQueryExecutor(QueryExecutor):
                     self.route_stats["cpu_fallback"] += 1
                     cpu_block(table)
                     continue
+                bins_off_origin = False  # known once the block is encoded
                 try:
                     enc, dev = self._encoded_block(table, self.plan.needed_columns, dict_cols)
                     rs.enter("prepare")
+                    off_origin = _off_origin_keys(enc, key_specs)
+                    bins_off_origin = any(off_origin)
                     for i in stacked_idx + pct_idx:
                         if i in expr_idx:
                             AggExprCompiler.check(specs[i].arg, enc)
@@ -2531,7 +2627,7 @@ class TpuQueryExecutor(QueryExecutor):
                         tuple(dremaps_np),
                     )
                     sig = (
-                        (enc.block_rows, kinds, "__rowmask" in dev),
+                        (enc.block_rows, kinds, "__rowmask" in dev, off_origin),
                         tuple(_operand_sig(part) for part in operands),
                     )
                     if pending and sig != pending_sig:
@@ -2545,6 +2641,8 @@ class TpuQueryExecutor(QueryExecutor):
                     rs.enter(None)  # the CPU's fold is no phase of the device path
                     logger.debug("batch on CPU (%s)", e)
                     self.route_stats["cpu_fallback"] += 1
+                    if bins_off_origin:
+                        self._note_offorigin_bins("host", 1)
                     if keep is not None:
                         self._spill_kept(keep, partials, specs, lay, "host_partials")
                     t = self._bounds_filter(self._materialize(table))
@@ -2556,6 +2654,8 @@ class TpuQueryExecutor(QueryExecutor):
             dispatch_pending()
             sp_blocks["rows"] = rs.blocks
             sp_blocks["expr_aggs"] = len(expr_idx)
+            for k in ("fold_minmax_scatter_blocks", "timebin_offorigin_device_blocks", "timebin_offorigin_host_blocks"):
+                sp_blocks[k] = rs[k]
         if expr_idx:
             # where the expressions were evaluated: in the device program for
             # the blocks it folded, by the CPU engine for the blocks that did
@@ -2714,7 +2814,8 @@ class TpuQueryExecutor(QueryExecutor):
             else:
                 abs_ms = ((ks.origin_rel or 0) + codes) * ks.bin_ms
                 cols[f"__g{i}"] = pa.array(
-                    abs_ms.astype("datetime64[ms]"), pa.timestamp("ms")
+                    abs_ms.astype("datetime64[ms]"), pa.timestamp("ms"),
+                    mask=codes == ks.capacity - 1 if ks.null_slot else None,
                 )
         pct_hists = dict(pcts or [])
         for si, spec in enumerate(specs):
@@ -2898,13 +2999,23 @@ class TpuQueryExecutor(QueryExecutor):
             _timed_readback(idx, self.route_stats, dtype=None),
         )
 
-    def _note_fold_route(self, block_rows: int, kernel_groups: int, blocks: int) -> None:
+    def _note_fold_route(self, block_rows: int, kernel_groups: int, blocks: int, lay: AccLayout) -> None:
         """`blocks` blocks went through a device program: count the route
         their additive reduction took, by the function the kernel itself
         branches on and with what it sees (under a mesh a device holds
-        its share of the block's rows)."""
+        its share of the block's rows), and the route of their min / max
+        fold where the layout has one."""
         rows = block_rows // (self.mesh.shape["data"] if self.mesh is not None else 1)
         self.route_stats[f"fold_{kernels.fold_route(rows, kernel_groups)}_blocks"] += blocks
+        if lay.n_mink or lay.n_maxk:
+            self.route_stats["fold_minmax_scatter_blocks"] += blocks
+
+    def _note_offorigin_bins(self, path: str, blocks: int) -> None:
+        """`blocks` blocks whose text bins a time column off the block's
+        origin were binned on `path` (device | host)."""
+        if blocks:
+            self.route_stats[f"timebin_offorigin_{path}_blocks"] += blocks
+            DEVICE_TIMEBIN_OFFORIGIN.labels(path).inc(blocks)
 
     # ----------------------------------------------- high-card (block-local)
 
@@ -2929,6 +3040,7 @@ class TpuQueryExecutor(QueryExecutor):
 
         rs = self.route_stats
         prev = rs.enter("prepare")
+        off_origin = _off_origin_keys(enc, key_specs)
         caps: list[int] = []
         origins: list[int] = []
         keyinfo: list[tuple] = []
@@ -2946,15 +3058,15 @@ class TpuQueryExecutor(QueryExecutor):
             else:
                 if col.vmin is None or col.vmax is None:
                     raise UnsupportedOnDevice("time-bin key over all-null column")
-                lo_bin = (enc.time_origin_ms + col.vmin) // ks.bin_ms
-                hi_bin = (enc.time_origin_ms + col.vmax) // ks.bin_ms
-                span = int(hi_bin - lo_bin + 1)
+                _bin_steps(ks, col)
+                lo_bin, hi_bin = _bin_range(ks, enc, col)
+                span = int(hi_bin - lo_bin + 1 + ks.null_slot)
                 cap = _pow2(max(2, span))
                 if cap > LOCAL_G_MAX:
                     raise UnsupportedOnDevice("time-bin span exceeds device capacity")
                 caps.append(cap)
                 origins.append(int(lo_bin))
-                keyinfo.append(("timebin", int(lo_bin), ks.bin_ms, cap))
+                keyinfo.append(("timebin", int(lo_bin), ks.bin_ms, cap - 1 if ks.null_slot else None, cap))
         num_groups = 1
         for c in caps:
             num_groups *= c
@@ -2993,8 +3105,13 @@ class TpuQueryExecutor(QueryExecutor):
                 if ks.kind == "dict":
                     codes = np.minimum(vals.astype(np.int64), cap - 1)
                 else:
-                    abs_ms = vals.astype(np.int64) + enc.time_origin_ms
-                    codes = np.clip(abs_ms // ks.bin_ms - origin, 0, cap - 1)
+                    col = enc.columns[ks.column]
+                    origin_ms, unit_ms = _time_base(enc, col)
+                    abs_ms = vals.astype(np.int64) * unit_ms + origin_ms
+                    codes = np.clip(abs_ms // ks.bin_ms - origin, 0, cap - 1 - ks.null_slot)
+                    if ks.null_slot and not col.all_valid:
+                        live = col.valid if len(col.valid) else _timed_readback(dev[f"{ks.column}__valid"], rs, dtype=None)
+                        codes = np.where(live, codes, cap - 1)
                 comp = codes if comp is None else comp * cap + codes
             uniq, inv = np.unique(comp, return_inverse=True)
             num_groups = _pow2(max(2, len(uniq)))
@@ -3007,7 +3124,12 @@ class TpuQueryExecutor(QueryExecutor):
             dev["__pairkey"] = put_row(inv.astype(np.int32))
 
         if composite_vals is None:
-            key_sig = tuple((ks.kind, ks.column, ks.bin_ms) for ks in key_specs)
+            # a key over the partition timestamp, on the block's origin, is
+            # the three it was; any other says what its program differs by
+            key_sig = tuple(
+                (ks.kind, ks.column, ks.bin_ms, *((off, ks.null_slot) if off or ks.null_slot else ()))
+                for ks, off in zip(key_specs, off_origin)
+            )
             full_luts = luts + self._time_args(enc, key_specs, origins, self._bounds_ms())
         else:
             key_sig = (("pair", "__pairkey", 0),)
@@ -3028,7 +3150,10 @@ class TpuQueryExecutor(QueryExecutor):
             num_groups,
         )
         out_dev = program(dev, dev_luts, row_mask)
-        self._note_fold_route(enc.block_rows, num_groups, 1)
+        self._note_fold_route(enc.block_rows, num_groups, 1, lay)
+        if any(off_origin):
+            # the pair codes' bins were numpy's (`_host_codes` above)
+            self._note_offorigin_bins("device" if composite_vals is None else "host", 1)
         if keep.active:
             # the fold runs while the host makes the lanes; nothing waits on it
             rs.dispatched("prepare")
@@ -3160,7 +3285,7 @@ class TpuQueryExecutor(QueryExecutor):
                 off = origins[i] - keep.bin_origin[i]
                 if abs(off) + caps[i] >= int(_LANE_NULL):
                     return None
-                lanes[i, : len(code)] = code + off
+                lanes[i, : len(code)] = np.where(code == caps[i] - 1, _LANE_NULL, code + off) if ks.null_slot else code + off
         return lanes
 
     def _device_merge(
@@ -3229,7 +3354,7 @@ class TpuQueryExecutor(QueryExecutor):
                 keyinfo.append(("dict", [ks.gdict.values[c] for c in uniq] + [None], 0))
                 lane = np.where(named, np.searchsorted(uniq, lane), len(uniq))
             else:
-                keyinfo.append(("timebin", keep.bin_origin[i], ks.bin_ms, 0))
+                keyinfo.append(("timebin", keep.bin_origin[i], ks.bin_ms, int(_LANE_NULL) if ks.null_slot else None, 0))
             key_codes.append(np.repeat(lane, run_max))
         pt = self._partial_from_arrays(got, lay, keyinfo, specs, key_codes=key_codes)
         rs.enter(prev)
@@ -3322,7 +3447,9 @@ class TpuQueryExecutor(QueryExecutor):
         compiler = PredicateCompiler()
         n_timebin = sum(1 for k in key_sig if k[0] == "timebin")
         n_bounds = sum(1 for b in bounds_ms if b is not None)
-        n_time_args = 2 * n_timebin + n_bounds
+        # a key off the block's origin says so in its signature (`_local_block`)
+        n_key_args = 2 * n_timebin + sum(1 for k in key_sig if len(k) > 3 and k[3])
+        n_time_args = n_key_args + n_bounds
 
         from parseable_tpu import DEFAULT_TIMESTAMP_KEY
 
@@ -3338,7 +3465,7 @@ class TpuQueryExecutor(QueryExecutor):
                 mask = jnp.logical_and(mask, row_mask)
                 if n_bounds and DEFAULT_TIMESTAMP_KEY in enc.columns:
                     ts = dev[DEFAULT_TIMESTAMP_KEY]
-                    bi = 2 * n_timebin
+                    bi = n_key_args
                     if bounds_ms[0] is not None:
                         mask = jnp.logical_and(mask, ts >= extra[bi][0])
                         bi += 1
@@ -3353,17 +3480,21 @@ class TpuQueryExecutor(QueryExecutor):
                     ids = None
                     stride = 1
                     ti = 0
-                    for (kind, column, bin_ms), cap in zip(key_sig, caps):
+                    for (kind, column, bin_ms, *flags), cap in zip(key_sig, caps):
                         if kind == "dict":
                             codes = jnp.minimum(dev[column], cap - 1)
                         else:
+                            off, null_slot = flags or (False, False)
                             shift, k_off = extra[ti][0], extra[ti + 1][0]
-                            ti += 2
+                            steps = extra[ti + 2][0] if off else jnp.int32(bin_ms)
+                            ti += 3 if off else 2
                             codes = jnp.clip(
-                                (dev[column] + shift) // jnp.int32(bin_ms) + k_off,
+                                (dev[column] + shift) // steps + k_off,
                                 0,
-                                cap - 1,
+                                cap - 1 - null_slot,
                             )
+                            if null_slot:
+                                codes = jnp.where(dev[f"{column}__valid"], codes, cap - 1)
                         part = codes * jnp.int32(stride)
                         ids = part if ids is None else ids + part
                         stride *= cap
@@ -3442,9 +3573,12 @@ class TpuQueryExecutor(QueryExecutor):
             arr = pa.array(values)
             take = np.minimum(code, len(values) - 1).astype(np.int32)
             return pa.DictionaryArray.from_arrays(pa.array(take), arr)
-        origin_bin, bin_ms = info[1], info[2]
+        origin_bin, bin_ms, null_code = info[1], info[2], info[3]
         abs_ms = (origin_bin + code) * bin_ms
-        return pa.array(abs_ms.astype("datetime64[ms]"), pa.timestamp("ms"))
+        return pa.array(
+            abs_ms.astype("datetime64[ms]"), pa.timestamp("ms"),
+            mask=None if null_code is None else code == null_code,
+        )
 
     def _partial_from_arrays(
         self,
@@ -3530,7 +3664,7 @@ class TpuQueryExecutor(QueryExecutor):
             if ks.kind == "dict":
                 keyinfo.append(("dict", ks.epoch_values() + [None], ks.capacity))
             else:
-                keyinfo.append(("timebin", ks.origin_rel or 0, ks.bin_ms, ks.capacity))
+                keyinfo.append(("timebin", ks.origin_rel or 0, ks.bin_ms, ks.capacity - 1 if ks.null_slot else None, ks.capacity))
         pt = self._partial_from_arrays(arr, lay, keyinfo, specs)
         self.route_stats.enter(prev)
         return pt
@@ -3680,6 +3814,7 @@ class TpuQueryExecutor(QueryExecutor):
             tuple(layout.sq_cols),
             tuple(layout.pct_cols),
             tuple(layout.cnt_cols),
+            layout.off_origin,
         )
         prog = _PROGRAM_CACHE.get(key)
         if prog is not None:
@@ -3693,12 +3828,14 @@ class TpuQueryExecutor(QueryExecutor):
         compiler = PredicateCompiler()
         kernel_groups = num_groups // shard_groups  # per-device group window
         key_specs = [
-            KeySpec(ks.kind, ks.column, ks.expr, ks.bin_ms, ks.gdict, cap, orig)
+            KeySpec(ks.kind, ks.column, ks.expr, ks.bin_ms, ks.gdict, cap, orig, ks.null_slot)
             for ks, cap, orig in zip(layout.key_specs, layout.caps, layout.origins)
         ]
         n_timebin = sum(1 for ks in key_specs if ks.kind == "timebin")
         n_bounds = sum(1 for b in bounds_ms if b is not None)
-        n_time_args = 2 * n_timebin + n_bounds
+        off_origin = layout.off_origin or (False,) * len(key_specs)
+        n_key_args = 2 * n_timebin + sum(off_origin)
+        n_time_args = n_key_args + n_bounds
 
         from parseable_tpu import DEFAULT_TIMESTAMP_KEY
 
@@ -3716,7 +3853,7 @@ class TpuQueryExecutor(QueryExecutor):
                 mask = jnp.logical_and(mask, row_mask)
                 if n_bounds and DEFAULT_TIMESTAMP_KEY in enc.columns:
                     ts = dev[DEFAULT_TIMESTAMP_KEY]
-                    bi = 2 * n_timebin
+                    bi = n_key_args
                     if bounds_ms[0] is not None:
                         mask = jnp.logical_and(mask, ts >= extra[bi][0])
                         bi += 1
@@ -3731,19 +3868,24 @@ class TpuQueryExecutor(QueryExecutor):
                     stride = 1
                     ri = 0
                     ti = 0
-                    for ks in key_specs:
+                    for ks, off in zip(key_specs, off_origin):
                         cap = ks.capacity
                         if ks.kind == "dict":
                             codes = jnp.minimum(remaps[ri][_as_index(dev[ks.column])], cap - 1)
                             ri += 1
                         else:
                             shift, k_off = extra[ti][0], extra[ti + 1][0]
-                            ti += 2
+                            # the bin in the column's own steps is the block's
+                            # to say (`_time_args`); on the origin it is the text's
+                            steps = extra[ti + 2][0] if off else jnp.int32(ks.bin_ms)
+                            ti += 3 if off else 2
                             codes = jnp.clip(
-                                (dev[ks.column] + shift) // jnp.int32(ks.bin_ms) + k_off,
+                                (dev[ks.column] + shift) // steps + k_off,
                                 0,
-                                cap - 1,
+                                cap - 1 - ks.null_slot,
                             )
+                            if ks.null_slot:
+                                codes = jnp.where(dev[f"{ks.column}__valid"], codes, cap - 1)
                         part = codes * jnp.int32(stride)
                         ids = part if ids is None else ids + part
                         stride *= cap
@@ -3959,35 +4101,63 @@ class TpuQueryExecutor(QueryExecutor):
     ) -> list[np.ndarray]:
         """Per-block time scalars appended after the predicate LUTs, in a
         fixed layout both the host builder and the traced fold share:
-        [per-timebin-key (shift, K)...,  bounds lo?,  bounds hi?].
+        [per-timebin-key (shift, K, steps?)...,  bounds lo?,  bounds hi?].
 
         shift = origin % bin (so (rel + shift) // bin is the global bin
         index minus origin//bin) and K = origin//bin - scan_lo_bin (the
         block's bin offset inside the scan's group window, bounded by the
-        group capacity). Bounds clamp like predicate literals."""
+        group capacity). A key over a column off the block's origin
+        (`_off_origin_keys`) counts all three in the column's own steps and
+        ships the divisor too: steps = bin_ms // unit_ms, shift = (origin_ms
+        // unit_ms) % steps, K = origin_ms // bin_ms - scan_lo_bin, so one
+        program serves every origin and every unit. Bounds clamp like
+        predicate literals."""
         out: list[np.ndarray] = []
         from parseable_tpu import DEFAULT_TIMESTAMP_KEY
 
-        on_origin = [ks.column for ks in key_specs if ks.kind == "timebin"]
         if any(b is not None for b in bounds_ms):
-            on_origin.append(DEFAULT_TIMESTAMP_KEY)
-        for name in on_origin:
-            _require_on_origin(enc.columns.get(name))
+            _require_on_origin(enc.columns.get(DEFAULT_TIMESTAMP_KEY))
         for ks, origin_bin in zip(key_specs, origins):
             if ks.kind != "timebin":
                 continue
-            shift = enc.time_origin_ms % ks.bin_ms
-            k_off = enc.time_origin_ms // ks.bin_ms - int(origin_bin)
+            col = enc.columns[ks.column]  # there: `_required_layout` / `_local_block` looked
+            origin_ms, unit_ms = _time_base(enc, col)
+            steps = _bin_steps(ks, col)  # bin_ms itself on the block's origin
+            shift = origin_ms // unit_ms % steps
+            k_off = origin_ms // ks.bin_ms - int(origin_bin)
             if not (-(2**31) < k_off < 2**31):
                 raise UnsupportedOnDevice("block outside the scan's bin window")
             out.append(np.asarray([shift], dtype=np.int32))
             out.append(np.asarray([k_off], dtype=np.int32))
+            if col.origin_ms is not None:
+                out.append(np.asarray([steps], dtype=np.int32))
         for b in bounds_ms:
             if b is not None:
                 rel = b - enc.time_origin_ms
                 rel = max(-(2**31) + 2, min(2**31 - 2, rel))
                 out.append(np.asarray([rel], dtype=np.int32))
         return out
+
+    def _where_window_ms(self, column: str) -> tuple[int | None, int | None]:
+        """The least and greatest epoch ms of `column` that the WHERE's
+        top-level conjuncts let through (`plan.constraints`), None where
+        they set no such bound: lets a time-bin key over an event-time
+        column size its group window once, as `scan_time_hint` does for
+        p_timestamp (one capacity epoch, one readback a query)."""
+        lo = hi = None
+        for c in self.plan.constraints:
+            if c.column != column or c.op not in ("=", "<", "<=", ">", ">=") or not isinstance(c.value, str):
+                continue
+            try:
+                v = _dt_to_us(parse_rfc3339(c.value)) // 1000
+            except ValueError:
+                continue
+            if c.op in (">", ">=", "="):
+                lo = v if lo is None else max(lo, v)
+            if c.op in ("<", "<=", "="):
+                v = v - 1 if c.op == "<" else v
+                hi = v if hi is None else min(hi, v)
+        return lo, hi
 
     def _required_layout(self, ks: KeySpec, enc: EncodedBatch) -> tuple[int, int]:
         """(origin, capacity) this key needs for the incoming batch. A change
@@ -4001,12 +4171,19 @@ class TpuQueryExecutor(QueryExecutor):
         col = enc.columns.get(ks.column)
         if col is None:
             raise UnsupportedOnDevice(f"time column {ks.column} missing")
-        _require_on_origin(col)
+        _bin_steps(ks, col)  # declared here, before any state is touched
         if col.vmin is None or col.vmax is None:
             return ks.origin_rel or 0, max(ks.capacity, 2)
-        lo_bin = (enc.time_origin_ms + col.vmin) // ks.bin_ms
-        hi_bin = (enc.time_origin_ms + col.vmax) // ks.bin_ms
-        if ks.origin_rel is None and self.plan.scan_time_hint is not None:
+        lo_bin, hi_bin = _bin_range(ks, enc, col)
+        if col.origin_ms is not None:
+            # an event-time column is not what the manifests' p_timestamp
+            # range speaks of: its window is the text's own bounds on it
+            if ks.origin_rel is None:
+                w_lo, w_hi = self._where_window_ms(ks.column)
+                if w_lo is not None and w_hi is not None and 0 <= (w_hi - w_lo) // ks.bin_ms <= (1 << 22):
+                    lo_bin = min(lo_bin, w_lo // ks.bin_ms)
+                    hi_bin = max(hi_bin, w_hi // ks.bin_ms)
+        elif ks.origin_rel is None and self.plan.scan_time_hint is not None:
             # pre-size from the scan's manifest time range: one capacity
             # epoch, one flush, one readback for the whole query
             h_lo, h_hi = self.plan.scan_time_hint
@@ -4016,7 +4193,7 @@ class TpuQueryExecutor(QueryExecutor):
                 lo_bin = min(lo_bin, hint_lo_bin)
                 hi_bin = max(hi_bin, hint_hi_bin)
         origin_bin = lo_bin if ks.origin_rel is None else min(ks.origin_rel, lo_bin)
-        span = hi_bin - origin_bin + 1
+        span = hi_bin - origin_bin + 1 + ks.null_slot
         cap = max(ks.capacity, 2)
         while cap < span:
             cap *= 2
@@ -4054,6 +4231,8 @@ class TpuQueryExecutor(QueryExecutor):
                 rem //= ks.capacity
                 if ks.kind == "dict":
                     key_parts.append(live[code] if code < len(live) else None)
+                elif ks.null_slot and code == ks.capacity - 1:
+                    key_parts.append(None)
                 else:
                     abs_ms = ((ks.origin_rel or 0) + code) * ks.bin_ms
                     key_parts.append(

@@ -163,13 +163,16 @@ def plan_fingerprint(lp, engine: str) -> str:
     """Semantic fingerprint of what the interim depends on: the full
     statement (WHERE/GROUP BY/aggregates), the effective time bounds, the
     projected columns, and the engine (device partial sums are f32 per
-    block — close, but not bit-identical to the CPU's f64)."""
-    from parseable_tpu.query import sql as S
-
+    block — close, but not bit-identical to the CPU's f64). The statement
+    is its syntax tree's own repr, structural and whole: EXPLAIN's
+    rendering (`format_statement`) prints an IN list as `inlist` and a
+    BETWEEN as `between`, so two texts that differed only in the hosts
+    they named shared a key, and the second was answered with the first's
+    rows (found by ISSUE 34's two texts, which differ in nothing else)."""
     cols = sorted(lp.needed_columns) if lp.needed_columns is not None else ["*"]
     text = "\x1f".join(
         [
-            S.format_statement(lp.select),
+            repr(lp.select),
             str(lp.time_bounds.low),
             str(lp.time_bounds.high),
             ",".join(cols),

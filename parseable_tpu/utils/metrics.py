@@ -215,6 +215,18 @@ DEVICE_EXPR_AGGREGATES = _counter(
     "Aggregate outputs over an arithmetic expression by where the expression was evaluated",
     ["path"],
 )
+# blocks whose text bins (date_bin / date_trunc) a time column off the
+# block's origin (an event time backfilled years from its ingest minute):
+# binned inside the device program ("device"), or by host code ("host": a
+# block the CPU engine folded, a bin the column's unit does not divide among
+# them)
+DEVICE_TIMEBIN_OFFORIGIN = _counter(
+    "tpu_timebin_offorigin",
+    "Blocks that bin a time column off the block's origin, by where the bin was computed",
+    ["path"],
+)
+for _path in ("device", "host"):
+    DEVICE_TIMEBIN_OFFORIGIN.labels(_path)  # a scrape reads 0, not nothing
 # a column ops/device.py could not hold on the device, so every query that
 # names it takes the CPU engine for that block: time_span (a timestamp
 # column too wide for int32 in any whole unit), sub_ms, nested, other

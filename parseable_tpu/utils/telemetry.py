@@ -380,8 +380,13 @@ class Tracer:
         # stitched cluster trace shows WHICH shard/lane produced a span and
         # why it declined — the fixed fields above stay the stable schema;
         # `survivors` is execute.merge's (groups the device merge kept of
-        # the entries counted under `rows`)
-        for k in ("shard", "lane", "cause", "qwait_us", "survivors"):
+        # the entries counted under `rows`); the last three are
+        # execute.blocks' (blocks by the route of their min / max fold and by
+        # where an event-time bin off the block's origin was computed)
+        for k in (
+            "shard", "lane", "cause", "qwait_us", "survivors",
+            "fold_minmax_scatter_blocks", "timebin_offorigin_device_blocks", "timebin_offorigin_host_blocks",
+        ):
             if k in attrs:
                 row[k] = attrs[k]
         _SPAN_RING.append(row)

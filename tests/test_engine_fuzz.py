@@ -14,6 +14,9 @@ window shapes end-to-end.
 ISSUE 30: the grammar's aggregates take arithmetic expressions over the
 numeric columns too (sum(lat * 2), avg(lat + status), ...).
 
+ISSUE 34: a lane of its own bins an event-time column eight years off the
+rows' p_timestamp (NULLs among its values), with min / max beside the bins.
+
 Tolerance model per aggregate kind (alias prefix encodes it):
   a*  exact/f32 sums        rel 2e-4
   s*  stddev/var            rel 5e-3 abs 1e-3 (centered-M2 on device)
@@ -182,6 +185,57 @@ def test_differential_fuzz():
                 .to_pylist()
             )
             rows_equal(cpu, solo, f"[trial {trial}] {sql}", "solo")
+
+
+# ------------------------------------------- event-time bins (ISSUE 34)
+
+EVENT_BASE = datetime(2016, 1, 1)  # eight years before the rows' p_timestamp: off every block's origin
+EVENT_GROUPS = [
+    "date_bin(interval '1 hour', ev)", "date_bin(interval '10m', ev)", "date_trunc('minute', ev)",
+    "date_trunc('day', ev)", "date_bin(interval '90 seconds', ev)", "host", "status",
+]
+EVENT_AGGS = ["min(lat)", "max(lat)", "max(status)", "min(status - lat)", "count(*)", "count(lat)", "avg(lat)", "sum(status)"]
+EVENT_FILTERS = [
+    "ev >= '2016-01-01T01:00:00Z'", "ev < '2016-01-01T03:30:00Z'", "ev >= '2016-01-01T00:30:00Z' AND ev < '2016-01-01T04:00:00Z'",
+    "ev > '2016-01-01T02:00:00.500Z'", "ev IS NOT NULL", "host IN ('h0', 'h1', 'h3')", "lat > 50",
+]
+
+
+def with_event_time(rng: random.Random, table: pa.Table) -> pa.Table:
+    """`ev`: the time the rows carry, in whole steps of a unit drawn per table (so neighbouring blocks differ in unit and
+    in origin), a twentieth of it NULL."""
+    n = table.num_rows
+    np_rng = np.random.default_rng(rng.randrange(1 << 30))
+    unit_ms = rng.choice([1, 1000, 60_000])
+    first_ms = rng.choice([0, 3_600_000, 86_400_000])
+    steps = np_rng.integers(0, 5 * 3_600_000 // unit_ms, n)
+    ev = [None if np_rng.random() < 0.05 else EVENT_BASE + timedelta(milliseconds=int(first_ms + s * unit_ms)) for s in steps]
+    return table.append_column("ev", pa.array(ev, pa.timestamp("ms")))
+
+
+def test_differential_fuzz_event_time_bins_with_min_and_max():
+    """Time bins over an event-time column off the block's origin, `min` / `max` beside them: the three lanes again.
+    A bin its column's unit does not divide (90 s over whole minutes) is the CPU engine's, declared: still the same rows."""
+    rng = random.Random(int(os.environ.get("FUZZ_SEED", "1234")) + 34)
+    no_mesh = Options()
+    no_mesh.mesh_shape = "off"
+    binned = 0
+    for trial in range(max(20, TRIALS // 4)):
+        tables = [with_event_time(rng, make_table(rng, rng.choice([500, 3000]))) for _ in range(rng.randint(1, 3))]
+        groups = rng.sample(EVENT_GROUPS, rng.randint(1, 2))
+        aggs = [f"{expr} a{i}" for i, expr in enumerate(rng.sample(EVENT_AGGS, rng.randint(1, 3)))]
+        sql = "SELECT " + ", ".join([f"{g} g{i}" for i, g in enumerate(groups)] + aggs) + " FROM t"
+        if rng.random() < 0.6:
+            sql += f" WHERE {rng.choice(EVENT_FILTERS)}"
+        sql += " GROUP BY " + ", ".join(f"g{i}" for i in range(len(groups)))
+        cpu = QueryExecutor(build_plan(parse_sql(sql))).execute(iter(tables)).to_pylist()
+        ex = TpuQueryExecutor(build_plan(parse_sql(sql)))
+        rows_equal(cpu, ex.execute(iter(tables)).to_pylist(), f"[trial {trial}] {sql}", "mesh")
+        binned += ex.route_stats["timebin_offorigin_device_blocks"]
+        if trial % 3 == 0:
+            solo = TpuQueryExecutor(build_plan(parse_sql(sql)), no_mesh).execute(iter(tables)).to_pylist()
+            rows_equal(cpu, solo, f"[trial {trial}] {sql}", "solo")
+    assert binned > 0  # the device did bin some of them
 
 
 # ----------------------------------------------------- session-level shapes

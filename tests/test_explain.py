@@ -140,6 +140,10 @@ def test_explain_analyze_surfaces_device_routes(loaded):
         "operand_puts",
         # the additive reduction's route in each folded block (ISSUE 33)
         "fold_onehot_blocks", "fold_factored_blocks", "fold_scatter_blocks",
+        # the min / max fold's route, and where a time bin over a column off
+        # the block's origin was computed (ISSUE 34)
+        "fold_minmax_scatter_blocks",
+        "timebin_offorigin_device_blocks", "timebin_offorigin_host_blocks",
     }
     assert int(routes["recompiles"]) == 0
     total_blocks = sum(

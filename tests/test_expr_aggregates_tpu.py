@@ -435,11 +435,15 @@ def test_a_table_that_merely_holds_a_wide_column_runs_the_program_it_ran_without
     assert with_col == without and len(keys) == 2 and keys[0] == keys[1]
 
 
-def test_date_bin_over_a_column_off_the_origin_is_declared():
+def test_date_bin_over_a_column_off_the_origin_is_declared_where_its_unit_does_not_divide_the_bin():
+    """Since ISSUE 34 a bin that is a whole multiple of the column's unit folds on the device, in the column's own steps
+    (tests/test_event_time_bins_tpu.py); ship dates are held in whole days, so a day bin does and an hour bin does not."""
     table, _ = lineitem(seed=41, n=200)
-    sql = "SELECT date_bin(interval '1 day', l_shipdate) AS d, count(*) AS n FROM t GROUP BY d"
-    cpu, tpu, ex = run(sql, [table])
-    assert ex.route_stats["cpu_fallback"] == 1 and sorted(map(str, tpu)) == sorted(map(str, cpu))
+    for interval, on_cpu in (("1 hour", 1), ("1 day", 0)):
+        sql = f"SELECT date_bin(interval '{interval}', l_shipdate) AS d, count(*) AS n FROM t GROUP BY d"
+        cpu, tpu, ex = run(sql, [table])
+        assert ex.route_stats["cpu_fallback"] == on_cpu and sorted(map(str, tpu)) == sorted(map(str, cpu))
+        assert (ex.route_stats["timebin_offorigin_host_blocks"], ex.route_stats["timebin_offorigin_device_blocks"]) == (on_cpu, 1 - on_cpu)
 
 
 def test_the_encoding_survives_the_encoded_block_cache(tmp_path):
